@@ -389,9 +389,16 @@ def run_sharded_section(*, iters: int, sizes=(250_000, 1_000_000),
     bit-identity against the single-device lexicographic oracle, and the
     per-shard rows audit. Multi-device CPU needs
     --xla_force_host_platform_device_count set BEFORE jax initializes, so
-    this function only ORCHESTRATES: it re-invokes this module in a
+    on CPU this function only ORCHESTRATES: it re-invokes this module in a
     subprocess with --sharded-worker (progress relayed from its stderr) and
-    parses the JSON section from its stdout."""
+    parses the JSON section from its stdout. On a TPU backend the chips are
+    real and a chip belongs to one process, so the measurements run here,
+    in-process, over ``jax.devices()``."""
+    if jax.default_backend() == "tpu":
+        n_dev = jax.device_count()
+        return _run_sharded_measurements(
+            iters=iters, sizes=sizes, devices=n_dev, dim=dim,
+            shard_counts=tuple(s for s in shard_counts if s <= n_dev))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"

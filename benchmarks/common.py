@@ -86,17 +86,18 @@ QUERY_TYPES = {
 }
 
 # the same four levels expressed through the session API; each entry takes
-# (db, ccfg, q_emb) and returns a ready QueryBuilder lowering to the exact
-# Predicate its QUERY_TYPES twin builds
+# (db, ccfg, q_emb[, tenant]) and returns a ready QueryBuilder lowering to
+# the exact Predicate its QUERY_TYPES twin builds (tenant 3 by default;
+# the two admin levels carry no tenant clause and ignore it)
 SESSION_QUERIES = {
-    "pure_similarity": lambda db, ccfg, q: db.admin_session().search(q),
-    "date_filter": lambda db, ccfg, q: (db.admin_session().search(q)
-                                        .newer_than(ccfg.now_ts - 60 * DAY_S)),
-    "tenant_category": lambda db, ccfg, q: (
-        db.session(Principal(tenant_id=3, group_bits=0xFFFFFFFF))
+    "pure_similarity": lambda db, ccfg, q, tenant=3: db.admin_session().search(q),
+    "date_filter": lambda db, ccfg, q, tenant=3: (
+        db.admin_session().search(q).newer_than(ccfg.now_ts - 60 * DAY_S)),
+    "tenant_category": lambda db, ccfg, q, tenant=3: (
+        db.session(Principal(tenant_id=tenant, group_bits=0xFFFFFFFF))
         .search(q).in_categories([1, 2])),
-    "full_multi": lambda db, ccfg, q: (
-        db.session(Principal(tenant_id=3, group_bits=0b0011))
+    "full_multi": lambda db, ccfg, q, tenant=3: (
+        db.session(Principal(tenant_id=tenant, group_bits=0b0011))
         .search(q).newer_than(ccfg.now_ts - 60 * DAY_S).in_categories([1, 2])),
 }
 

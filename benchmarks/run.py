@@ -17,6 +17,8 @@ def main() -> None:
                     help="unified-query engine for the latency table")
     args = ap.parse_args()
 
+    from repro.runtime import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import (bench_complexity, bench_freshness, bench_isolation,
                             bench_latency, bench_tiering)
 

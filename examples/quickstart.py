@@ -19,6 +19,9 @@ from repro.api import RagDB
 from repro.core import Principal, StoreConfig
 from repro.core.splitstack import SplitStackClient
 from repro.data.corpus import DAY_S, CorpusConfig, make_corpus, make_queries
+from repro.runtime import configure_compile_cache
+
+configure_compile_cache()
 
 ccfg = CorpusConfig(n_docs=20_000, dim=64, n_tenants=8, n_categories=5)
 scfg = StoreConfig(capacity=1 << 15, dim=64)

@@ -15,6 +15,7 @@ from repro.api import RagDB
 from repro.core import Principal, StoreConfig
 from repro.data.corpus import DAY_S, CorpusConfig, make_corpus
 from repro.models.transformer import TransformerConfig, init
+from repro.runtime import configure_compile_cache
 from repro.serving.engine import RAGEngine, Request
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=12)
     ap.add_argument("--docs", type=int, default=10_000)
     args = ap.parse_args()
+    configure_compile_cache()
 
     rng = np.random.default_rng(0)
     ccfg = CorpusConfig(n_docs=args.docs, dim=48, n_tenants=6, n_categories=5)

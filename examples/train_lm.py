@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.data.lm_pipeline import Prefetcher, synthetic_lm_batches
 from repro.models.transformer import TransformerConfig, init, loss_fn
+from repro.runtime import configure_compile_cache
 from repro.training.fault_tolerance import StragglerDetector, resume_or_init
 from repro.training.optimizer import adamw, cosine_schedule
 from repro.training.train_loop import (Trainer, TrainerConfig, init_state,
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    configure_compile_cache()
 
     # ~10M params — sized so a few hundred CPU steps visibly learn the
     # synthetic Markov stream; the same loop drives the pod-scale configs

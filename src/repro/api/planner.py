@@ -193,7 +193,12 @@ class PlannerConfig:
                                        # Bit-identical either way — this is a
                                        # memory-traffic knob, not a semantics
                                        # knob.
-    page_rows: int = 1 << 15          # rows per page tile in the paged regime
+    page_rows: int = 2048             # rows per page tile in the paged regime:
+                                      # at D=768 f32 the emb double buffer is
+                                      # 2*2048*768*4 B = 12 MiB, inside the
+                                      # 16 MiB scoped VMEM a TPU v5e kernel
+                                      # gets (4096 is refused there); a
+                                      # multiple of 128 (lane-major pages)
     cost_model: CostModel | None = None
     # serving-path hints (consumed by serving.scheduler + degrade_plan):
     deadline_ms: float | None = None  # per-query latency SLO; compile_plan

@@ -260,7 +260,7 @@ class RagDB:
             self.n_shards = n_shards
             hot_placement = ShardPlacement(n_shards=n_shards,
                                            capacity=hot_cfg.capacity,
-                                           kind=placement)
+                                           kind=placement, mesh=mesh, axes=ax)
         self.router = TieredRouter(
             hot_cfg, warm_cfg,
             hot_window_s=hot_window_s if tiered else _FOREVER,

@@ -99,7 +99,11 @@ def unified_query_ref(store: Store, q: jax.Array, pred: jax.Array, k: int):
     padded to k."""
     n = store["emb"].shape[0]
     mask = predicate_mask(store, pred)                            # (N,)
-    scores = q.astype(jnp.float32) @ store["emb"].astype(jnp.float32).T   # (B,N)
+    # exact f32, as in the arena-scan stages (a TPU's default f32 matmul
+    # rounds to bf16 and would rank differently from the pallas engine)
+    scores = jnp.matmul(q.astype(jnp.float32),
+                        store["emb"].astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)      # (B, N)
     scores = jnp.where(mask[None, :], scores, NEG_INF)
     k_eff = min(k, n)
     top_scores, top_idx = jax.lax.top_k(scores, k_eff)

@@ -57,7 +57,7 @@ class TieredRouter:
                  hot_window_s: int, now_ts: int, hot_placement=None):
         # hot_placement: optional core.store.ShardPlacement — a mesh-built
         # RagDB routes hot-tier slot allocation through per-shard regions
-        self.hot = TransactionLog(hot_cfg, empty(hot_cfg),
+        self.hot = TransactionLog(hot_cfg, empty(hot_cfg, hot_placement),
                                   placement=hot_placement)
         self.warm = SplitStackClient(warm_cfg)
         self.cold: dict[int, dict[str, Any]] = {}
@@ -81,7 +81,8 @@ class TieredRouter:
                             tfs=None if batch.tfs is None else batch.tfs[s])
 
         if len(idx_hot):
-            self.hot.ingest(take(idx_hot))
+            # an all-hot batch (single-tier mode) goes in as it is
+            self.hot.ingest(batch if len(idx_warm) == 0 else take(idx_hot))
         if len(idx_warm):
             self.warm.ingest(take(idx_warm))
 

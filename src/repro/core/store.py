@@ -19,6 +19,9 @@ structural reason the unified stack's inconsistency window is 0 by design.
 
 Capacity is a fixed pre-allocated arena (production stores pre-size their
 slabs the same way); `StoreConfig.capacity` rows, free slots carry tenant=-1.
+On a device mesh (`ShardPlacement` with a ``mesh``) every lane is
+row-sharded over the mesh axes from the moment it is allocated, so each
+device holds only its own contiguous region.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 Store = dict[str, Any]
 
@@ -41,7 +45,7 @@ class StoreConfig:
     n_acl_groups: int = 32
 
 
-def empty(cfg: StoreConfig) -> Store:
+def _empty_lanes(cfg: StoreConfig) -> Store:
     N, D = cfg.capacity, cfg.dim
     return {
         "emb": jnp.zeros((N, D), jnp.dtype(cfg.dtype)),
@@ -54,6 +58,15 @@ def empty(cfg: StoreConfig) -> Store:
         "commit_ts": jnp.int32(0),
         "n_live": jnp.int32(0),
     }
+
+
+def empty(cfg: StoreConfig, placement: "ShardPlacement | None" = None) -> Store:
+    """A fresh arena. With a mesh placement the lanes are built directly
+    in their sharded layout (never materialized whole on one device)."""
+    shardings = placement.shardings() if placement is not None else None
+    if shardings is None:
+        return _empty_lanes(cfg)
+    return jax.jit(lambda: _empty_lanes(cfg), out_shardings=shardings)()
 
 
 def normalize(cfg: StoreConfig, emb: jax.Array) -> jax.Array:
@@ -85,6 +98,8 @@ class ShardPlacement:
     n_shards: int
     capacity: int
     kind: str = "hash"            # "hash" | "tenant"
+    mesh: Any = None              # jax.sharding.Mesh the regions live on
+    axes: tuple[str, ...] = ()    # mesh axes the rows are sharded over
 
     def __post_init__(self):
         if self.kind not in ("hash", "tenant"):
@@ -96,6 +111,19 @@ class ShardPlacement:
     @property
     def rows_per_shard(self) -> int:
         return self.capacity // self.n_shards
+
+    def shardings(self) -> dict | None:
+        """Per-lane `NamedSharding`s of a mesh-placed arena (rows over
+        ``axes``, scalars replicated), or None without a mesh."""
+        if self.mesh is None:
+            return None
+        row = NamedSharding(self.mesh, P(self.axes))
+        rep = NamedSharding(self.mesh, P())
+        out = {k: row for k in ("tenant", "category", "updated_at", "acl",
+                                "doc_id", "version")}
+        out["emb"] = NamedSharding(self.mesh, P(self.axes, None))
+        out["commit_ts"] = out["n_live"] = rep
+        return out
 
     def region(self, shard: int) -> tuple[int, int]:
         """Slot range [start, stop) owned by ``shard``."""

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +31,8 @@ from repro.core.store import (DocBatch, ShardPlacement, Store, StoreConfig,
 # NOTE: no buffer donation on these programs — readers may pin old snapshots
 # (MVCC). A deployment that doesn't expose snapshots would donate for in-place
 # updates; that trade-off is deliberate and documented in DESIGN.md.
-@partial(jax.jit, static_argnames=("cfg",))
+# A mesh-placed log compiles them with its lanes' shardings as out_shardings
+# (`_commit_programs`), so a commit never gathers a sharded arena.
 def ingest(store: Store, cfg: StoreConfig, slots: jax.Array, batch_emb: jax.Array,
            tenant: jax.Array, category: jax.Array, updated_at: jax.Array,
            acl: jax.Array, doc_id: jax.Array) -> Store:
@@ -53,7 +53,6 @@ def ingest(store: Store, cfg: StoreConfig, slots: jax.Array, batch_emb: jax.Arra
     return new
 
 
-@partial(jax.jit, static_argnames=("cfg",))
 def update(store: Store, cfg: StoreConfig, slots: jax.Array, new_emb: jax.Array,
            updated_at: jax.Array) -> Store:
     """Re-embed existing documents (the staleness-critical path): the fresh
@@ -67,7 +66,6 @@ def update(store: Store, cfg: StoreConfig, slots: jax.Array, new_emb: jax.Array,
     return new
 
 
-@jax.jit
 def delete(store: Store, slots: jax.Array) -> Store:
     """Tombstone rows (tenant = -1 makes them invisible to every predicate)."""
     was_live = store["tenant"][slots] >= 0
@@ -78,6 +76,19 @@ def delete(store: Store, slots: jax.Array) -> Store:
     new["commit_ts"] = store["commit_ts"] + 1
     new["n_live"] = store["n_live"] - jnp.sum(was_live).astype(jnp.int32)
     return new
+
+
+def _commit_programs(shardings: dict | None):
+    """The jitted (ingest, update, delete) commit programs; with lane
+    ``shardings`` (a mesh-placed arena) every output lane keeps its
+    sharding."""
+    kw = {} if shardings is None else {"out_shardings": shardings}
+    return (jax.jit(ingest, static_argnames=("cfg",), **kw),
+            jax.jit(update, static_argnames=("cfg",), **kw),
+            jax.jit(delete, **kw))
+
+
+_PROGRAMS = _commit_programs(None)
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +155,9 @@ class TransactionLog:
         # (shard-local slot recycling — a freed slot can only be reused by a
         # doc that routes to the same shard, so placement never drifts).
         self.placement = placement
+        shardings = placement.shardings() if placement is not None else None
+        self._ingest, self._update, self._delete = (
+            _PROGRAMS if shardings is None else _commit_programs(shardings))
         if placement is not None:
             if placement.capacity != cfg.capacity:
                 raise ValueError("placement capacity != store capacity")
@@ -324,15 +338,17 @@ class TransactionLog:
         slots = jnp.asarray(slot_list, jnp.int32)
         self._crash("ingest", "prepare")
         t0 = time.perf_counter()
-        new = ingest(self._store, self.cfg, slots, batch.emb, batch.tenant,
-                     batch.category, batch.updated_at, batch.acl, batch.doc_id)
+        new = self._ingest(self._store, self.cfg, slots, batch.emb,
+                           batch.tenant, batch.category, batch.updated_at,
+                           batch.acl, batch.doc_id)
         jax.block_until_ready(new["commit_ts"])
         self.write_latencies_s.append(time.perf_counter() - t0)
         doc_ids = [int(d) for d in jax.device_get(batch.doc_id)]
         rec = IntentRecord(
             op="ingest", epoch=self.commit_count + 1, store=new,
             slot_updates=tuple(zip(doc_ids, slot_list)),
-            ivf_op=("add", slot_list, np.asarray(batch.emb)),
+            ivf_op=(("add", slot_list, np.asarray(batch.emb))
+                    if self.ivf is not None else None),
             lex_op=(slot_list,
                     None if batch.terms is None else np.asarray(batch.terms),
                     None if batch.tfs is None else np.asarray(batch.tfs)),
@@ -346,13 +362,15 @@ class TransactionLog:
         slots = jnp.asarray(slot_list, jnp.int32)
         self._crash("update", "prepare")
         t0 = time.perf_counter()
-        new = update(self._store, self.cfg, slots, new_emb, jnp.asarray(updated_at, jnp.int32))
+        new = self._update(self._store, self.cfg, slots, new_emb,
+                           jnp.asarray(updated_at, jnp.int32))
         jax.block_until_ready(new["commit_ts"])
         self.write_latencies_s.append(time.perf_counter() - t0)
         rec = IntentRecord(
             op="update", epoch=self.commit_count + 1, store=new,
             # re-embedded rows move to their new centroid
-            ivf_op=("add", slot_list, np.asarray(new_emb)))
+            ivf_op=(("add", slot_list, np.asarray(new_emb))
+                    if self.ivf is not None else None))
         self._wal = rec
         self._crash("update", "intent")
         self._publish(rec, inject=True)
@@ -365,7 +383,7 @@ class TransactionLog:
         slot_list = [self._slot_of_doc[d]
                      for d in dict.fromkeys(int(d) for d in doc_ids)]
         self._crash("delete", "prepare")
-        new = delete(self._store, jnp.asarray(slot_list, jnp.int32))
+        new = self._delete(self._store, jnp.asarray(slot_list, jnp.int32))
         jax.block_until_ready(new["commit_ts"])
         rec = IntentRecord(
             op="delete", epoch=self.commit_count + 1, store=new,

@@ -163,34 +163,46 @@ def stream_corpus(cfg: CorpusConfig, chunk_rows: int = 65_536):
     >>> int(chunks[1].doc_id[0])      # ids continue across chunks
     64
     """
-    start, chunk = 0, 0
-    while start < cfg.n_docs:
-        n = min(chunk_rows, cfg.n_docs - start)
-        rng = np.random.default_rng(
-            np.random.SeedSequence([cfg.seed, 0x57E4A, chunk]))
-        emb, tid = _topic_points(cfg, rng, n, with_topics=True)
-        tenant = rng.integers(0, cfg.n_tenants, n, dtype=np.int32)
-        category = rng.integers(0, cfg.n_categories, n, dtype=np.int32)
-        updated_at = rng.integers(0, cfg.days_span * DAY_S, n,
-                                  dtype=np.int64).astype(np.int32)
-        acl = np.zeros(n, dtype=np.uint32)
-        for _ in range(3):
-            bit = rng.integers(0, cfg.n_acl_groups, n)
-            on = rng.random(n) < 0.6
-            acl |= (np.uint32(1) << bit.astype(np.uint32)) * on.astype(np.uint32)
-        acl |= np.uint32(1) << rng.integers(
-            0, cfg.n_acl_groups, n).astype(np.uint32)
-        doc_id = np.arange(start, start + n, dtype=np.int32)
-        rng_lex = np.random.default_rng(
-            np.random.SeedSequence([cfg.seed, 0x7E45, chunk]))
-        terms, tfs = _doc_lexical(cfg, tid, rng_lex)
-        yield DocBatch(emb=jnp.asarray(emb), tenant=jnp.asarray(tenant),
-                       category=jnp.asarray(category),
-                       updated_at=jnp.asarray(updated_at),
-                       acl=jnp.asarray(acl), doc_id=jnp.asarray(doc_id),
-                       terms=jnp.asarray(terms), tfs=jnp.asarray(tfs))
-        start += n
-        chunk += 1
+    for chunk in range(-(-cfg.n_docs // chunk_rows)):
+        yield corpus_chunk(cfg, chunk, chunk_rows)
+
+
+def corpus_chunk(cfg: CorpusConfig, chunk: int,
+                 chunk_rows: int = 65_536) -> DocBatch:
+    """Chunk ``chunk`` of `stream_corpus` as a device `DocBatch`."""
+    return DocBatch(**{name: jnp.asarray(col) for name, col
+                       in chunk_columns(cfg, chunk, chunk_rows).items()})
+
+
+def chunk_columns(cfg: CorpusConfig, chunk: int,
+                  chunk_rows: int = 65_536) -> dict[str, np.ndarray]:
+    """Host (numpy) columns of chunk ``chunk`` of `stream_corpus`, keyed by
+    `DocBatch` field. Each chunk draws from its own derived rng stream, so
+    chunks may be generated out of order or on several threads and still
+    be byte-identical."""
+    start = chunk * chunk_rows
+    n = min(chunk_rows, cfg.n_docs - start)
+    rng = np.random.default_rng(
+        np.random.SeedSequence([cfg.seed, 0x57E4A, chunk]))
+    emb, tid = _topic_points(cfg, rng, n, with_topics=True)
+    tenant = rng.integers(0, cfg.n_tenants, n, dtype=np.int32)
+    category = rng.integers(0, cfg.n_categories, n, dtype=np.int32)
+    updated_at = rng.integers(0, cfg.days_span * DAY_S, n,
+                              dtype=np.int64).astype(np.int32)
+    acl = np.zeros(n, dtype=np.uint32)
+    for _ in range(3):
+        bit = rng.integers(0, cfg.n_acl_groups, n)
+        on = rng.random(n) < 0.6
+        acl |= (np.uint32(1) << bit.astype(np.uint32)) * on.astype(np.uint32)
+    acl |= np.uint32(1) << rng.integers(
+        0, cfg.n_acl_groups, n).astype(np.uint32)
+    doc_id = np.arange(start, start + n, dtype=np.int32)
+    rng_lex = np.random.default_rng(
+        np.random.SeedSequence([cfg.seed, 0x7E45, chunk]))
+    terms, tfs = _doc_lexical(cfg, tid, rng_lex)
+    return dict(emb=emb, tenant=tenant, category=category,
+                updated_at=updated_at, acl=acl, doc_id=doc_id, terms=terms,
+                tfs=tfs)
 
 
 def make_queries(cfg: CorpusConfig, n_queries: int, batch: int = 1, seed: int = 1) -> jax.Array:

@@ -191,8 +191,10 @@ class LexicalArena:
         idx = np.asarray(slots, np.int64).reshape(-1)
         if idx.size == 0:
             return
-        old_t = np.asarray(self._terms)[idx]
-        old_f = np.asarray(self._tfs)[idx]
+        dev = jnp.asarray(idx, jnp.int32)
+        # gather on device: only the written rows cross to the host
+        old_t = np.asarray(self._terms[dev])
+        old_f = np.asarray(self._tfs[dev])
         if (old_t >= 0).any():
             self.stats.remove(old_t, old_f)
         if terms is None:
@@ -205,7 +207,6 @@ class LexicalArena:
                 vocab_size=self.cfg.vocab_size)
         if (new_t >= 0).any():
             self.stats.add(new_t, new_f)
-        dev = jnp.asarray(idx, jnp.int32)
         self._terms = self._terms.at[dev].set(jnp.asarray(new_t))
         self._tfs = self._tfs.at[dev].set(jnp.asarray(new_f))
         self.commit_count += 1

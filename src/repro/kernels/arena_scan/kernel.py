@@ -4,8 +4,8 @@
 
   grid = (B_blocks, N_blocks)              # N innermost -> sequential scan
   per step:
-    VMEM tiles:  q (BLK_B, D), emb (BLK_N, D), meta (BLK_N, M) int32,
-                 [terms (BLK_N, T) int32, lexnorm (BLK_N, T) f32,
+    VMEM tiles:  q (BLK_B, D), emb (BLK_N, D), meta (M, BLK_N) int32,
+                 [terms (T, BLK_N) int32, lexnorm (T, BLK_N) f32,
                   qterms (BLK_B, QT) int32, qidf (BLK_B, QT) f32],
                  gids (BLK_B, 1), preds (G, 4) int32 (replicated)
     stages:      score (MXU dot [+ VPU BM25]) + mask (predicate groups via
@@ -19,7 +19,9 @@
 
   grid = (B_blocks,)                       # the page loop lives IN the body
   the arena streams (emb, meta [, terms, lexnorm]) stay in ANY memory
-  (HBM); each stream owns a (2, PAGE, width) VMEM scratch buffer and a
+  (HBM); each stream owns a double VMEM scratch buffer ((2, PAGE, D) for
+  emb, (2, width, PAGE) for the lane-major narrow streams, so every page
+  DMA slices the arena along an axis tiled in multiples of 128) and a
   2-slot DMA semaphore. The page loop overlaps copy with compute:
 
       start(page 0 -> slot 0)
@@ -40,8 +42,9 @@ and paged mode's merge schedule at page size P equals resident mode's (and
 the jnp streaming ref's) at blk_n = P — so one conformance matrix covers
 every (engine, regime, page size) cell (tests/test_arena_scan_conformance).
 
-CPU CI executes both regimes in interpret mode; compiled TPU runs are the
-standing ROADMAP follow-up.
+CPU CI executes both regimes in interpret mode; tests/test_tpu_compile.py
+compiles both for a described TPU v5e at D=768, and chip_smoke.py runs the
+resident regime compiled on the chip.
 """
 from __future__ import annotations
 
@@ -60,11 +63,11 @@ def _tile_step(spec: ScanSpec, k: int, scratch, q, e, meta, gids, preds,
                base, lex):
     """One tile through the shared stages: mask -> score -> merge into the
     running lists. ``base`` is the tile's arena offset (index source for
-    positional engines; slot-lane engines index from meta[:, 4])."""
+    positional engines; slot-lane engines index from meta[4])."""
     row_keep = tile_mask(spec, meta, preds, gids, onehot=True)
     signals = tile_signals(spec, q, e, row_keep, lex)
     if spec.slot_lane:
-        idx = jnp.broadcast_to(meta[:, 4][None, :], signals[0].shape)
+        idx = jnp.broadcast_to(meta[4:5, :], signals[0].shape)
     else:
         idx = base + jax.lax.broadcasted_iota(jnp.int32, signals[0].shape, 1)
     for (s_ref, i_ref), sig in zip(scratch, signals):
@@ -123,7 +126,8 @@ def _paged_kernel(gid_ref, pred_ref, q_ref, *refs, spec: ScanSpec, k: int,
     """The page loop with explicit double-buffered DMA (module docstring).
     Arg layout after the VMEM-resident smalls: [qterms, qidf,] HBM streams
     (emb, meta [, terms, lexnorm]), outputs, running-list scratch, then per
-    stream a (2, page, width) buffer + a 2-slot DMA semaphore."""
+    stream a double page buffer + a 2-slot DMA semaphore. emb pages slice
+    rows; the lane-major narrow streams slice columns."""
     if spec.has_lex:
         qterms_ref, qidf_ref, *refs = refs
         qlex = (qterms_ref[...], qidf_ref[...])
@@ -135,9 +139,10 @@ def _paged_kernel(gid_ref, pred_ref, q_ref, *refs, spec: ScanSpec, k: int,
     assert len(sems) == n_streams
 
     def copies(slot, p):
-        return [pltpu.make_async_copy(h.at[pl.ds(p * page, page)],
-                                      b.at[slot], s.at[slot])
-                for h, b, s in zip(hbm, bufs, sems)]
+        rows = pl.ds(p * page, page)
+        srcs = [hbm[0].at[rows]] + [h.at[:, rows] for h in hbm[1:]]
+        return [pltpu.make_async_copy(src, b.at[slot], s.at[slot])
+                for src, b, s in zip(srcs, bufs, sems)]
 
     _init_lists(scratch)
     q = q_ref[...]
@@ -175,14 +180,15 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                       blk_b: int = 8, blk_n: int = 512,
                       page_rows: int | None = None,
                       interpret: bool = False):
-    """The unified scan. q: (B, D); emb: (N, D); meta: (N, M) int32 with
-    M = `spec.meta_width`; gids: (B, 1) int32 group id per query row;
-    preds: (G, 4) int32 stacked lowered predicates; ``lex`` (when
-    `spec.has_lex`) is (terms (N, T) int32, lexnorm (N, T) f32,
+    """The unified scan. q: (B, D); emb: (N, D); meta: (M, N) int32,
+    lane-major, with M = `spec.meta_width`; gids: (B, 1) int32 group id per
+    query row; preds: (G, 4) int32 stacked lowered predicates; ``lex``
+    (when `spec.has_lex`) is (terms (T, N) int32, lexnorm (T, N) f32,
     qterms (B, QT) int32, qidf (B, QT) f32 — fusion weights pre-folded).
 
     B % blk_b == 0, D % 128 == 0, and N % blk_n == 0 (resident) or
-    N % page_rows == 0 (paged) — the family ops wrappers pad. Returns
+    N % page_rows == 0 (paged), with blk_n / page_rows multiples of 128 —
+    the family ops wrappers pad. Returns
     `spec.n_lists` (scores (B, k) f32, indices (B, k) i32) pairs,
     flattened. ``page_rows`` selects the paged regime; its merge schedule
     (and thus its bits) equals resident mode at blk_n = page_rows."""
@@ -191,7 +197,7 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
     G = preds.shape[0]
     M = spec.meta_width
     assert B % blk_b == 0, (B, blk_b)
-    assert meta.shape[1] == M, (meta.shape, M)
+    assert meta.shape[0] == M, (meta.shape, M)
     assert gids.shape == (B, 1), gids.shape
     n_lists = spec.n_lists
     out_shape = (jax.ShapeDtypeStruct((B, k), jnp.float32),
@@ -207,15 +213,15 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
             pl.BlockSpec((G, 4), lambda b, n: (0, 0)),       # preds
             pl.BlockSpec((blk_b, D), lambda b, n: (b, 0)),   # q
             pl.BlockSpec((blk_n, D), lambda b, n: (n, 0)),   # emb
-            pl.BlockSpec((blk_n, M), lambda b, n: (n, 0)),   # meta
+            pl.BlockSpec((M, blk_n), lambda b, n: (0, n)),   # meta
         ]
         inputs = [gids, preds, q, emb, meta]
         if spec.has_lex:
             terms, lexnorm, qterms, qidf = lex
-            T, QT = terms.shape[1], qterms.shape[1]
+            T, QT = terms.shape[0], qterms.shape[1]
             in_specs += [
-                pl.BlockSpec((blk_n, T), lambda b, n: (n, 0)),   # terms
-                pl.BlockSpec((blk_n, T), lambda b, n: (n, 0)),   # lexnorm
+                pl.BlockSpec((T, blk_n), lambda b, n: (0, n)),   # terms
+                pl.BlockSpec((T, blk_n), lambda b, n: (0, n)),   # lexnorm
                 pl.BlockSpec((blk_b, QT), lambda b, n: (b, 0)),  # qterms
                 pl.BlockSpec((blk_b, QT), lambda b, n: (b, 0)),  # qidf
             ]
@@ -234,25 +240,25 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
             pl.BlockSpec((blk_b, D), lambda b: (b, 0)),      # q
         ]
         inputs = [gids, preds, q]
-        stream_shapes = [(D, jnp.float32), (M, jnp.int32)]
+        stream_shapes = [((page, D), jnp.float32), ((M, page), jnp.int32)]
         if spec.has_lex:
             terms, lexnorm, qterms, qidf = lex
-            T, QT = terms.shape[1], qterms.shape[1]
+            T, QT = terms.shape[0], qterms.shape[1]
             in_specs += [
                 pl.BlockSpec((blk_b, QT), lambda b: (b, 0)),  # qterms
                 pl.BlockSpec((blk_b, QT), lambda b: (b, 0)),  # qidf
             ]
             inputs += [qterms, qidf]
-            stream_shapes += [(T, jnp.int32), (T, jnp.float32)]
+            stream_shapes += [((T, page), jnp.int32), ((T, page), jnp.float32)]
         # the arena streams stay HBM-resident; the body DMAs pages itself
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * len(stream_shapes)
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(stream_shapes)
         inputs += ([emb, meta, terms, lexnorm] if spec.has_lex
                    else [emb, meta])
         kernel = functools.partial(_paged_kernel, spec=spec, k=k, page=page,
                                    n_pages=N // page)
         out_spec = (pl.BlockSpec((blk_b, k), lambda b: (b, 0)),) * 2 * n_lists
         scratch = list(list_scratch)
-        scratch += [pltpu.VMEM((2, page, w), dt) for w, dt in stream_shapes]
+        scratch += [pltpu.VMEM((2,) + shape, dt) for shape, dt in stream_shapes]
         scratch += [pltpu.SemaphoreType.DMA((2,))] * len(stream_shapes)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -11,9 +11,11 @@ through these helpers, so the invariants live in exactly one place:
   * D pads to the 128-lane MXU multiple (padded dims contribute 0 to the
     dot), B pads to the blk_b multiple (row-parallel: padding rows cannot
     perturb real rows, and they are sliced off before returning);
-  * the (N, 4) metadata interleave is packed once per snapshot and
+  * the metadata columns and the (N, T) lexical lanes are packed
+    LANE-MAJOR — (4, N) meta, (T, N) lanes — once per snapshot and
     LRU-memoized on the column object ids (snapshot columns are immutable
-    — a write is only observable through NEW column arrays).
+    — a write is only observable through NEW column arrays). Lane-major is
+    what lets a page DMA slice the narrow streams along a 128-aligned axis.
 """
 from __future__ import annotations
 
@@ -28,30 +30,50 @@ BLK_SCAN = 32768
 
 
 def _pack_meta(tenant, updated_at, category, acl):
+    """Lane-major (4, N) int32 metadata: rows tenant, updated_at, category,
+    acl (bit pattern)."""
     return jnp.stack([tenant.astype(jnp.int32), updated_at.astype(jnp.int32),
                       category.astype(jnp.int32), acl.astype(jnp.int32)],
-                     axis=1)
+                     axis=0)
 
 
-#: Packed-metadata memo: keyed on the column object ids; entries HOLD the
-#: source columns so a key can never alias a freed array, and the tiny LRU
-#: bounds that retention to a few snapshots' worth of int32 columns (the
+#: Packed-lane memo: keyed on the source arrays' object ids; entries HOLD
+#: the sources so a key can never alias a freed array, and the tiny LRU
+#: bounds that retention to a few snapshots' worth of narrow columns (the
 #: embedding matrix is never held).
 _META_CACHE: OrderedDict[tuple, tuple] = OrderedDict()
 _META_CACHE_CAP = 4
 
 
-def _packed_meta(tenant, updated_at, category, acl):
-    key = (id(tenant), id(updated_at), id(category), id(acl))
+def _memo_packed(fn, *cols):
+    if any(isinstance(c, jax.core.Tracer) for c in cols):
+        return fn(*cols)            # under an outer jit: nothing to reuse
+    key = (fn.__name__,) + tuple(id(c) for c in cols)
     hit = _META_CACHE.get(key)
     if hit is not None:
         _META_CACHE.move_to_end(key)
         return hit[0]
-    meta = _pack_meta(tenant, updated_at, category, acl)
-    _META_CACHE[key] = (meta, tenant, updated_at, category, acl)
+    packed = fn(*cols)
+    _META_CACHE[key] = (packed,) + cols
     while len(_META_CACHE) > _META_CACHE_CAP:
         _META_CACHE.popitem(last=False)
-    return meta
+    return packed
+
+
+def _packed_meta(tenant, updated_at, category, acl):
+    """`_pack_meta`, memoized per snapshot."""
+    return _memo_packed(_pack_meta, tenant, updated_at, category, acl)
+
+
+def _lanes_t(terms, lexnorm):
+    return (jnp.asarray(terms, jnp.int32).T,
+            jnp.asarray(lexnorm, jnp.float32).T)
+
+
+def _packed_lanes(terms, lexnorm):
+    """(N, T) lexical lanes -> lane-major (T, N) pair, memoized per
+    snapshot."""
+    return _memo_packed(_lanes_t, terms, lexnorm)
 
 
 def _pad_axis0(x, mult, fill):
@@ -62,24 +84,31 @@ def _pad_axis0(x, mult, fill):
     return jnp.pad(x, widths, constant_values=fill)
 
 
+def _pad_cols(x, mult, fill):
+    pad = (-x.shape[1]) % mult
+    if pad == 0:
+        return x
+    return jnp.pad(x, ((0, 0), (0, pad)), constant_values=fill)
+
+
 def pad_dead_rows(emb, meta, mult: int, terms=None, lexnorm=None):
     """Pad the arena streams to the tile multiple with DEAD rows
     (tenant = -1 — no predicate group can keep them; slot-lane metas also
-    get slot = -1 via the full dead row). Returns the padded streams."""
+    get slot = -1). ``meta`` and the lexical lanes are lane-major (W, N):
+    their rows pad along axis 1. Returns the padded streams."""
     n = emb.shape[0]
     emb = _pad_axis0(emb, mult, 0)
-    meta = _pad_axis0(meta, mult, 0)
-    if meta.shape[0] != n:
-        dead_row = jnp.full((meta.shape[1],), 0, jnp.int32)
-        dead_row = dead_row.at[0].set(-1)
-        if meta.shape[1] > 4:
-            dead_row = dead_row.at[4].set(-1)
-        dead = jnp.arange(meta.shape[0]) >= n
-        meta = jnp.where(dead[:, None], dead_row[None, :], meta)
+    meta = _pad_cols(meta, mult, 0)
+    if meta.shape[1] != n:
+        dead_col = jnp.zeros((meta.shape[0],), jnp.int32).at[0].set(-1)
+        if meta.shape[0] > 4:
+            dead_col = dead_col.at[4].set(-1)
+        dead = jnp.arange(meta.shape[1]) >= n
+        meta = jnp.where(dead[None, :], dead_col[:, None], meta)
     if terms is None:
         return emb, meta
-    return (emb, meta, _pad_axis0(terms, mult, -1),
-            _pad_axis0(lexnorm, mult, 0))
+    return (emb, meta, _pad_cols(terms, mult, -1),
+            _pad_cols(lexnorm, mult, 0))
 
 
 def pad_d128(q, emb):
@@ -93,13 +122,16 @@ def pad_d128(q, emb):
 
 
 def default_use_kernel(use_kernel: bool | None) -> bool:
-    """Pallas on a TPU backend, the jnp streaming scan elsewhere."""
+    """Pallas on a TPU backend, the jnp streaming scan elsewhere. There is
+    no fallback the other way: on a TPU the compiled kernel runs or the
+    call raises."""
     if use_kernel is None:
         return jax.default_backend() == "tpu"
     return use_kernel
 
 
 def default_interpret(interpret: bool | None) -> bool:
+    """Interpret-mode Pallas off a TPU (tests), compiled Mosaic on one."""
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret

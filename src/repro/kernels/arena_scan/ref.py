@@ -59,7 +59,7 @@ def arena_scan_ref(q, emb, meta, gids, preds, k: int, *,
     row_keep = tile_mask(spec, meta, preds, gids, onehot=False)
     signals = tile_signals(spec, q, emb, row_keep, lex, barrier=True)
     if spec.slot_lane:
-        idx_src = meta[:, 4]
+        idx_src = meta[4]
     else:
         idx_src = jnp.arange(n, dtype=jnp.int32)
     k_eff = min(k, n)
@@ -77,7 +77,8 @@ def arena_scan_scan_ref(q, emb, meta, gids, preds, k: int, blk_n: int, *,
                         lex: tuple | None = None):
     """Streaming scan: `lax.scan` over (blk_n,)-row tiles, LOCAL top-k per
     running list, one final merge over the (tiles*k)-wide candidates.
-    Never materializes (B, N). N % blk_n == 0 (family ops pad).
+    Never materializes (B, N). N % blk_n == 0 (family ops pad). ``meta``
+    and the lexical lanes are lane-major (W, N), as in every engine.
 
     Bit-identity with the oracle is by construction: same stage functions,
     tiling splits N only, and `lax.top_k` breaks ties toward the lower
@@ -88,14 +89,17 @@ def arena_scan_scan_ref(q, emb, meta, gids, preds, k: int, blk_n: int, *,
     q, gids, lex = _pad_b(q, gids, lex)
     assert n % blk_n == 0, (n, blk_n)
     n_tiles = n // blk_n
+
+    def tiled(lanes):          # lane-major (W, N) -> (tiles, W, blk_n)
+        return jnp.moveaxis(lanes.reshape(lanes.shape[0], n_tiles, blk_n),
+                            1, 0)
+
     emb_t = emb.reshape(n_tiles, blk_n, emb.shape[1])
-    meta_t = meta.reshape(n_tiles, blk_n, meta.shape[1])
     base_t = jnp.arange(n_tiles, dtype=jnp.int32) * blk_n
-    tiles = (emb_t, meta_t, base_t)
+    tiles = (emb_t, tiled(meta), base_t)
     if spec.has_lex:
         terms, lexnorm, qterms, qidf = lex
-        tiles += (terms.reshape(n_tiles, blk_n, terms.shape[1]),
-                  lexnorm.reshape(n_tiles, blk_n, lexnorm.shape[1]))
+        tiles += (tiled(terms), tiled(lexnorm))
     k_loc = min(k, blk_n)
 
     def step(_, tile):
@@ -104,7 +108,7 @@ def arena_scan_scan_ref(q, emb, meta, gids, preds, k: int, blk_n: int, *,
         row_keep = tile_mask(spec, m, preds, gids, onehot=False)
         signals = tile_signals(spec, q, e, row_keep, lex_tile, barrier=True)
         if spec.slot_lane:
-            idx_src = jnp.broadcast_to(m[:, 4][None, :], signals[0].shape)
+            idx_src = jnp.broadcast_to(m[4:5, :], signals[0].shape)
         out = []
         for sig in signals:
             s, pos = jax.lax.top_k(sig, k_loc)

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.arena_scan.ref import _pad_b
@@ -130,7 +129,7 @@ def make_sharded_arena_scan(mesh, axes, n_rows: int, k: int, *,
             meta = jnp.stack([store_l["tenant"].astype(jnp.int32),
                               store_l["updated_at"].astype(jnp.int32),
                               store_l["category"].astype(jnp.int32),
-                              store_l["acl"].astype(jnp.int32)], axis=1)
+                              store_l["acl"].astype(jnp.int32)], axis=0)
             row_keep = tile_mask(spec, meta, pred_l[None, :], gids,
                                  onehot=False)
             sig, = tile_signals(spec, q_p, store_l["emb"], row_keep,
@@ -166,9 +165,10 @@ def make_sharded_arena_scan(mesh, axes, n_rows: int, k: int, *,
     store_specs = {"emb": P(ax, None), "tenant": row, "category": row,
                    "updated_at": row, "acl": row, "doc_id": row,
                    "version": row, "commit_ts": P(), "n_live": P()}
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(store_specs, P(), P()),
-                     out_specs=(P(), P(), P(ax)), check_rep=False)
+    return jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                                 in_specs=(store_specs, P(), P()),
+                                 out_specs=(P(), P(), P(ax)),
+                                 check_vma=False))
 
 
 def sharded_collective_bytes(fn, store, q, pred) -> int:

@@ -4,8 +4,10 @@ Pallas kernel body, the jnp streaming scan, and the dense oracle.
 Bit-identity between the three engines is BY CONSTRUCTION, not luck, and
 this file is the construction: every engine calls the same stage functions
 on the same tile values in the same order, tiling splits the arena axis N
-only (never the contraction axis D), and `lax.top_k` breaks ties toward the
-lower index locally and in every merge.
+only (never the contraction axis D), and every selection (`lax.top_k` in the
+XLA engines, `merge_topk` in the kernel) breaks ties toward the lower index
+locally and in every merge. Metadata and lexical lanes are LANE-MAJOR
+((W, n) tiles) in every engine.
 
 Floating-point pinning — the two rules that make the fused score
 bit-stable across DIFFERENT surrounding programs (a Pallas interpret loop,
@@ -89,70 +91,92 @@ def merge_topk(best_s, best_i, scores, idx, k: int):
 
     Ties break toward the lower concatenation position — running list
     first, then tile index order — which is what keeps every engine's
-    winner set identical to the dense oracle's single `top_k`."""
+    winner set identical to the dense oracle's single `top_k`.
+
+    Mosaic has no `top_k` lowering, so the selection is k rounds of: row
+    max, lowest position holding it, mask that position. Every step is an
+    exact compare/select (no arithmetic on scores), so the result is the
+    same (value, index) sequence `lax.top_k` returns."""
     all_s = jnp.concatenate([best_s, scores], axis=1)
     all_i = jnp.concatenate([best_i, idx], axis=1)
-    new_s, sel = jax.lax.top_k(all_s, k)
-    # gather indices via comparison one-hot (Mosaic-safe; avoids dyn-gather)
-    m = all_s.shape[1]
-    onehot = sel[:, :, None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, m), 2)
-    new_i = jnp.sum(jnp.where(onehot, all_i[:, None, :], 0), axis=2)
+    b, m = all_s.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (b, m), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
+
+    def round_(r, carry):
+        cand, new_s, new_i = carry
+        top = jnp.max(cand, axis=1, keepdims=True)                  # (B, 1)
+        at = jnp.min(jnp.where(cand == top, pos, m), axis=1, keepdims=True)
+        hit = pos == at
+        top_i = jnp.max(jnp.where(hit, all_i, jnp.iinfo(jnp.int32).min),
+                        axis=1, keepdims=True)
+        new_s = jnp.where(col == r, top, new_s)
+        new_i = jnp.where(col == r, top_i, new_i)
+        return jnp.where(hit, -jnp.inf, cand), new_s, new_i
+
+    init = (all_s, jnp.full((b, k), NEG_INF, jnp.float32),
+            jnp.full((b, k), -1, jnp.int32))
+    _, new_s, new_i = jax.lax.fori_loop(0, k, round_, init)
     return new_s, new_i
 
 
 def dense_scores(q, e):
     """Similarity stage (MXU): (B, D) x (n, D) -> (B, n) f32 dot product.
     The contraction axis D is never tiled, so every engine computes the
-    same per-element reduction."""
+    same per-element reduction. Precision is pinned to exact f32: on a TPU
+    the default f32 matmul rounds its inputs to bf16, and XLA and Mosaic
+    could then rank the same rows differently (on CPU this changes no
+    bits)."""
     return jax.lax.dot_general(q.astype(jnp.float32), e.astype(jnp.float32),
                                (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
 def bm25_scores(terms, lexnorm, qterms, qidf):
     """Lexical stage (VPU): masked-gather BM25 over one tile's postings
-    lanes. terms: (n, T) int32 lane term ids (-1 empty); lexnorm: (n, T)
-    f32 per-lane tf/length weight; qterms: (B, QT) int32 (-1 padding);
-    qidf: (B, QT) f32 per-term idf (0 on padding, fusion weight already
-    folded in). Returns (B, n) f32.
+    lanes, LANE-MAJOR. terms: (T, n) int32 lane term ids (-1 empty);
+    lexnorm: (T, n) f32 per-lane tf/length weight; qterms: (B, QT) int32
+    (-1 padding); qidf: (B, QT) f32 per-term idf (0 on padding, fusion
+    weight already folded in). Returns (B, n) f32.
 
     The accumulation order is FIXED — lanes outer, query terms inner — and
     the lane product is select-guarded (see module docstring, rule 2), so
-    the sum is the same IEEE value in every fusion context. Padding
-    safety: a padding query term (-1) can only "match" an empty doc lane
-    (-1), and its gathered idf is 0, so it contributes exactly 0.0."""
-    blk_b = qterms.shape[0]
-    blk_n = terms.shape[0]
-    bm25 = jnp.zeros((blk_b, blk_n), jnp.float32)
-    for t in range(terms.shape[1]):
-        lane = terms[:, t]
-        ln = lexnorm[:, t]
-        w = jnp.zeros((blk_b, blk_n), jnp.float32)
+    the sum is the same IEEE value in every fusion context. Lanes are
+    static (1, n) row slices of the lane-major tile — a sublane pick, where
+    a column slice of an (n, T) tile would be a relayout per lane.
+    Padding safety: a padding query term (-1) can only "match" an empty
+    doc lane (-1), and its gathered idf is 0, so it contributes exactly
+    0.0."""
+    bm25 = jnp.zeros((qterms.shape[0], terms.shape[1]), jnp.float32)
+    for t in range(terms.shape[0]):
+        lane = terms[t:t + 1, :]                                   # (1, n)
+        w = jnp.zeros_like(bm25)
         for j in range(qterms.shape[1]):
-            hit = lane[None, :] == qterms[:, j][:, None]
-            w = w + jnp.where(hit, qidf[:, j][:, None], 0.0)
-        bm25 = bm25 + jnp.where(w != 0.0, w * ln[None, :], 0.0)
+            w = w + jnp.where(lane == qterms[:, j:j + 1], qidf[:, j:j + 1], 0.0)
+        bm25 = bm25 + jnp.where(w != 0.0, w * lexnorm[t:t + 1, :], 0.0)
     return bm25
 
 
 def predicate_keep(meta, preds):
     """Mask stage: all G engine-level WHERE clauses over one metadata tile,
-    one broadcast pass. meta: (n, >=4) int32 [tenant, updated_at, category,
-    acl, ...]; preds: (G, 4) int32 stacked `Predicate.as_array()` rows.
+    one broadcast pass. meta: (>=4, n) int32, LANE-MAJOR rows [tenant,
+    updated_at, category, acl, ...] (a page of it is an aligned (M, page)
+    slice); preds: (G, 4) int32 stacked `Predicate.as_array()` rows.
     Returns (G, n) bool — row is live AND satisfies group g's clauses."""
-    tenant = meta[:, 0]
-    ts = meta[:, 1]
-    cat = meta[:, 2]
-    acl = meta[:, 3]
-    p_tenant = preds[:, 0][:, None]
-    p_ts = preds[:, 1][:, None]
-    p_cat = preds[:, 2][:, None]
-    p_acl = preds[:, 3][:, None]
-    keep = (tenant >= 0)[None, :]                          # live rows only
-    keep &= (p_tenant == -2) | (tenant[None, :] == p_tenant)  # tenant isolation
-    keep &= ts[None, :] >= p_ts                            # freshness
-    keep &= (jnp.left_shift(1, cat)[None, :] & p_cat) != 0    # category set
-    keep &= (acl[None, :] & p_acl) != 0                    # ACL groups
+    tenant = meta[0:1, :]
+    ts = meta[1:2, :]
+    cat = meta[2:3, :]
+    acl = meta[3:4, :]
+    p_tenant = preds[:, 0:1]
+    p_ts = preds[:, 1:2]
+    p_cat = preds[:, 2:3]
+    p_acl = preds[:, 3:4]
+    keep = tenant >= 0                                     # live rows only
+    keep &= (p_tenant == -2) | (tenant == p_tenant)        # tenant isolation
+    keep &= ts >= p_ts                                     # freshness
+    keep &= (jnp.left_shift(1, cat) & p_cat) != 0          # category set
+    keep &= (acl & p_acl) != 0                             # ACL groups
     return keep
 
 
@@ -178,7 +202,7 @@ def tile_mask(spec: ScanSpec, meta, preds, gids, *, onehot: bool):
     keep = predicate_keep(meta, preds)
     row_keep = row_keep_onehot(keep, gids) if onehot else keep[gids]
     if spec.slot_lane:
-        row_keep &= (meta[:, 4] >= 0)[None, :]             # member padding out
+        row_keep &= meta[4:5, :] >= 0                      # member padding out
     return row_keep
 
 

@@ -74,8 +74,8 @@ def decode_attention_sharded(mesh: Mesh, seq_axis: str | tuple[str, ...],
         out = acc_glob / jnp.maximum(l_glob, 1e-30)
         return out.reshape(B, H, hd).astype(q_l.dtype)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), P(None, axes), P(None, axes), P()),
-                   out_specs=P(), check_rep=False)  # pallas outs carry no rep info
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), P(None, axes), P(None, axes), P()),
+                       out_specs=P(),
+                       check_vma=False)  # pallas outs carry no vma info
     return fn(q, k_cache, v_cache, lengths)

@@ -29,7 +29,8 @@ def filtered_topk_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                          blk_b: int = 8, blk_n: int = 512,
                          page_rows: int | None = None,
                          interpret: bool = False):
-    """q: (B, D); emb: (N, D); meta: (N, 4) int32 [tenant, ts, cat, acl];
+    """q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major rows
+    [tenant, ts, cat, acl];
     pred: (4,) int32. B % blk_b == 0, N % blk_n == 0 (or N % page_rows == 0
     in the paged regime), D % 128 == 0 (the ops.py wrapper pads). Returns
     (scores (B, k) f32, slots (B, k) i32)."""

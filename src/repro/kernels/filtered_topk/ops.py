@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.arena_scan.ops import (_pack_meta, _pad_axis0,  # noqa: F401
-                                          pad_dead_rows, pad_d128)
+                                          default_interpret, pad_dead_rows,
+                                          pad_d128)
 from repro.kernels.filtered_topk.filtered_topk import (NEG_INF,
                                                        filtered_topk_pallas)
 
@@ -52,8 +53,7 @@ def filtered_topk(q, emb, tenant, updated_at, category, acl, pred, k: int,
     """Single-device entry point (contract of core.query.unified_query).
     ``page_rows`` selects the kernel's paged (HBM-resident, double-buffered
     DMA) regime; bits are unchanged (see arena_scan.kernel)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = default_interpret(interpret)
     if k > emb.shape[0]:   # LIMIT larger than the arena: SQL semantics
         k_eff = emb.shape[0]
         s, i = filtered_topk(q, emb, tenant, updated_at, category, acl, pred,
@@ -74,11 +74,11 @@ def filtered_topk_sharded(mesh: Mesh, axis: str | tuple[str, ...],
                           interpret: bool | None = None):
     """Distributed unified query over a row-sharded corpus.
 
-    emb (N, D) and meta (N, 4) sharded along axis; q replicated.
+    emb (N, D) sharded along its rows and lane-major meta (4, N) along its
+    columns over ``axis``; q replicated.
     Returns (scores (B, k), GLOBAL slots (B, k)).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = default_interpret(interpret)
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     n_shards = 1
     for a in axes:
@@ -103,8 +103,8 @@ def filtered_topk_sharded(mesh: Mesh, axis: str | tuple[str, ...],
         top_i = jnp.take_along_axis(i_all, pos, axis=1)
         return top_s, jnp.where(top_s > jnp.float32(NEG_INF), top_i, -1)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), P(axes), P(axes), P()),
-                   out_specs=(P(), P()), check_rep=False)  # pallas outs carry no rep info
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), P(axes), P(None, axes), P()),
+                       out_specs=(P(), P()),
+                       check_vma=False)  # pallas outs carry no vma info
     return fn(q, emb, meta, pred)

@@ -29,7 +29,7 @@ NEG_INF = jnp.float32(jnp.finfo(jnp.float32).min)
 def group_masks(meta: jax.Array, preds: jax.Array) -> jax.Array:
     """All G engine-level WHERE clauses over one metadata block, one pass.
 
-    meta: (N, 4) int32 [tenant, updated_at, category, acl];
+    meta: (4, N) int32 lane-major rows [tenant, updated_at, category, acl];
     preds: (G, 4) int32 stacked `Predicate.as_array()` rows.
     Returns (G, N) bool — row n is live AND satisfies group g's clauses.
     (Alias of the framework's `predicate_keep` mask stage.)
@@ -40,7 +40,7 @@ def group_masks(meta: jax.Array, preds: jax.Array) -> jax.Array:
 @partial(jax.jit, static_argnames=("k",))
 def grouped_topk_ref(q: jax.Array, emb: jax.Array, meta: jax.Array,
                      gids: jax.Array, preds: jax.Array, k: int):
-    """Dense oracle. q: (B, D); emb: (N, D); meta: (N, 4) int32; gids: (B,)
+    """Dense oracle. q: (B, D); emb: (N, D); meta: (4, N) int32; gids: (B,)
     int32 group id per query row (values in [0, G)); preds: (G, 4) int32.
     Returns (scores (B, k) f32, slots (B, k) i32, -1 past the fill)."""
     s, i = arena_scan_ref(q, emb, meta, gids, preds, k,

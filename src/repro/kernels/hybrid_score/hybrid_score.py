@@ -39,9 +39,10 @@ This family is the unified arena-scan framework's lexical configuration
 body, both residency regimes (resident BlockSpec pipelining / paged
 double-buffered DMA), and the running top-k merges live in the framework.
 
-CPU CI executes this body in interpret mode only (bit-identity vs the jnp
-refs); running it compiled on a real TPU rig is a ROADMAP follow-up,
-mirroring ivf_probe / grouped_topk.
+CPU CI executes this body in interpret mode (bit-identity vs the jnp
+refs); tests/test_tpu_compile.py compiles it for a described TPU v5e at
+D=768, T=16, QT=16 at the wrapper's fixed tile (blk_n=512), and
+chip_smoke.py runs it compiled on the chip.
 """
 from __future__ import annotations
 
@@ -60,7 +61,8 @@ def hybrid_score_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                         w_lex: float = 1.0, blk_b: int = 8, blk_n: int = 512,
                         page_rows: int | None = None,
                         interpret: bool = False):
-    """q: (B, D); emb: (N, D); meta: (N, 4) int32; terms/lexnorm: (N, T);
+    """q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major;
+    terms/lexnorm: (T, N) lane-major;
     gids: (B, 1) int32; preds: (G, 4) int32; qterms: (B, QT) int32 (-1
     padding); qidf: (B, QT) f32 (0 on padding). B % blk_b == 0, N % blk_n
     == 0 (or N % page_rows == 0 in the paged regime), D % 128 == 0 (the

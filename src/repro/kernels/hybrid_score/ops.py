@@ -25,8 +25,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.arena_scan.ops import (_packed_meta, _pad_axis0,
-                                          default_blk_n, default_interpret,
+from repro.kernels.arena_scan.ops import (_packed_lanes, _packed_meta,
+                                          _pad_axis0, default_blk_n,
+                                          default_interpret,
                                           default_use_kernel, pad_d128,
                                           pad_dead_rows)
 from repro.kernels.hybrid_score.hybrid_score import hybrid_score_pallas
@@ -123,8 +124,8 @@ def hybrid_score(q, emb, tenant, updated_at, category, acl, terms, lexnorm,
                      else jnp.pad(a, pad, constant_values=-1)
                      for j, a in enumerate(out))
     meta = _packed_meta(tenant, updated_at, category, acl)
-    return _run(jnp.asarray(q), emb, meta, jnp.asarray(terms, jnp.int32),
-                jnp.asarray(lexnorm, jnp.float32),
+    terms_t, lexnorm_t = _packed_lanes(terms, lexnorm)
+    return _run(jnp.asarray(q), emb, meta, terms_t, lexnorm_t,
                 jnp.asarray(idf, jnp.float32),
                 jnp.asarray(gids, jnp.int32), jnp.asarray(preds, jnp.int32),
                 jnp.asarray(qterms, jnp.int32), k, mode, float(w_dense),

@@ -52,11 +52,12 @@ def qidf_of(idf: jax.Array, qterms: jax.Array) -> jax.Array:
 
 def bm25_block(terms: jax.Array, lexnorm: jax.Array, qterms: jax.Array,
                qidf: jax.Array) -> jax.Array:
-    """Masked-gather BM25 over one block of postings lanes — the arena-scan
-    framework's lexical score stage (see `arena_scan.stages.bm25_scores`
-    for the fixed accumulation order and the select-guarded lane product
-    that pin its bits across fusion contexts). Returns (B, N) f32."""
-    return bm25_scores(terms, lexnorm, qterms, qidf)
+    """Masked-gather BM25 over one block of (N, T) postings lanes — the
+    arena-scan framework's lexical score stage on the row-major layout the
+    split-stack scorers hold (see `arena_scan.stages.bm25_scores` for the
+    fixed accumulation order and the select-guarded lane product that pin
+    its bits across fusion contexts). Returns (B, N) f32."""
+    return bm25_scores(terms.T, lexnorm.T, qterms, qidf)
 
 
 def rrf_fuse(ds: jax.Array, di: jax.Array, ls: jax.Array, li: jax.Array,
@@ -110,8 +111,8 @@ def _fold(q, qidf, mode, w_dense, w_lex):
 def hybrid_score_ref(q, emb, meta, terms, lexnorm, gids, preds, qterms, qidf,
                      k: int, mode: str = "wsum", w_dense: float = 1.0,
                      w_lex: float = 1.0, rrf_c: float = 60.0):
-    """Dense oracle. q: (B, D); emb: (N, D); meta: (N, 4) int32; terms /
-    lexnorm: (N, T); gids: (B,) int32; preds: (G, 4) int32; qterms: (B, QT)
+    """Dense oracle. q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major;
+    terms / lexnorm: (T, N) lane-major; gids: (B,) int32; preds: (G, 4) int32; qterms: (B, QT)
     int32; qidf: (B, QT) f32. Returns (scores (B, k) f32, slots (B, k) i32)
     for ``wsum`` and the fused RRF lists for ``rrf``."""
     q, qidf = _fold(q, qidf, mode, w_dense, w_lex)

@@ -34,8 +34,8 @@ def ivf_probe_pallas(q: jax.Array, cand_emb: jax.Array, cand_meta: jax.Array,
                      blk_b: int = 8, blk_p: int = 256,
                      page_rows: int | None = None,
                      interpret: bool = False):
-    """q: (B, D); cand_emb: (P, D); cand_meta: (P, 5) int32
-    [tenant, ts, cat, acl, arena_slot]; pred: (4,) int32.
+    """q: (B, D); cand_emb: (P, D); cand_meta: (5, P) int32 lane-major
+    rows [tenant, ts, cat, acl, arena_slot]; pred: (4,) int32.
     B % blk_b == 0, P % blk_p == 0 (or P % page_rows == 0 in the paged
     regime), D % 128 == 0 (the ops.py wrapper pads).
     Returns (scores (B, k) f32, arena slots (B, k) i32)."""

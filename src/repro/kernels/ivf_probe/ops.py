@@ -4,7 +4,7 @@ Handles candidate assembly + padding + engine dispatch:
 
   probed cluster ids (deduplicated union for ONE predicate group)
     -> member-table rows (U, cap) + the exact-scan overflow tail
-    -> ONE (P, D) embedding / (P, 5) metadata gather for the whole group
+    -> ONE (P, D) embedding / (5, P) metadata gather for the whole group
     -> fused probe (Pallas on TPU, jnp ref elsewhere): mask + score + running
        top-k over arena slots
 
@@ -25,7 +25,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.arena_scan.ops import (_pad_axis0, pad_d128,
+from repro.kernels.arena_scan.ops import (_pad_axis0, default_interpret,
+                                          default_use_kernel, pad_d128,
                                           pad_dead_rows)
 from repro.kernels.ivf_probe.ivf_probe import ivf_probe_pallas
 from repro.kernels.ivf_probe.ref import NEG_INF, ivf_probe_ref
@@ -35,7 +36,7 @@ def _assemble(emb, tenant, updated_at, category, acl, members, overflow,
               clusters):
     """Candidate rows for one predicate group: the probed clusters' member
     slots plus the overflow tail, with arena-side metadata. Returns
-    (cand_emb (P, D), cand_meta (P, 5) int32)."""
+    (cand_emb (P, D), cand_meta (5, P) int32, lane-major)."""
     n = emb.shape[0]
     m = members[jnp.maximum(clusters, 0)]                  # (U, cap)
     m = jnp.where((clusters >= 0)[:, None], m, -1)         # cluster-list pad
@@ -49,7 +50,7 @@ def _assemble(emb, tenant, updated_at, category, acl, members, overflow,
         category[safe],
         acl[safe].astype(jnp.int32),
         cand,
-    ], axis=1)
+    ], axis=0)
     return emb[safe], meta
 
 
@@ -89,10 +90,8 @@ def ivf_probe(q, emb, tenant, updated_at, category, acl, members, overflow,
     ref elsewhere; tests pass ``use_kernel=True, interpret=True`` to execute
     the kernel body on CPU.
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    use_kernel = default_use_kernel(use_kernel)
+    interpret = default_interpret(interpret)
     n_cand = members.shape[1] * clusters.shape[0] + overflow.shape[0]
     n_cand_padded = n_cand + ((-n_cand) % blk_p)
     if n_cand_padded == 0:          # empty candidate set: nothing qualifies

@@ -29,7 +29,8 @@ def ivf_probe_ref(q: jax.Array, cand_emb: jax.Array, cand_meta: jax.Array,
                   pred: jax.Array, k: int):
     """q: (B, D); cand_emb: (P, D) — the probed clusters' member rows,
     gathered ONCE for the whole predicate group (never per query row);
-    cand_meta: (P, 5) int32 [tenant, updated_at, category, acl, arena_slot]
+    cand_meta: (5, P) int32 lane-major rows [tenant, updated_at, category,
+    acl, arena_slot]
     (slot < 0 marks member-table padding); pred: (4,) int32.
     Returns (scores (B, k) f32, arena slots (B, k) i32, -1 past the fill)."""
     gids = jnp.zeros((q.shape[0],), jnp.int32)

@@ -11,24 +11,15 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`jax.sharding.AxisType` only exists on newer jax (>= 0.5); on the
-    pinned 0.4.x rig every mesh axis is Auto by default, so the kwarg is
-    simply omitted — same semantics both ways."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):

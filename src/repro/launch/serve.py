@@ -25,6 +25,9 @@ def main():
     ap.add_argument("--engine", default="ref", choices=["ref", "pallas"])
     args = ap.parse_args()
 
+    from repro.runtime import configure_compile_cache
+    configure_compile_cache()
+
     from repro.configs import get
     from repro.core import Principal, StoreConfig, TransactionLog, empty
     from repro.data.corpus import DAY_S, CorpusConfig, make_corpus

@@ -458,8 +458,8 @@ def _rag_cell(arch: Arch, shape: dict, mesh: Mesh) -> Cell:
     M = shape["batch"]
 
     def fn(store, slots, emb, tenant, category, updated_at, acl, doc_id):
-        return txn.ingest.__wrapped__(store, scfg, slots, emb, tenant, category,
-                                      updated_at, acl, doc_id)
+        return txn.ingest(store, scfg, slots, emb, tenant, category,
+                          updated_at, acl, doc_id)
 
     args = (store_sds, _sds((M,), jnp.int32), _sds((M, D), jnp.float32),
             _sds((M,), jnp.int32), _sds((M,), jnp.int32), _sds((M,), jnp.int32),

@@ -234,7 +234,6 @@ def make_vp_loss_fn(cfg: TransformerConfig, mesh, *, tp_axis: str = "model"):
     Collective payload per token: 3 scalars — independent of vocab size.
     """
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp_axes = tuple(a for a in mesh.axis_names if a != tp_axis)
@@ -270,10 +269,10 @@ def make_vp_loss_fn(cfg: TransformerConfig, mesh, *, tp_axis: str = "model"):
         return nll_sum / jnp.maximum(cnt, 1)
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    xent_sharded = shard_map(
+    xent_sharded = jax.shard_map(
         local_xent, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, tp_axis), P(dp, None)),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
     def loss(params: Params, batch: dict[str, jax.Array]) -> jax.Array:
         x, aux = backbone(params, cfg, batch["tokens"])

@@ -186,8 +186,8 @@ def _lanes_ivf(rng, store, B, N, D, k, G, qt, page):
                      np.asarray(store["updated_at"]),
                      np.asarray(store["category"]),
                      np.asarray(store["acl"]).view(np.int32),
-                     slots], axis=1).astype(np.int32)
-    meta[dead] = [-1, 0, 0, 0, -1]
+                     slots], axis=0).astype(np.int32)     # lane-major (5, P)
+    meta[:, dead] = np.asarray([-1, 0, 0, 0, -1])[:, None]
     cand_emb = np.asarray(store["emb"]).copy()
     cand_emb[dead] = 0.0
     cand_emb, meta = jnp.asarray(cand_emb), jnp.asarray(meta)
@@ -248,7 +248,7 @@ def _lanes_hybrid(mode):
                         0.0).astype(np.float32)
         outs = {
             "oracle": hybrid_score_ref(jnp.asarray(q), store["emb"], meta,
-                                       store["terms"], store["lexnorm"],
+                                       store["terms"].T, store["lexnorm"].T,
                                        jnp.asarray(gids), pa,
                                        jnp.asarray(qterms),
                                        jnp.asarray(qidf), k, **kw),

@@ -74,7 +74,7 @@ def test_sharded_kernels_and_vp_loss_subprocess():
         meta = jnp.stack([jnp.asarray(rng.integers(-1, 5, N, dtype=np.int32)),
                           jnp.asarray(rng.integers(0, 99, N, dtype=np.int32)),
                           jnp.asarray(rng.integers(0, 4, N, dtype=np.int32)),
-                          jnp.asarray(rng.integers(1, 8, N, dtype=np.int32))], 1)
+                          jnp.asarray(rng.integers(1, 8, N, dtype=np.int32))], 0)
         pred = jnp.array([1, 20, 0b1010, 0b11], jnp.int32)
         s1, i1 = filtered_topk_sharded(mesh, ("data", "model"), q, emb, meta, pred, kk)
         s2, i2 = filtered_topk_ref(q, emb, meta, pred, kk)
@@ -225,7 +225,6 @@ def test_mini_dryrun_subprocess():
 def test_compression_psum_subprocess():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import psum_bf16, psum_int8
         from repro.launch.mesh import make_mesh
@@ -233,8 +232,9 @@ def test_compression_psum_subprocess():
         x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 256), np.float32))
         want = np.asarray(x).sum(0)
         for fn, tol in [(psum_bf16, 2e-2), (psum_int8, 4e-2)]:
-            f = shard_map(lambda v: fn(v, "d"), mesh=mesh, in_specs=P("d"),
-                          out_specs=P("d"), check_rep=False)
+            f = jax.shard_map(lambda v: fn(v, "d"), mesh=mesh,
+                              in_specs=P("d"), out_specs=P("d"),
+                              check_vma=False)
             got = np.asarray(f(x))[0]
             rel = np.abs(got - want).max() / np.abs(want).max()
             assert rel < tol, (fn.__name__, rel)

@@ -103,7 +103,7 @@ def test_kernel_bit_identical_to_refs(mode, qt, B, N, D, k, blk_n, rng):
                     np.asarray(store["idf"])[np.clip(qterms, 0, None)],
                     0.0).astype(np.float32)
     s_o, i_o = hybrid_score_ref(jnp.asarray(q), store["emb"], meta,
-                                store["terms"], store["lexnorm"],
+                                store["terms"].T, store["lexnorm"].T,
                                 jnp.asarray(gids), preds,
                                 jnp.asarray(qterms), jnp.asarray(qidf), k,
                                 mode=mode, **kw)

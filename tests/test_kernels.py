@@ -30,7 +30,7 @@ def test_filtered_topk_sweep(B, N, D, k, blk_n, dtype, rng):
     acl = jnp.asarray(rng.integers(1, 16, N, dtype=np.int64).astype(np.uint32))
     pred = jnp.array([2, 300, 0b10110, 0b0101], jnp.int32)
     s_p, i_p = filtered_topk(q, emb, tenant, ts, cat, acl, pred, k, blk_n=blk_n)
-    meta = jnp.stack([tenant, ts, cat, acl.astype(jnp.int32)], 1)
+    meta = jnp.stack([tenant, ts, cat, acl.astype(jnp.int32)], 0)
     s_r, i_r = filtered_topk_ref(q, emb, meta, pred, k)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_r), rtol=tol, atol=tol)
