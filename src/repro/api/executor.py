@@ -42,10 +42,25 @@ from repro.obs.tracer import FanSpan
 from repro.core.query import (BLOCK_ALL, Predicate, stack_predicates,
                               unified_query, unified_query_grouped)
 from repro.core.store import Store
+from repro.kernels.arena_scan.stages import B_LANES
 
 #: tier tags in the returned `tiers` array
 TIER_HOT = 0
 TIER_WARM = 1
+
+#: Launch families that run the arena-scan kernel on a TPU (all compile as
+#: ``jit__run``). The kernel streams the arena once per `B_LANES` query
+#: rows, so a launch of ``bucket`` rows makes ceil(bucket / B_LANES) passes.
+SCAN_FAMILIES = ("filtered", "grouped", "hybrid")
+
+
+def _family(engine: str, fused: bool) -> str:
+    """A launch's family: ``filtered`` or ``grouped`` (the exact Pallas
+    engine, one group or fused), else the engine (``hybrid``, ``ivf``,
+    ``sharded``, ``ref``)."""
+    if engine == "pallas":
+        return "grouped" if fused else "filtered"
+    return engine
 
 
 @dataclasses.dataclass
@@ -208,6 +223,8 @@ class _Hot:
     terms: int = 0                # postings lanes this program streamed
                                   # (hybrid only) — the calibration audit's
                                   # per-unit twin of stats.terms_scanned
+    unit: int | None = None       # dispatch unit's sequence number (tracing
+                                  # only: `Tracer.next_unit`)
 
 
 def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
@@ -299,7 +316,7 @@ def _finish_hot(hot: _Hot, trace_fan=None) -> tuple[np.ndarray, np.ndarray]:
     if hot.rescan is not None:
         store, q, pred, k, exact, nv, ivf = hot.rescan
         if bool((sl[:nv] < 0).any()):
-            fan = (FanSpan(trace_fan, "rescan", engine=exact)
+            fan = (FanSpan(trace_fan, "rescan", engine=exact, unit=hot.unit)
                    if trace_fan is not None else None)
             s, sl = unified_query(store, q, pred, k, engine=exact)
             s, sl = jax.device_get((s, sl))
@@ -783,11 +800,12 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
     for unit in units:
         member_idxs = [groups[p.group_key] for p in unit.plans]
         rep = unit.plans[0]
-        fan = None
+        fan = seq = None
         if row_traces is not None:
+            seq = tracer.next_unit() if tracer is not None else None
             fan = FanSpan([row_traces[i] for m in member_idxs for i in m],
                           "launch", engine=rep.engine, fused=unit.fused,
-                          groups=len(unit.plans))
+                          groups=len(unit.plans), unit=seq)
         t_launch0 = time.perf_counter()
         if rep.engine == "hybrid":
             # hybrid always dispatches through the grouped fused scan (a
@@ -842,8 +860,17 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
                               page_rows=plan.page_rows)
         hot.launch_ms = (time.perf_counter() - t_launch0) * 1e3
         if fan is not None:
-            fan.end(rows=sum(len(m) for m in member_idxs),
-                    page_rows=rep.page_rows)
+            # the launched shape: real rows, the padded rows the scan got,
+            # and (arena-scan kernel) its row blocks, one arena pass each
+            hot.unit = seq
+            bucket = int(hot.s.shape[0])
+            family = _family(rep.engine, unit.fused)
+            shape = {"rows": sum(len(m) for m in member_idxs),
+                     "bucket": bucket, "family": family,
+                     "page_rows": rep.page_rows}
+            if family in SCAN_FAMILIES:
+                shape["passes"] = -(-bucket // B_LANES)
+            fan.end(**shape)
         inflight.append((unit, member_idxs, hot))
         if stats is not None:
             n_rows_unit = sum(len(m) for m in member_idxs)
@@ -856,7 +883,7 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
     # -- phase 2: warm probes while the hot scans are in flight ----------
     warm_results: list[list[tuple] | None] = []
     warm_failed: set = set()
-    for unit, member_idxs, _ in inflight:
+    for unit, member_idxs, hot in inflight:
         if unit.plans[0].route != "hot+warm":
             warm_results.append(None)
             continue
@@ -884,7 +911,7 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
             wspan = None
             if row_traces is not None:
                 wspan = FanSpan([row_traces[i] for i in m], "warm_probe",
-                                engine=plan.engine)
+                                engine=plan.engine, unit=hot.unit)
                 if tracer is not None:
                     # warm faults + WarmGuard retry/hedge/breaker decisions
                     # annotate the active sink — this probe's span
@@ -940,7 +967,7 @@ def finish_plans(pending: InFlightPlans):
         unit_traces = ([row_traces[i] for m in member_idxs for i in m]
                        if row_traces is not None else None)
         sync_fan = (FanSpan(unit_traces, "device_sync",
-                            engine=unit.plans[0].engine)
+                            engine=unit.plans[0].engine, unit=hot.unit)
                     if unit_traces is not None else None)
         t_sync0 = time.perf_counter()
         hs, hi = _finish_hot(hot, trace_fan=unit_traces)
@@ -962,7 +989,8 @@ def finish_plans(pending: InFlightPlans):
                 terms_scanned=hot.terms)
         if stats is not None:
             stats.rows_scanned += hot.rows
-        merge_fan = (FanSpan(unit_traces, "merge", groups=len(member_idxs))
+        merge_fan = (FanSpan(unit_traces, "merge", groups=len(member_idxs),
+                             unit=hot.unit)
                      if unit_traces is not None else None)
         off = 0
         for gi, m in enumerate(member_idxs):

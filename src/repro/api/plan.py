@@ -133,6 +133,12 @@ class PhysicalPlan:
     placement: str | None = None      # sharded engine: "hash" | "tenant"
                                       # row placement (tenant-affine enables
                                       # the owning-shard-only scan gate)
+    compile_span: tuple | None = dataclasses.field(
+        default=None, compare=False, repr=False)
+                                      # (t0, t1) of the planner's compile on
+                                      # the db tracer's clock, set only while
+                                      # tracing: Scheduler.offer adds it to
+                                      # the read's trace as `plan_compile`
 
     @property
     def group_key(self) -> tuple:

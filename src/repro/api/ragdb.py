@@ -197,6 +197,14 @@ class PendingExecution:
                                       # the traces (no scheduler upstream):
                                       # finish() then finishes them too
 
+    @property
+    def units(self) -> list:
+        """Sequence numbers of the dispatch units this batch launched
+        (consecutive; set only while tracing, else None each)."""
+        if self.inflight is None:
+            return []
+        return [hot.unit for _, _, hot in self.inflight.inflight]
+
 
 class RagDB:
     """Owns the storage engine (hot `TransactionLog` inside a `TieredRouter`,
@@ -493,6 +501,20 @@ class RagDB:
 
     # -- planning + execution --------------------------------------------
     def compile(self, logical: LogicalPlan) -> PhysicalPlan:
+        """The planner's compile of one read. While tracing, it is the
+        profiler event ``rag.plan_compile`` and its interval rides on the
+        plan (`PhysicalPlan.compile_span`) to the read's trace."""
+        if not self.tracer.enabled:
+            return self._compile(logical)
+        with self.tracer.measure("plan_compile") as m:
+            plan = self._compile(logical)
+        # the plan was built by this call and nothing else holds it yet:
+        # stamp it in place (a dataclasses.replace would cost about as
+        # much as the compile it reports)
+        object.__setattr__(plan, "compile_span", (m.t0, m.t1))
+        return plan
+
+    def _compile(self, logical: LogicalPlan) -> PhysicalPlan:
         snap = self.log.snapshot()
         return compile_plan(
             logical, n_rows=snap["emb"].shape[0],

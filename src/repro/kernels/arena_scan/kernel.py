@@ -55,8 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.arena_scan.stages import (NEG_INF, ScanSpec, merge_topk,
-                                             tile_mask, tile_signals)
+from repro.kernels.arena_scan.stages import (B_LANES, NEG_INF, ScanSpec,
+                                             merge_topk, tile_mask,
+                                             tile_signals)
 
 
 def _tile_step(spec: ScanSpec, k: int, scratch, q, e, meta, gids, preds,
@@ -177,7 +178,7 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                       gids: jax.Array, preds: jax.Array, k: int, *,
                       spec: ScanSpec = ScanSpec(),
                       lex: tuple | None = None,
-                      blk_b: int = 8, blk_n: int = 512,
+                      blk_b: int = B_LANES, blk_n: int = 512,
                       page_rows: int | None = None,
                       interpret: bool = False):
     """The unified scan. q: (B, D); emb: (N, D); meta: (M, N) int32,

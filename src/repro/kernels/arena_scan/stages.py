@@ -50,8 +50,9 @@ import jax.numpy as jnp
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-#: Query-row lane width every engine pads B to (= the kernel wrappers'
-#: ``blk_b`` default) — pinning rule 3 above.
+#: Query-row lane width every engine pads B to — pinning rule 3 above —
+#: and the kernel's query-row block (``blk_b``'s default in every wrapper):
+#: the kernel streams the arena once per B_LANES query rows.
 B_LANES = 8
 
 

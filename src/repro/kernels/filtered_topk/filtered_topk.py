@@ -20,13 +20,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.arena_scan.kernel import arena_scan_pallas
-from repro.kernels.arena_scan.stages import (NEG_INF, ScanSpec,  # noqa: F401
+from repro.kernels.arena_scan.stages import (B_LANES, NEG_INF,  # noqa: F401
+                                             ScanSpec,
                                              merge_topk as _merge_topk)
 
 
 def filtered_topk_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                          pred: jax.Array, k: int, *,
-                         blk_b: int = 8, blk_n: int = 512,
+                         blk_b: int = B_LANES, blk_n: int = 512,
                          page_rows: int | None = None,
                          interpret: bool = False):
     """q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major rows

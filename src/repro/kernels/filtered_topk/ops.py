@@ -27,6 +27,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels.arena_scan.ops import (_pack_meta, _pad_axis0,  # noqa: F401
                                           default_interpret, pad_dead_rows,
                                           pad_d128)
+from repro.kernels.arena_scan.stages import B_LANES
 from repro.kernels.filtered_topk.filtered_topk import (NEG_INF,
                                                        filtered_topk_pallas)
 
@@ -47,7 +48,7 @@ def _run(q, emb, meta, pred, k, blk_b, blk_n, page_rows, interpret):
 
 
 def filtered_topk(q, emb, tenant, updated_at, category, acl, pred, k: int,
-                  *, blk_b: int = 8, blk_n: int = 512,
+                  *, blk_b: int = B_LANES, blk_n: int = 512,
                   page_rows: int | None = None,
                   interpret: bool | None = None):
     """Single-device entry point (contract of core.query.unified_query).
@@ -70,7 +71,7 @@ def filtered_topk(q, emb, tenant, updated_at, category, acl, pred, k: int,
 
 def filtered_topk_sharded(mesh: Mesh, axis: str | tuple[str, ...],
                           q, emb, meta, pred, k: int,
-                          *, blk_b: int = 8, blk_n: int = 512,
+                          *, blk_b: int = B_LANES, blk_n: int = 512,
                           interpret: bool | None = None):
     """Distributed unified query over a row-sharded corpus.
 

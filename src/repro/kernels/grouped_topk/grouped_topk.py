@@ -25,12 +25,12 @@ from __future__ import annotations
 import jax
 
 from repro.kernels.arena_scan.kernel import arena_scan_pallas
-from repro.kernels.arena_scan.stages import ScanSpec
+from repro.kernels.arena_scan.stages import B_LANES, ScanSpec
 
 
 def grouped_topk_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                         gids: jax.Array, preds: jax.Array, k: int, *,
-                        blk_b: int = 8, blk_n: int = 512,
+                        blk_b: int = B_LANES, blk_n: int = 512,
                         page_rows: int | None = None,
                         interpret: bool = False):
     """q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major rows

@@ -29,6 +29,7 @@ from repro.kernels.arena_scan.ops import (BLK_SCAN,  # noqa: F401
                                           default_blk_n, default_interpret,
                                           default_use_kernel, pad_d128,
                                           pad_dead_rows)
+from repro.kernels.arena_scan.stages import B_LANES
 from repro.kernels.grouped_topk.grouped_topk import grouped_topk_pallas
 from repro.kernels.grouped_topk.ref import NEG_INF, grouped_topk_scan_ref
 
@@ -56,7 +57,7 @@ def _run(q, emb, meta, gids, preds, k, use_kernel, blk_b, blk_n, page_rows,
 
 def grouped_topk(q, emb, tenant, updated_at, category, acl, gids, preds,
                  k: int, *, use_kernel: bool | None = None,
-                 blk_b: int = 8, blk_n: int | None = None,
+                 blk_b: int = B_LANES, blk_n: int | None = None,
                  page_rows: int | None = None,
                  interpret: bool | None = None):
     """Fused multi-predicate grouped top-k over one arena scan.

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.arena_scan.kernel import arena_scan_pallas
-from repro.kernels.arena_scan.stages import ScanSpec
+from repro.kernels.arena_scan.stages import B_LANES, ScanSpec
 
 
 def hybrid_score_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
@@ -58,7 +58,8 @@ def hybrid_score_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                         gids: jax.Array, preds: jax.Array,
                         qterms: jax.Array, qidf: jax.Array, k: int, *,
                         mode: str = "wsum", w_dense: float = 1.0,
-                        w_lex: float = 1.0, blk_b: int = 8, blk_n: int = 512,
+                        w_lex: float = 1.0, blk_b: int = B_LANES,
+                        blk_n: int = 512,
                         page_rows: int | None = None,
                         interpret: bool = False):
     """q: (B, D); emb: (N, D); meta: (4, N) int32 lane-major;

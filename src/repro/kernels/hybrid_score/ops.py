@@ -30,6 +30,7 @@ from repro.kernels.arena_scan.ops import (_packed_lanes, _packed_meta,
                                           default_interpret,
                                           default_use_kernel, pad_d128,
                                           pad_dead_rows)
+from repro.kernels.arena_scan.stages import B_LANES
 from repro.kernels.hybrid_score.hybrid_score import hybrid_score_pallas
 from repro.kernels.hybrid_score.ref import (NEG_INF, hybrid_score_scan_ref,
                                             qidf_of, rrf_fuse)
@@ -75,7 +76,7 @@ def hybrid_score(q, emb, tenant, updated_at, category, acl, terms, lexnorm,
                  idf, gids, preds, qterms, k: int, *, mode: str = "wsum",
                  w_dense: float = 1.0, w_lex: float = 1.0,
                  rrf_c: float = 60.0, lists: bool = False,
-                 use_kernel: bool | None = None, blk_b: int = 8,
+                 use_kernel: bool | None = None, blk_b: int = B_LANES,
                  blk_n: int | None = None, page_rows: int | None = None,
                  interpret: bool | None = None):
     """Fused hybrid dense+BM25 grouped top-k over ONE arena scan.

@@ -1,13 +1,15 @@
 """Per-request span trees on `perf_counter` clocks.
 
 One `Trace` is one request's life: a root ``request`` span plus children
-for every stage the serving stack walks — queue wait, plan/degrade, the
-cache lookup, the hot launch, the device sync (with any ivf completeness
-rescan as *its* child), the warm probe (annotated with WarmGuard
-retry/hedge/breaker decisions), the tier merge, and the finish. The async
-three-phase dispatch (executor.launch_plans / finish_plans) means these
-stages do NOT share a call stack: span handles are *carried* — on
-`ServeRequest`, `PendingExecution`, and `InFlightPlans` — across the
+for every stage the serving stack walks — the planner's compile
+(``plan_compile``), queue wait, the degradation-ladder walk (``degrade``),
+the cache lookup, the hot launch, the wait of a launched batch in the
+scheduler's pipeline (``pending``), the device sync (with any ivf
+completeness rescan as *its* child), the warm probe (annotated with
+WarmGuard retry/hedge/breaker decisions), the tier merge, and the finish.
+The async three-phase dispatch (executor.launch_plans / finish_plans)
+means these stages do NOT share a call stack: span handles are *carried*
+— on `ServeRequest`, `PendingExecution`, and `InFlightPlans` — across the
 launch/finish boundary, which is why spans here are explicit begin/end
 records in a flat parent-linked list, not context managers.
 
@@ -20,6 +22,19 @@ Span ids are deterministic — sequential ints in creation order within a
 trace, with trace ids sequential per tracer — so two runs of the same
 workload produce the same tree identifiers (the flight-recorder diffing
 contract).
+
+While a profiler session is active (``jax.profiler.start_trace``), every
+span an enabled tracer records is also a profiler host event named
+``rag.<span name>`` (a `jax.profiler.TraceAnnotation`, the C++ TraceMe),
+entered where the span begins and exited where it ends, with the span's
+scalar annotations as event stats. Those events sit on the profiler's
+clock beside the device's operations. Events need not nest: a batch's
+``pending`` event opens in one scheduler round and closes in the next. A
+`FanSpan` writes one event per measured interval, not one per member
+trace. Each dispatch unit gets a sequence number from `Tracer.next_unit`;
+its events carry it as ``unit``, which links a profiler event back to the
+member traces whose spans carry the same number. jax is imported the
+first time an enabled tracer opens a span, never on the disabled path.
 
 Disabled tracing is a no-op fast path: `Tracer(enabled=False).trace()`
 returns the shared `NULL_TRACE` singleton whose methods do nothing, and
@@ -50,6 +65,38 @@ from __future__ import annotations
 
 import time
 
+_TraceMe = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _event(name: str, ann: dict | None):
+    """Enter the profiler host event ``rag.<name>`` now, with the scalar
+    annotations of ``ann`` as its stats; None while no profiler session is
+    active (then nothing is recorded and nothing more is built)."""
+    global _TraceMe
+    if _TraceMe is None:
+        from jax.profiler import TraceAnnotation
+        _TraceMe = TraceAnnotation
+    if not _TraceMe.is_enabled():
+        return None
+    ev = _TraceMe("rag." + name, **_stats(ann)) if ann else \
+        _TraceMe("rag." + name)
+    ev.__enter__()
+    return ev
+
+
+def _close(ev, ann: dict | None = None) -> None:
+    """Exit a profiler event from `_event`, adding ``ann`` to its stats."""
+    if ann:
+        ev.set_metadata(**_stats(ann))
+    ev.__exit__(None, None, None)
+
+
+def _stats(ann: dict) -> dict:
+    """The annotations a profiler event can carry: ints, floats, bools and
+    strings (a tuple or a None stays in the span's record only)."""
+    return {k: v for k, v in ann.items()
+            if isinstance(v, (int, float, str))}
+
 
 def _jsonable(v):
     """Annotation values as JSON-serializable primitives (tuples of rung
@@ -69,9 +116,10 @@ def _jsonable(v):
 
 class Span:
     """One timed stage. ``t1 is None`` while open; times are raw
-    `perf_counter` seconds (exports normalize to a common base)."""
+    `perf_counter` seconds (exports normalize to a common base). ``event``
+    is the span's open profiler event, if one is being written."""
 
-    __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "ann")
+    __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "ann", "event")
 
     def __init__(self, name: str, span_id: int, parent_id: int, t0: float,
                  ann: dict | None = None):
@@ -81,6 +129,7 @@ class Span:
         self.t0 = t0
         self.t1: float | None = None
         self.ann: dict = dict(ann) if ann else {}
+        self.event = None
 
     def annotate(self, key: str, value) -> None:
         self.ann[key] = value
@@ -110,11 +159,12 @@ class Trace:
                  "finished")
 
     def __init__(self, clock, recorder, trace_id: str, name: str = "request",
-                 ann: dict | None = None):
+                 ann: dict | None = None, t0: float | None = None):
         self._clock = clock
         self._recorder = recorder
         self.trace_id = trace_id
-        root = Span(name, 0, -1, clock(), ann)
+        root = Span(name, 0, -1, clock() if t0 is None else t0, ann)
+        root.event = _event(name, ann)
         self.spans: list[Span] = [root]
         self._open: list[int] = [0]
         self.pins: list[str] = []
@@ -123,11 +173,13 @@ class Trace:
     # -- span construction -------------------------------------------------
     def begin(self, name: str, t0: float | None = None, **ann) -> int:
         """Open a child of the current open span; returns its span id (the
-        handle carried across launch/finish boundaries)."""
+        handle carried across launch/finish boundaries). Its profiler
+        event, if one is written, starts now."""
         sid = len(self.spans)
         parent = self._open[-1] if self._open else 0
-        self.spans.append(Span(name, sid, parent,
-                               self._clock() if t0 is None else t0, ann))
+        sp = Span(name, sid, parent, self._clock() if t0 is None else t0, ann)
+        sp.event = _event(name, ann)
+        self.spans.append(sp)
         self._open.append(sid)
         return sid
 
@@ -148,6 +200,9 @@ class Trace:
             sp.t1 = self._clock() if t1 is None else t1
         if ann:
             sp.ann.update(ann)
+        if sp.event is not None:
+            _close(sp.event, ann)
+            sp.event = None
         if self._open and self._open[-1] == span_id:
             self._open.pop()
         elif span_id in self._open:
@@ -175,7 +230,8 @@ class Trace:
 
     def add(self, name: str, t0: float, t1: float, **ann) -> int:
         """Record an already-measured, closed span under the current open
-        span (the batch-shared stages fan in through here)."""
+        span. Its interval is past, so it writes no profiler event: the
+        code that measured it writes that (as `Tracer.measure` does)."""
         sid = len(self.spans)
         parent = self._open[-1] if self._open else 0
         sp = Span(name, sid, parent, t0, ann)
@@ -213,6 +269,9 @@ class Trace:
             sp = spans[o.pop()]
             if sp.t1 is None:
                 sp.t1 = end
+            if sp.event is not None:
+                _close(sp.event, ann if sp is spans[0] else None)
+                sp.event = None
         if ann:
             spans[0].ann.update(ann)
         self.finished = True
@@ -300,9 +359,10 @@ class FanSpan:
     """One measured operation recorded into several request traces at once
     (a dispatch unit's launch/sync serves every member request). Begins on
     construction; `end()` closes the span in every member trace with ONE
-    shared clock reading, so the interval is identical across trees."""
+    shared clock reading, so the interval is identical across trees, and
+    closes its one profiler event."""
 
-    __slots__ = ("_pairs", "t0", "_clock")
+    __slots__ = ("_pairs", "t0", "_clock", "_event")
 
     def __init__(self, traces, name: str, clock=time.perf_counter, **ann):
         self._clock = clock
@@ -316,10 +376,13 @@ class FanSpan:
             seen.add(id(t))
             pairs.append((t, t._begin_at(name, t0, shared)))
         self._pairs = pairs
+        self._event = _event(name, shared) if pairs else None
 
     def annotate(self, key: str, value) -> None:
         for t, sid in self._pairs:
             t.spans[sid].ann[key] = value
+        if self._event is not None:
+            self._event.set_metadata(**_stats({key: value}))
 
     def fault(self, site: str) -> None:
         for t, sid in self._pairs:
@@ -332,7 +395,33 @@ class FanSpan:
         shared = ann or None
         for t, sid in self._pairs:
             t._end_at(sid, t1, shared)
+        if self._event is not None:
+            _close(self._event, shared)
+            self._event = None
         return (t1 - self.t0) * 1e3
+
+
+class Measured:
+    """Context manager of `Tracer.measure`: ``t0``/``t1`` on the tracer's
+    clock, and one profiler event over the same block."""
+
+    __slots__ = ("_clock", "_name", "_event", "t0", "t1")
+
+    def __init__(self, clock, name: str):
+        self._clock, self._name = clock, name
+        self._event = None
+        self.t0 = self.t1 = None
+
+    def __enter__(self) -> "Measured":
+        self._event = _event(self._name, None)
+        self.t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._clock()
+        if self._event is not None:
+            _close(self._event)
+            self._event = None
 
 
 class TraceGroup:
@@ -378,25 +467,40 @@ class Tracer:
         self.recorder = recorder
         self.clock = clock
         self._seq = 0
+        self._units = 0
         self._active: list = []
 
     @property
     def traces_started(self) -> int:
         return self._seq
 
-    def trace(self, name: str = "request", **ann):
+    def trace(self, name: str = "request", t0: float | None = None, **ann):
         """A fresh trace (deterministic sequential id), or `NULL_TRACE`
-        when disabled — the only allocation the disabled path skips."""
+        when disabled — the only allocation the disabled path skips. ``t0``
+        backdates the root span (a read whose plan was compiled before it
+        was offered)."""
         if not self.enabled:
             return NULL_TRACE
         self._seq += 1
         return Trace(self.clock, self.recorder, f"t{self._seq:06d}",
-                     name, ann)
+                     name, ann, t0)
 
     def fan(self, traces, name: str, **ann):
         if not self.enabled:
             return NULL_SPAN
         return FanSpan(traces, name, clock=self.clock, **ann)
+
+    def next_unit(self) -> int:
+        """The next dispatch unit's sequence number (``unit`` on its spans
+        and profiler events), deterministic per tracer like trace ids."""
+        self._units += 1
+        return self._units
+
+    def measure(self, name: str) -> "Measured":
+        """Time a block on this tracer's clock as the profiler event
+        ``rag.<name>``; the interval is left on the returned object for a
+        trace to `Trace.add` later."""
+        return Measured(self.clock, name)
 
     # -- active-sink stack (fault / guard annotation) ----------------------
     def push(self, sink) -> None:

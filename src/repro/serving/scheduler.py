@@ -152,9 +152,11 @@ class Scheduler:
         self.guard.tracer = db.tracer
         self.queue: deque[ServeRequest] = deque()
         # at most one batch in flight beyond the one being launched: the
-        # executor's device_get pipeline depth
+        # executor's device_get pipeline depth. Each entry: the launched
+        # batch, its requests, their queue waits, its launch time, and its
+        # open ``pending`` span (None while not tracing)
         self._pending: list[tuple[PendingExecution, list[ServeRequest],
-                                  list[float], float]] = []
+                                  list[float], float, object]] = []
         self.shed_count = 0
 
     # -- admission ---------------------------------------------------------
@@ -164,11 +166,17 @@ class Scheduler:
         With the db's tracer on, the request's trace is born HERE — queue
         wait is part of its life — with an open ``queue`` span that the
         drain closes; a shed request's trace finishes immediately, pinned
-        ``failed`` so the flight recorder keeps it."""
+        ``failed`` so the flight recorder keeps it. A plan compiled while
+        tracing brings its compile interval: the trace then starts there,
+        with that interval as its closed ``plan_compile`` span."""
         tracer = self.db.tracer
         if tracer.enabled and req.trace is None:
-            req.trace = tracer.trace("request", req_id=req.req_id,
-                                     tenant=req.tenant)
+            compiled = req.plan.compile_span
+            req.trace = tracer.trace(
+                "request", t0=compiled[0] if compiled else None,
+                req_id=req.req_id, tenant=req.tenant)
+            if compiled:
+                req.trace.add("plan_compile", *compiled, req_id=req.req_id)
         if self.cfg.admission and len(self.queue) >= self.cfg.max_queue:
             self.shed_count += 1
             self.metrics.inc("shed", tenant=req.tenant)
@@ -179,7 +187,7 @@ class Scheduler:
             return False
         self.queue.append(req)
         if req.trace is not None and req.trace.enabled:
-            req.trace.begin("queue")
+            req.trace.begin("queue", req_id=req.req_id)
         return True
 
     @property
@@ -244,7 +252,7 @@ class Scheduler:
                     # close the queue span offer()/requeue left open
                     tr.end_current(wait_ms=wait_ms)
                 budget = self.cfg.slo_ms - wait_ms
-                sid = tr.begin("plan", pressure=pressure,
+                sid = tr.begin("degrade", pressure=pressure,
                                budget_ms=budget) if traced else None
                 plan = (self._degrade_for(r, budget, pressure)
                         if self.cfg.admission else r.plan)
@@ -303,7 +311,15 @@ class Scheduler:
                 self.metrics.inc("launch_failures")
                 out.extend(self._failed_results(batch, waits, now))
             else:
-                self._pending.append((pending, batch, waits, now))
+                # launched, not yet finished: the batch's results wait in
+                # the pipeline until the next round's finish starts
+                hold = None
+                if traces is not None:
+                    units = pending.units
+                    ann = ({"unit": units[0], "units": len(units)}
+                           if units else {})
+                    hold = self.db.tracer.fan(traces, "pending", **ann)
+                self._pending.append((pending, batch, waits, now, hold))
         if len(self._pending) > (1 if batch else 0):
             out.extend(self._finish_oldest())
         return out
@@ -359,7 +375,7 @@ class Scheduler:
             if r.trace is not None and r.trace.enabled:
                 # back in line: a fresh queue span (the drain closes it)
                 r.trace.annotate("requeues", r.retries)
-                r.trace.begin("queue")
+                r.trace.begin("queue", req_id=r.req_id)
             self.queue.appendleft(r)
         if not give_up:
             return []
@@ -374,7 +390,9 @@ class Scheduler:
         return out
 
     def _finish_oldest(self) -> list[ServedResult]:
-        pending, batch, waits, t_launch = self._pending.pop(0)
+        pending, batch, waits, t_launch, hold = self._pending.pop(0)
+        if hold is not None:
+            hold.end()
         try:
             scores, slots, tiers = self.db.finish(pending)
         except FaultError:

@@ -6,10 +6,14 @@ The contracts under test (ISSUE 10):
     finished trace whose span tree is well-formed (root ``request``, valid
     parent links, closed monotone intervals nested inside the root) and
     covers the pipeline stages the request actually crossed
-    (cache_lookup -> launch -> device_sync -> merge, queue/plan under the
-    scheduler).
+    (cache_lookup -> launch -> device_sync -> merge, plan_compile/queue/
+    degrade/pending under the scheduler).
   * ZERO-COST DISABLED — tracer off is the default and results are
     bit-identical to tracer on: tracing observes, never steers.
+  * PROFILER CLOCK — under an active `jax.profiler` session an enabled
+    tracer writes each span as a ``rag.<name>`` host event (one per fan
+    span, with ``unit`` / ``req_id`` stats); a disabled tracer writes none;
+    each launch span carries the shape it launched (rows, bucket, passes).
   * PINNING — the flight recorder's ring is bounded, pinned (slo /
     degraded / fault / failed) traces survive the ring rolling past them,
     the pin list is bounded too (drops counted), and fault/degradation
@@ -21,8 +25,11 @@ The contracts under test (ISSUE 10):
     independent), keyed by (engine, N-bucket, G, k), and
     `CostModel.calibrated` rescales curves by the measured drift.
 """
+import glob
 import json
+import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -30,7 +37,7 @@ from repro.api import RagDB
 from repro.api.planner import CostModel, PlannerConfig
 from repro.core import StoreConfig
 from repro.data.corpus import DAY_S, CorpusConfig, make_corpus
-from repro.obs import CalibrationTable, FlightRecorder, Tracer
+from repro.obs import CalibrationTable, FanSpan, FlightRecorder, Tracer
 from repro.obs.calibration import pow2_bucket
 from repro.serving.faults import FaultPlan, FaultRule
 from repro.serving.metrics import MetricsRegistry
@@ -144,8 +151,10 @@ def test_scheduler_trace_adds_queue_and_plan_spans():
     for t in rec.traces():
         _assert_well_formed(t)
         names = [s.name for s in t.spans]
-        assert names[:2] == ["request", "queue"]
-        assert "plan" in names and "launch" in names
+        # the plans were compiled while tracing: each read's trace starts
+        # at its compile, a closed span before the queue wait
+        assert names[:3] == ["request", "plan_compile", "queue"]
+        assert "degrade" in names and "launch" in names
         assert t.root.ann["deadline_met"] is True
         assert "e2e_ms" in t.root.ann and "req_id" in t.root.ann
 
@@ -165,6 +174,143 @@ def test_tracer_disabled_results_bit_identical():
     db.attach_tracer(Tracer(enabled=False))
     db.execute(plans, use_cache=False)
     assert db.tracer.traces_started == 0    # disabled path makes no traces
+
+
+# -- spans on the profiler's clock -----------------------------------------
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under a profiler session writing into ``log_dir``; return
+    its result and the ``rag.*`` host events as (name, stats) pairs."""
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    events = [(ev.name, dict(ev.stats))
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("rag.")]
+    return out, events
+
+
+def _serve(db, plans, max_batch):
+    clock = FakeClock()
+    sched = Scheduler(db, SchedulerConfig(slo_ms=1e9, max_queue=64,
+                                          max_batch=max_batch,
+                                          degrade_pressure=2.0,
+                                          stale_pressure=2.0,
+                                          use_cache=False),
+                      clock=clock, metrics=MetricsRegistry(),
+                      sleep=clock.advance)
+    for i, plan in enumerate(plans):
+        assert sched.offer(ServeRequest(plan=plan, arrival_t=clock(),
+                                        req_id=i))
+    return sorted(sched.run_until_idle(), key=lambda r: r.request.req_id)
+
+
+def test_enabled_tracer_writes_rag_events(tmp_path):
+    db, ccfg = _db()
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+
+    def run():
+        return _serve(db, _plans(db, ccfg, 5), max_batch=2)
+
+    results, events = _profiled(tmp_path, run)
+    assert len(results) == 5
+    by_name: dict = {}
+    for name, stats in events:
+        by_name.setdefault(name, []).append(stats)
+    for stage in ("queue", "plan_compile", "launch", "pending",
+                  "device_sync", "merge"):
+        assert f"rag.{stage}" in by_name, (stage, sorted(by_name))
+    # per-request spans carry the request; per-unit spans the unit
+    assert sorted(st["req_id"] for st in by_name["rag.queue"]) == \
+        list(range(5))
+    launched = sorted(st["unit"] for st in by_name["rag.launch"])
+    assert len(launched) == 3               # batches of 2, 2, 1: one each
+    assert len(set(launched)) == len(launched)
+    for stage in ("device_sync", "merge"):
+        assert sorted(st["unit"] for st in by_name[f"rag.{stage}"]) == \
+            launched
+    assert sorted(st["unit"] for st in by_name["rag.pending"]) == launched
+    assert len(by_name["rag.plan_compile"]) == 5
+    assert len(by_name["rag.request"]) == 5
+    # the member traces' spans carry the unit of the profiler events
+    for t in rec.traces():
+        _assert_well_formed(t)
+        units = {s.ann["unit"] for s in t.spans
+                 if s.name in ("launch", "pending", "device_sync", "merge")}
+        assert len(units) == 1 and units <= set(launched)
+        names = [s.name for s in t.spans]
+        assert names.index("launch") < names.index("pending") \
+            < names.index("device_sync")
+
+
+def test_fan_span_writes_one_event(tmp_path):
+    tracer = Tracer(enabled=True)
+
+    def run():
+        traces = [tracer.trace("request", req_id=i) for i in range(3)]
+        FanSpan(traces, "launch", unit=7).end(rows=3)
+        for t in traces:
+            t.finish()
+        return traces
+
+    traces, events = _profiled(tmp_path, run)
+    launches = [st for name, st in events if name == "rag.launch"]
+    assert launches == [{"unit": 7, "rows": 3}]
+    for t in traces:                        # ... and a span in each trace
+        (span,) = [s for s in t.spans if s.name == "launch"]
+        assert span.ann == {"unit": 7, "rows": 3}
+
+
+def test_disabled_tracer_writes_no_events(tmp_path):
+    db, ccfg = _db()
+    assert not db.tracer.enabled
+    results, events = _profiled(
+        tmp_path, lambda: _serve(db, _plans(db, ccfg, 3), max_batch=2))
+    assert len(results) == 3
+    assert events == []
+
+
+def test_launch_span_carries_launched_shape():
+    db, ccfg = _db()
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+    rng = np.random.default_rng(3)
+    sess = db.admin_session()
+    for n, bucket, passes in ((9, 16, 2), (5, 8, 1)):
+        plans = [sess.search(rng.standard_normal(ccfg.dim).astype(np.float32),
+                             normalize=False).using("pallas").limit(6).plan()
+                 for _ in range(n)]
+        db.execute(plans, use_cache=False)
+        spans = [s for t in rec.traces()[-n:] for s in t.spans
+                 if s.name == "launch"]
+        assert len(spans) == n
+        assert {s.ann["unit"] for s in spans} == {spans[0].ann["unit"]}
+        ann = spans[0].ann
+        assert (ann["family"], ann["rows"], ann["bucket"], ann["passes"]) \
+            == ("filtered", n, bucket, passes)
+    # the ref engine scans without the kernel: no passes to count
+    db.execute(_plans(db, ccfg, 3), use_cache=False)
+    ann = next(s.ann for s in rec.traces()[-1].spans if s.name == "launch")
+    assert ann["family"] == "ref" and "passes" not in ann
+
+
+def test_results_bit_identical_under_profiler(tmp_path):
+    db, ccfg = _db()
+    plans = _plans(db, ccfg, 5)
+    off = _serve(db, plans, max_batch=4)
+    db.attach_tracer(Tracer(enabled=True, recorder=FlightRecorder()))
+    on, events = _profiled(tmp_path, lambda: _serve(db, plans, max_batch=4))
+    assert events                           # the tracer did write events
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a.scores, b.scores)
+        np.testing.assert_array_equal(a.slots, b.slots)
 
 
 # -- flight-recorder pinning rules -----------------------------------------

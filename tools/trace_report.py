@@ -13,7 +13,7 @@ written by ``bench_serving --chaos``. The report:
   1. header: recorded/retained/pinned counts + pin-reason histogram (what
      fraction of retained traces are there because something went wrong);
   2. top-N slowest retained traces with their full span breakdown — the
-     "why was THIS request slow" view (queue wait vs plan vs device sync
+     "why was THIS request slow" view (queue wait vs degrade vs device sync
      vs warm probe is visible per request, annotations inline);
   3. per-stage rollup across every retained trace (count/mean/p95/max per
      span name) and per-engine / per-tenant trace rollups;
@@ -144,7 +144,7 @@ def report(dump: dict, top: int, stage_pcts: bool) -> None:
                 continue
             val = _root(t)["ann"].get(key)
             if val is None:         # scheduler traces carry engine on the
-                for s in t["spans"]:   # plan span, not the root
+                for s in t["spans"]:   # degrade span, not the root
                     if key in s["ann"]:
                         val = s["ann"][key]
                         break
