@@ -42,15 +42,18 @@ from repro.obs.tracer import FanSpan
 from repro.core.query import (BLOCK_ALL, Predicate, stack_predicates,
                               unified_query, unified_query_grouped)
 from repro.core.store import Store
-from repro.kernels.arena_scan.stages import B_LANES
+from repro.kernels.arena_scan.ops import default_blk_b
+from repro.kernels.arena_scan.stages import ScanSpec
+from repro.kernels.hybrid_score.hybrid_score import hybrid_spec
 
 #: tier tags in the returned `tiers` array
 TIER_HOT = 0
 TIER_WARM = 1
 
 #: Launch families that run the arena-scan kernel on a TPU (all compile as
-#: ``jit__run``). The kernel streams the arena once per `B_LANES` query
-#: rows, so a launch of ``bucket`` rows makes ceil(bucket / B_LANES) passes.
+#: ``jit__run``). The kernel streams the arena once per query-row block of
+#: `default_blk_b` rows, so a launch of ``bucket`` rows makes
+#: ceil(bucket / blk_b) passes: one for a dense bucket up to 128 rows.
 SCAN_FAMILIES = ("filtered", "grouped", "hybrid")
 
 
@@ -861,7 +864,8 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
         hot.launch_ms = (time.perf_counter() - t_launch0) * 1e3
         if fan is not None:
             # the launched shape: real rows, the padded rows the scan got,
-            # and (arena-scan kernel) its row blocks, one arena pass each
+            # and (arena-scan kernel) its query-row block and its passes,
+            # one arena stream per block
             hot.unit = seq
             bucket = int(hot.s.shape[0])
             family = _family(rep.engine, unit.fused)
@@ -869,7 +873,11 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
                      "bucket": bucket, "family": family,
                      "page_rows": rep.page_rows}
             if family in SCAN_FAMILIES:
-                shape["passes"] = -(-bucket // B_LANES)
+                spec = (hybrid_spec(rep.lex[0]) if family == "hybrid"
+                        else ScanSpec())
+                blk_b = default_blk_b(bucket, spec)
+                shape["block_rows"] = blk_b
+                shape["passes"] = -(-bucket // blk_b)
             fan.end(**shape)
         inflight.append((unit, member_idxs, hot))
         if stats is not None:

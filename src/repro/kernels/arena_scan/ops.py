@@ -10,7 +10,8 @@ through these helpers, so the invariants live in exactly one place:
     testable;
   * D pads to the 128-lane MXU multiple (padded dims contribute 0 to the
     dot), B pads to the blk_b multiple (row-parallel: padding rows cannot
-    perturb real rows, and they are sliced off before returning);
+    perturb real rows, and they are sliced off before returning), with
+    blk_b from `default_blk_b`;
   * the metadata columns and the (N, T) lexical lanes are packed
     LANE-MAJOR — (4, N) meta, (T, N) lanes — once per snapshot and
     LRU-memoized on the column object ids (snapshot columns are immutable
@@ -23,6 +24,8 @@ from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.arena_scan.stages import B_LANES, ScanSpec
 
 #: jnp streaming-scan tile: big enough that tile overhead (local top-k,
 #: scan step) amortizes, small enough that a tile's scores stay cache-close.
@@ -148,3 +151,21 @@ def default_blk_n(n: int, use_kernel: bool, page_rows: int | None = None) -> int
         return 512
     cap = 1 << max(int(n) - 1, 0).bit_length()
     return min(BLK_SCAN, max(cap, 1))
+
+
+#: Largest query-row block: the MXU's width. A batch over it is split into
+#: blocks of this many rows by the kernel's grid, one arena stream each.
+MAX_BLK_B = 128
+
+
+def default_blk_b(b: int, spec: ScanSpec) -> int:
+    """Query-row block policy: the kernel streams the arena once per block
+    of ``blk_b`` query rows. A dense scan takes the launch's whole batch of
+    ``b`` rows as one block (rounded up to the `B_LANES` pad multiple,
+    capped at `MAX_BLK_B`): its MXU cost per arena tile hardly depends on
+    the rows, so every extra block is a whole extra stream of the arena. A
+    scan with a lexical stage keeps `B_LANES`: its BM25 compare loop is VPU
+    work per (query row, arena row), which a wider block does not share."""
+    if spec.has_lex:
+        return B_LANES
+    return min(-(-max(b, 1) // B_LANES) * B_LANES, MAX_BLK_B)

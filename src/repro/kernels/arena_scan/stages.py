@@ -51,8 +51,10 @@ import jax.numpy as jnp
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 #: Query-row lane width every engine pads B to — pinning rule 3 above —
-#: and the kernel's query-row block (``blk_b``'s default in every wrapper):
-#: the kernel streams the arena once per B_LANES query rows.
+#: and the multiple the kernel's query-row block ``blk_b`` rounds up to
+#: (`ops.default_blk_b`). The kernel streams the arena once per block: a
+#: dense scan holds the whole batch (up to 128 rows) in one block; a scan
+#: with a lexical stage keeps blocks of B_LANES rows.
 B_LANES = 8
 
 

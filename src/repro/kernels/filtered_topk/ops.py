@@ -25,9 +25,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.arena_scan.ops import (_pack_meta, _pad_axis0,  # noqa: F401
-                                          default_interpret, pad_dead_rows,
-                                          pad_d128)
-from repro.kernels.arena_scan.stages import B_LANES
+                                          default_blk_b, default_interpret,
+                                          pad_dead_rows, pad_d128)
+from repro.kernels.arena_scan.stages import B_LANES, ScanSpec
 from repro.kernels.filtered_topk.filtered_topk import (NEG_INF,
                                                        filtered_topk_pallas)
 
@@ -48,13 +48,17 @@ def _run(q, emb, meta, pred, k, blk_b, blk_n, page_rows, interpret):
 
 
 def filtered_topk(q, emb, tenant, updated_at, category, acl, pred, k: int,
-                  *, blk_b: int = B_LANES, blk_n: int = 512,
+                  *, blk_b: int | None = None, blk_n: int = 512,
                   page_rows: int | None = None,
                   interpret: bool | None = None):
     """Single-device entry point (contract of core.query.unified_query).
-    ``page_rows`` selects the kernel's paged (HBM-resident, double-buffered
-    DMA) regime; bits are unchanged (see arena_scan.kernel)."""
+    ``blk_b=None`` takes `default_blk_b`: the whole batch in one query-row
+    block, so the arena streams once. ``page_rows`` selects the kernel's
+    paged (HBM-resident, double-buffered DMA) regime; bits are unchanged
+    (see arena_scan.kernel)."""
     interpret = default_interpret(interpret)
+    if blk_b is None:
+        blk_b = default_blk_b(q.shape[0], ScanSpec())
     if k > emb.shape[0]:   # LIMIT larger than the arena: SQL semantics
         k_eff = emb.shape[0]
         s, i = filtered_topk(q, emb, tenant, updated_at, category, acl, pred,

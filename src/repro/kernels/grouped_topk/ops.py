@@ -26,10 +26,11 @@ import jax.numpy as jnp
 from repro.kernels.arena_scan.ops import (BLK_SCAN,  # noqa: F401
                                           _META_CACHE, _pack_meta,
                                           _packed_meta, _pad_axis0,
-                                          default_blk_n, default_interpret,
+                                          default_blk_b, default_blk_n,
+                                          default_interpret,
                                           default_use_kernel, pad_d128,
                                           pad_dead_rows)
-from repro.kernels.arena_scan.stages import B_LANES
+from repro.kernels.arena_scan.stages import ScanSpec
 from repro.kernels.grouped_topk.grouped_topk import grouped_topk_pallas
 from repro.kernels.grouped_topk.ref import NEG_INF, grouped_topk_scan_ref
 
@@ -57,7 +58,7 @@ def _run(q, emb, meta, gids, preds, k, use_kernel, blk_b, blk_n, page_rows,
 
 def grouped_topk(q, emb, tenant, updated_at, category, acl, gids, preds,
                  k: int, *, use_kernel: bool | None = None,
-                 blk_b: int = B_LANES, blk_n: int | None = None,
+                 blk_b: int | None = None, blk_n: int | None = None,
                  page_rows: int | None = None,
                  interpret: bool | None = None):
     """Fused multi-predicate grouped top-k over one arena scan.
@@ -70,7 +71,9 @@ def grouped_topk(q, emb, tenant, updated_at, category, acl, gids, preds,
 
     ``use_kernel=None`` picks the Pallas kernel on a TPU backend and the jnp
     streaming scan elsewhere; tests pass ``use_kernel=True, interpret=True``
-    to execute the kernel body on CPU. ``blk_n=None`` picks the engine's
+    to execute the kernel body on CPU. ``blk_b=None`` takes
+    `default_blk_b`: the kernel holds the whole batch in one query-row
+    block, so the arena streams once. ``blk_n=None`` picks the engine's
     default tile (512 VMEM rows for the kernel; `BLK_SCAN` for the jnp
     scan, clamped to the arena so small stores stay single-tile).
     ``page_rows`` selects the paged regime: the Pallas kernel switches to
@@ -79,6 +82,8 @@ def grouped_topk(q, emb, tenant, updated_at, category, acl, gids, preds,
     """
     use_kernel = default_use_kernel(use_kernel)
     interpret = default_interpret(interpret)
+    if blk_b is None:
+        blk_b = default_blk_b(q.shape[0], ScanSpec())
     if blk_n is None:
         blk_n = default_blk_n(emb.shape[0], use_kernel)
     n = emb.shape[0]

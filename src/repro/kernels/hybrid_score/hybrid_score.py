@@ -53,6 +53,12 @@ from repro.kernels.arena_scan.kernel import arena_scan_pallas
 from repro.kernels.arena_scan.stages import B_LANES, ScanSpec
 
 
+def hybrid_spec(mode: str) -> ScanSpec:
+    """The scan a fusion mode runs: ``wsum`` one fused list, ``rrf`` the
+    two per-signal lists."""
+    return ScanSpec(score="fused" if mode == "wsum" else "both")
+
+
 def hybrid_score_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                         terms: jax.Array, lexnorm: jax.Array,
                         gids: jax.Array, preds: jax.Array,
@@ -76,10 +82,8 @@ def hybrid_score_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
         # fold fusion weights into the inputs (pinning rule 1)
         q = q * jnp.float32(w_dense)
         qidf = qidf * jnp.float32(w_lex)
-        spec = ScanSpec(score="fused")
-    else:
-        spec = ScanSpec(score="both")
-    return arena_scan_pallas(q, emb, meta, gids, preds, k, spec=spec,
+    return arena_scan_pallas(q, emb, meta, gids, preds, k,
+                             spec=hybrid_spec(mode),
                              lex=(terms, lexnorm, qterms, qidf),
                              blk_b=blk_b, blk_n=blk_n, page_rows=page_rows,
                              interpret=interpret)

@@ -26,12 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.arena_scan.ops import (_packed_lanes, _packed_meta,
-                                          _pad_axis0, default_blk_n,
-                                          default_interpret,
+                                          _pad_axis0, default_blk_b,
+                                          default_blk_n, default_interpret,
                                           default_use_kernel, pad_d128,
                                           pad_dead_rows)
-from repro.kernels.arena_scan.stages import B_LANES
-from repro.kernels.hybrid_score.hybrid_score import hybrid_score_pallas
+from repro.kernels.hybrid_score.hybrid_score import (hybrid_score_pallas,
+                                                     hybrid_spec)
 from repro.kernels.hybrid_score.ref import (NEG_INF, hybrid_score_scan_ref,
                                             qidf_of, rrf_fuse)
 
@@ -76,7 +76,7 @@ def hybrid_score(q, emb, tenant, updated_at, category, acl, terms, lexnorm,
                  idf, gids, preds, qterms, k: int, *, mode: str = "wsum",
                  w_dense: float = 1.0, w_lex: float = 1.0,
                  rrf_c: float = 60.0, lists: bool = False,
-                 use_kernel: bool | None = None, blk_b: int = B_LANES,
+                 use_kernel: bool | None = None, blk_b: int | None = None,
                  blk_n: int | None = None, page_rows: int | None = None,
                  interpret: bool | None = None):
     """Fused hybrid dense+BM25 grouped top-k over ONE arena scan.
@@ -99,10 +99,11 @@ def hybrid_score(q, emb, tenant, updated_at, category, acl, terms, lexnorm,
     Returns (scores (B, k) f32, slots (B, k) i32, -1 past the fill).
     ``use_kernel=None`` picks the Pallas kernel on a TPU backend and the
     jnp streaming scan elsewhere; tests pass ``use_kernel=True,
-    interpret=True`` to execute the kernel body on CPU. ``page_rows``
-    selects the paged regime: the Pallas kernel switches to HBM-resident
-    streams with double-buffered DMA, the jnp scan tiles at the page size
-    — bits are unchanged either way (arena_scan contract).
+    interpret=True`` to execute the kernel body on CPU. ``blk_b=None``
+    takes `default_blk_b` (8-row blocks: the BM25 stage is per query row).
+    ``page_rows`` selects the paged regime: the Pallas kernel switches to
+    HBM-resident streams with double-buffered DMA, the jnp scan tiles at
+    the page size — bits are unchanged either way (arena_scan contract).
     """
     if lists and mode != "rrf":
         raise ValueError("lists=True is only meaningful for mode='rrf'")
@@ -110,6 +111,8 @@ def hybrid_score(q, emb, tenant, updated_at, category, acl, terms, lexnorm,
         raise ValueError(f"unknown fusion mode {mode!r}")
     use_kernel = default_use_kernel(use_kernel)
     interpret = default_interpret(interpret)
+    if blk_b is None:
+        blk_b = default_blk_b(q.shape[0], hybrid_spec(mode))
     if blk_n is None:
         blk_n = default_blk_n(emb.shape[0], use_kernel)
     n = emb.shape[0]

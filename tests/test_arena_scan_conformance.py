@@ -42,7 +42,9 @@ from repro.api.plan import LogicalPlan
 from repro.api.planner import PlannerConfig, compile_plan
 from repro.core.query import (Predicate, stack_predicates, unified_query,
                               unified_query_ref)
-from repro.kernels.arena_scan.ops import _pad_axis0, pad_d128
+from repro.kernels.arena_scan.ops import _pad_axis0, default_blk_b, pad_d128
+from repro.kernels.arena_scan.stages import ScanSpec
+from repro.kernels.filtered_topk.ops import filtered_topk
 from repro.kernels.grouped_topk.ops import _packed_meta, grouped_topk
 from repro.kernels.grouped_topk.ref import grouped_topk_ref
 from repro.kernels.hybrid_score.ops import hybrid_score
@@ -316,6 +318,45 @@ def test_conformance_matrix(family, B, N, D, k, G, qt, page, rng):
     if preds is not None:   # ivf asserts its slot-lane leakage inline
         for name, (_, slots) in outs.items():
             _assert_no_leak(store, preds, gids, slots)
+
+
+# ---------------------------------------------------------------------------
+# query-row block: the dense kernel holds the whole batch in one block
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("family", ["filtered", "grouped"])
+@pytest.mark.parametrize("B", [16, 32])
+@pytest.mark.parametrize("page", [None, 256])
+def test_query_block_bits(family, B, page, rng):
+    """The dense kernel at its default query-row block (the whole padded
+    batch, one arena stream) returns the same bits as at 8-row blocks (one
+    stream per 8 rows), resident and paged, and leaks no row."""
+    N, D, k, G = 1000, 96, 10, 3
+    blk_b = default_blk_b(B, ScanSpec())
+    assert blk_b == B
+    store = _arena(rng, N, D)
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    cols = (store["emb"], store["tenant"], store["updated_at"],
+            store["category"], store["acl"])
+    if family == "filtered":
+        preds, gids = [Predicate(tenant=1, min_ts=100)], np.zeros(B, np.int32)
+
+        def call(**kw):
+            return filtered_topk(q, *cols, preds[0].as_array(), k,
+                                 page_rows=page, interpret=True, **kw)
+    else:
+        preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
+        gids = rng.integers(0, G, B).astype(np.int32)
+
+        def call(**kw):
+            return grouped_topk(q, *cols, gids, stack_predicates(preds), k,
+                                use_kernel=True, interpret=True,
+                                page_rows=page, **kw)
+
+    outs = {"blk_b=8": call(blk_b=8), f"blk_b={blk_b}": call()}
+    _assert_all_equal(outs)
+    for _, slots in outs.values():
+        _assert_no_leak(store, preds, gids, slots)
 
 
 # ---------------------------------------------------------------------------

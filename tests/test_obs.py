@@ -37,6 +37,7 @@ from repro.api import RagDB
 from repro.api.planner import CostModel, PlannerConfig
 from repro.core import StoreConfig
 from repro.data.corpus import DAY_S, CorpusConfig, make_corpus
+from repro.index.lexical import LexicalConfig
 from repro.obs import CalibrationTable, FanSpan, FlightRecorder, Tracer
 from repro.obs.calibration import pow2_bucket
 from repro.serving.faults import FaultPlan, FaultRule
@@ -283,7 +284,8 @@ def test_launch_span_carries_launched_shape():
     db.attach_tracer(Tracer(enabled=True, recorder=rec))
     rng = np.random.default_rng(3)
     sess = db.admin_session()
-    for n, bucket, passes in ((9, 16, 2), (5, 8, 1)):
+    # a dense launch holds its whole bucket in one query-row block
+    for n, bucket in ((9, 16), (5, 8)):
         plans = [sess.search(rng.standard_normal(ccfg.dim).astype(np.float32),
                              normalize=False).using("pallas").limit(6).plan()
                  for _ in range(n)]
@@ -293,12 +295,56 @@ def test_launch_span_carries_launched_shape():
         assert len(spans) == n
         assert {s.ann["unit"] for s in spans} == {spans[0].ann["unit"]}
         ann = spans[0].ann
-        assert (ann["family"], ann["rows"], ann["bucket"], ann["passes"]) \
-            == ("filtered", n, bucket, passes)
+        assert (ann["family"], ann["rows"], ann["bucket"], ann["block_rows"],
+                ann["passes"]) == ("filtered", n, bucket, bucket, 1)
     # the ref engine scans without the kernel: no passes to count
     db.execute(_plans(db, ccfg, 3), use_cache=False)
     ann = next(s.ann for s in rec.traces()[-1].spans if s.name == "launch")
     assert ann["family"] == "ref" and "passes" not in ann
+
+
+def _lexical_db(n_docs=300, dim=16):
+    ccfg = CorpusConfig(n_docs=n_docs, dim=dim, n_tenants=3, n_categories=4,
+                        vocab_size=256, n_entity_terms=32)
+    db = RagDB(StoreConfig(capacity=512, dim=dim), now_ts=ccfg.now_ts,
+               lexical_cfg=LexicalConfig(vocab_size=256,
+                                         doc_terms=ccfg.doc_terms))
+    db.ingest(make_corpus(ccfg))
+    return db, ccfg
+
+
+@pytest.mark.parametrize("family,n,bucket,block_rows", [
+    ("grouped", 9, 16, 16),        # a dense bucket of 16: one arena pass
+    ("grouped", 200, 256, 128),    # past the MXU width: 128-row blocks
+    ("hybrid", 9, 16, 8),          # the BM25 stage keeps 8-row blocks
+    ("hybrid", 3, 4, 8),
+])
+def test_launch_passes_follow_block_rows(family, n, bucket, block_rows):
+    """`rag.launch`'s ``passes`` counts the arena streams the launch made:
+    ceil(bucket / block_rows), with the block the scan wrappers take
+    (`default_blk_b`)."""
+    db, ccfg = _lexical_db() if family == "hybrid" else _db()
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+    rng = np.random.default_rng(5)
+    sess = db.admin_session()
+    plans = []
+    for i in range(n):
+        b = sess.search(rng.standard_normal(ccfg.dim).astype(np.float32),
+                        normalize=False)
+        if family == "hybrid":
+            b = b.match([int(t) for t in rng.integers(0, 256, 2)])
+        else:
+            # four predicate groups, fused into one scan
+            b = b.newer_than(i % 4).using("pallas")
+        plans.append(b.limit(6).plan())
+    db.execute(plans, use_cache=False)
+    (ann,) = {tuple(sorted(s.ann.items())) for t in rec.traces()[-n:]
+              for s in t.spans if s.name == "launch"}
+    ann = dict(ann)
+    assert (ann["family"], ann["rows"], ann["bucket"], ann["block_rows"]) \
+        == (family, n, bucket, block_rows)
+    assert ann["passes"] == -(-bucket // block_rows)
 
 
 def test_results_bit_identical_under_profiler(tmp_path):

@@ -21,6 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api.planner import PlannerConfig
 from repro.core.store import ShardPlacement, StoreConfig, _empty_lanes
+from repro.kernels.arena_scan.ops import default_blk_b
+from repro.kernels.arena_scan.stages import ScanSpec
 from repro.kernels.arena_scan.sharded import make_sharded_arena_scan
 from repro.kernels.grouped_topk.ops import grouped_topk
 from repro.kernels.hybrid_score.ops import hybrid_score
@@ -88,6 +90,23 @@ def test_dense_paged_compiles_at_planner_page(one_chip):
             page_rows=page),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
         sds((B,), jnp.int32), sds((1, 4), jnp.int32))
+
+
+@pytest.mark.parametrize("rows,paged", [(16, False), (128, False),
+                                        (16, True)])
+def test_dense_query_block_compiles(one_chip, rows, paged):
+    """The dense kernel at its default query-row block (the launch's whole
+    batch, up to the MXU's 128 rows), resident and at the planner's page:
+    Mosaic takes the wider block within the scoped VMEM."""
+    assert default_blk_b(rows, ScanSpec()) == rows
+    sds, a = _arena(one_chip)
+    page = PlannerConfig().page_rows if paged else None
+    _assert_kernel(
+        lambda q, e, t, ts, c, acl, g, p: grouped_topk(
+            q, e, t, ts, c, acl, g, p, K, use_kernel=True, interpret=False,
+            page_rows=page),
+        sds((rows, D), jnp.float32), a["emb"], a["tenant"], a["ts"],
+        a["cat"], a["acl"], sds((rows,), jnp.int32), sds((4, 4), jnp.int32))
 
 
 def test_ivf_slot_lane_compiles(one_chip):
