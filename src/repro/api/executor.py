@@ -369,7 +369,8 @@ def _pad_group_launch(q: np.ndarray, gids: np.ndarray,
                       preds: list[Predicate], k: int, engine: str, *,
                       stats: ExecStats | None,
                       shapes: CompiledShapes | None, lex=None,
-                      page_rows: int | None = None):
+                      page_rows: int | None = None,
+                      groups_per_row: bool = False):
     """Shared bucket/blocker padding for fused grouped launches.
 
     Pads the predicate stack to a pow2 group count with `BLOCK_ALL` rows
@@ -379,14 +380,20 @@ def _pad_group_launch(q: np.ndarray, gids: np.ndarray,
     row, allocates no result rows (asserted via `_Hot.pad_check` at
     finish), and cannot waste a real group's predicate on dead queries.
     When row padding is needed and every lane is real, one extra blocker
-    bucket is opened to hold the padding rows.
+    bucket is opened to hold the padding rows. ``groups_per_row`` (hybrid)
+    pads the groups to the row bucket itself, which always has room for
+    the blocker (a group holds at least one real row): one program per row
+    bucket instead of one per (rows, groups) bucket pair. The group select
+    is an exact 0/1 one-hot, so blocker lanes change no bits.
 
     Returns (q, gids, preds, n_valid) with every array launch-ready."""
     n_valid = q.shape[0]
     g_real = len(preds)
     bucket = bucket_rows(n_valid) if shapes is not None else n_valid
     g_bucket = bucket_rows(g_real)
-    if bucket > n_valid and g_bucket == g_real:
+    if groups_per_row:
+        g_bucket = bucket_rows(bucket)
+    elif bucket > n_valid and g_bucket == g_real:
         g_bucket = bucket_rows(g_real + 1)   # open a lane for the blocker
     preds = list(preds) + [BLOCK_ALL] * (g_bucket - g_real)
     if stats is not None:
@@ -439,7 +446,7 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
     from repro.kernels.hybrid_score.ops import hybrid_score
     q, gids, preds, n_valid = _pad_group_launch(
         q, gids, preds, k, "hybrid", stats=stats, shapes=shapes, lex=lex_key,
-        page_rows=page_rows)
+        page_rows=page_rows, groups_per_row=True)
     if q.shape[0] != qterms.shape[0]:
         qterms = np.concatenate(
             [qterms, np.full((q.shape[0] - qterms.shape[0], qterms.shape[1]),
@@ -800,6 +807,8 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
     # -- phase 1: launch every hot program (no device_get yet) -----------
     # each entry: (unit, member row-index lists, real row count, _Hot)
     inflight = []
+    batch = (tracer.next_batch()
+             if row_traces is not None and tracer is not None else None)
     for unit in units:
         member_idxs = [groups[p.group_key] for p in unit.plans]
         rep = unit.plans[0]
@@ -822,10 +831,10 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
                 [np.full(len(m), g, np.int32)
                  for g, m in enumerate(member_idxs)])
             mode, qt_bucket, w_d, w_l = rep.lex
+            qterms = _qterms_rows(row_plans, idxs, qt_bucket)
             hot = _launch_hybrid(
                 hot_store, lex.snapshot(), q_all[np.asarray(idxs)], gids,
-                [p.pred for p in unit.plans],
-                _qterms_rows(row_plans, idxs, qt_bucket), k, mode=mode,
+                [p.pred for p in unit.plans], qterms, k, mode=mode,
                 w_dense=w_d, w_lex=w_l, rrf_c=lex.cfg.rrf_c,
                 lists=(mode == "rrf" and rep.route == "hot+warm"),
                 stats=stats, shapes=shapes, lex_key=rep.lex,
@@ -871,13 +880,19 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
             family = _family(rep.engine, unit.fused)
             shape = {"rows": sum(len(m) for m in member_idxs),
                      "bucket": bucket, "family": family,
-                     "page_rows": rep.page_rows}
+                     "page_rows": rep.page_rows, "batch": batch}
             if family in SCAN_FAMILIES:
                 spec = (hybrid_spec(rep.lex[0]) if family == "hybrid"
                         else ScanSpec())
                 blk_b = default_blk_b(bucket, spec)
                 shape["block_rows"] = blk_b
                 shape["passes"] = -(-bucket // blk_b)
+            if family == "hybrid":
+                # the lexical stage's loop: ``lanes`` x ``qt`` compares a
+                # row, of which ``qterms`` (summed over the rows) are real
+                shape.update(mode=rep.lex[0], qt=rep.lex[1],
+                             qterms=int((qterms >= 0).sum()),
+                             lanes=int(lex.cfg.doc_terms))
             fan.end(**shape)
         inflight.append((unit, member_idxs, hot))
         if stats is not None:

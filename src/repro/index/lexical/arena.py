@@ -37,7 +37,10 @@ _TOKEN_RE = re.compile(r"[a-z0-9_]+")
 
 @dataclasses.dataclass(frozen=True)
 class LexicalConfig:
-    """Shape and scoring knobs of the postings arena.
+    """Shape and scoring knobs of the postings arena. The defaults are
+    the program's test widths; a deployment states its own (MS MARCO
+    passages take 64 lanes of BERT-base's 30,522 WordPiece ids, a width at
+    which the hybrid kernel compiles and runs).
 
     >>> LexicalConfig().doc_terms
     16

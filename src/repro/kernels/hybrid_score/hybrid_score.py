@@ -40,9 +40,15 @@ body, both residency regimes (resident BlockSpec pipelining / paged
 double-buffered DMA), and the running top-k merges live in the framework.
 
 CPU CI executes this body in interpret mode (bit-identity vs the jnp
-refs); tests/test_tpu_compile.py compiles it for a described TPU v5e at
-D=768, T=16, QT=16 at the wrapper's fixed tile (blk_n=512), and
-chip_smoke.py runs it compiled on the chip.
+refs, at T=64 lanes and QT=16 over a 30,522-id vocabulary too);
+tests/test_tpu_compile.py compiles it for a described TPU v5e at D=768,
+QT=16 and T=16 or 64, at the wrapper's resident tile (blk_n=512) and at
+the planner's 2048-row page. On the chip it runs compiled in
+chip_smoke.py (T=16) and, through the served path (RagDB, Scheduler,
+executor), at 2^20 x 768 with T=64 lanes of a 30,522-id vocabulary (MS
+MARCO widths) on a v5e. The T x QT lexical loop stays unrolled: a
+`fori_loop` over lanes read from the VMEM refs compiles faster but took
+twice the device time of a pass on a v5e.
 """
 from __future__ import annotations
 

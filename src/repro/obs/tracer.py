@@ -468,6 +468,7 @@ class Tracer:
         self.clock = clock
         self._seq = 0
         self._units = 0
+        self._batches = 0
         self._active: list = []
 
     @property
@@ -495,6 +496,12 @@ class Tracer:
         and profiler events), deterministic per tracer like trace ids."""
         self._units += 1
         return self._units
+
+    def next_batch(self) -> int:
+        """The next launched batch's sequence number (``batch`` on its
+        units' ``launch`` spans): the units one batch split into share it."""
+        self._batches += 1
+        return self._batches
 
     def measure(self, name: str) -> "Measured":
         """Time a block on this tracer's clock as the profiler event

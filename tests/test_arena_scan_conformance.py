@@ -62,10 +62,15 @@ V, T_LANES = 64, 6
 # shared fixtures: one arena schema serves every family
 # ---------------------------------------------------------------------------
 
-def _arena(rng, n, d, n_tenants=5):
-    terms = rng.integers(-1, V, (n, T_LANES)).astype(np.int32)
+def _arena(rng, n, d, n_tenants=5, vocab=V, lanes=T_LANES, pool=None):
+    """A random arena; with ``pool`` its term lanes draw from those ids
+    (and empty lanes) instead of the whole vocabulary."""
+    if pool is None:
+        terms = rng.integers(-1, vocab, (n, lanes)).astype(np.int32)
+    else:
+        terms = rng.choice(np.append(pool, -1), (n, lanes)).astype(np.int32)
     lexnorm = np.where(terms >= 0,
-                       (rng.random((n, T_LANES)) * 2).astype(np.float32),
+                       (rng.random((n, lanes)) * 2).astype(np.float32),
                        0.0).astype(np.float32)
     return {
         "emb": jnp.asarray(rng.standard_normal((n, d)).astype(np.float32)),
@@ -76,7 +81,7 @@ def _arena(rng, n, d, n_tenants=5):
                            .astype(np.uint32)),
         "terms": jnp.asarray(terms),
         "lexnorm": jnp.asarray(lexnorm),
-        "idf": jnp.asarray((rng.random(V) * 5).astype(np.float32)),
+        "idf": jnp.asarray((rng.random(vocab) * 5).astype(np.float32)),
     }
 
 
@@ -226,11 +231,16 @@ def _lanes_ivf(rng, store, B, N, D, k, G, qt, page):
     return outs, None, None
 
 
-def _lanes_hybrid(mode):
+def _lanes_hybrid(mode, pool=None):
+    """Hybrid lanes; with ``pool`` the query terms draw from those ids."""
     def lanes(rng, store, B, N, D, k, G, qt, page):
         q = rng.standard_normal((B, D)).astype(np.float32)
-        qterms = rng.integers(-1, V, (B, qt)).astype(np.int32)
-        qterms[:, 0] = rng.integers(0, V, B)     # at least one real term
+        if pool is None:
+            qterms = rng.integers(-1, V, (B, qt)).astype(np.int32)
+            qterms[:, 0] = rng.integers(0, V, B)  # at least one real term
+        else:
+            qterms = rng.choice(np.append(pool, -1), (B, qt)).astype(np.int32)
+            qterms[:, 0] = rng.choice(pool, B)
         gids = rng.integers(0, G, B).astype(np.int32)
         preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
         pa = stack_predicates(preds)
@@ -318,6 +328,157 @@ def test_conformance_matrix(family, B, N, D, k, G, qt, page, rng):
     if preds is not None:   # ivf asserts its slot-lane leakage inline
         for name, (_, slots) in outs.items():
             _assert_no_leak(store, preds, gids, slots)
+
+
+# ---------------------------------------------------------------------------
+# MS MARCO widths: 64 postings lanes, 16 query terms, the 30,522-id
+# WordPiece vocabulary of BERT-base
+# ---------------------------------------------------------------------------
+
+V_MSMARCO, T_MSMARCO = 30522, 64
+
+
+@pytest.mark.parametrize("mode", ["wsum", "rrf"])
+def test_hybrid_msmarco_widths(mode, rng):
+    """At T = 64 lanes and QT = 16 query terms over the whole vocabulary,
+    the oracle, the streaming scan and the kernel body (interpret mode),
+    resident and on 256-row pages, return the same bits; and no row of a
+    tenant no group asks for surfaces, though rows of one hold every query
+    term at the largest lane weight."""
+    B, N, D, k, G, qt, page = 5, 640, 32, 8, 3, 16, 256
+    pool = np.union1d(rng.choice(V_MSMARCO, 46, replace=False),
+                      [0, V_MSMARCO - 1]).astype(np.int32)
+    store = _arena(rng, N, D, vocab=V_MSMARCO, lanes=T_MSMARCO, pool=pool)
+    terms = np.asarray(store["terms"]).copy()
+    lexnorm = np.asarray(store["lexnorm"]).copy()
+    foreign = np.arange(0, N, 64)                 # tenant 4: no group's
+    terms[foreign] = -1
+    terms[foreign, :len(pool)] = pool
+    lexnorm[foreign] = np.where(terms[foreign] >= 0, 2.0, 0.0)
+    store.update(terms=jnp.asarray(terms), lexnorm=jnp.asarray(lexnorm),
+                 tenant=store["tenant"].at[foreign].set(4))
+    outs, preds, gids = _lanes_hybrid(mode, pool)(rng, store, B, N, D, k, G,
+                                                  qt, page)
+    assert {"scan-paged", "kernel-paged"} <= outs.keys()
+    _assert_all_equal(outs)
+    for _, slots in outs.values():
+        _assert_no_leak(store, preds, gids, slots)
+        assert not np.isin(np.asarray(slots), foreign).any()
+
+
+@pytest.mark.parametrize("mode", ["wsum", "rrf"])
+def test_hybrid_group_padding_bits(mode, rng):
+    """A hybrid launch pads its predicate groups to its row bucket with
+    blocker lanes, so one program serves every group count of a bucket.
+    The group select is an exact 0/1 one-hot: the padded launch returns
+    the bits of the unpadded scan, in the kernel body and the scan."""
+    N, D, B, G, k, qt = 640, 32, 5, 2, 8, 4
+    store = _arena(rng, N, D)
+    lex = {"terms": store["terms"], "lexnorm": store["lexnorm"],
+           "idf": store["idf"]}
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    qterms = rng.integers(0, V, (B, qt)).astype(np.int32)
+    gids = np.asarray([i % G for i in range(B)], np.int32)
+    preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
+    kw = dict(mode=mode, w_dense=W_DENSE, w_lex=W_LEX, rrf_c=60.0)
+
+    stats, shapes = ExecStats(), CompiledShapes()
+    s_l, i_l = _finish_hot(_launch_hybrid(dict(store), lex, q, gids, preds,
+                                          qterms, k, stats=stats,
+                                          shapes=shapes, **kw))
+    assert stats.padded_rows == 8 - B
+    assert stats.padded_groups == 8 - G          # groups to the row bucket
+    qp, gp, pp, _ = executor_mod._pad_group_launch(
+        q, gids, preds, k, "hybrid", stats=None, shapes=CompiledShapes(),
+        groups_per_row=True)
+    qtp = np.concatenate([qterms, np.full((8 - B, qt), -1, np.int32)])
+    cols = (store["emb"], store["tenant"], store["updated_at"],
+            store["category"], store["acl"], store["terms"],
+            store["lexnorm"], store["idf"])
+    for use_kernel in (False, True):
+        ref = hybrid_score(q, *cols, gids, stack_predicates(preds), qterms,
+                           k, use_kernel=use_kernel, interpret=True, **kw)
+        pad = hybrid_score(qp, *cols, gp, stack_predicates(pp), qtp, k,
+                           use_kernel=use_kernel, interpret=True, **kw)
+        _assert_all_equal({"unpadded": ref,
+                           "padded": tuple(a[:B] for a in pad),
+                           "launch": (s_l[:B], i_l[:B])})
+        _assert_no_leak(store, preds, gids, ref[1])
+
+
+@pytest.mark.parametrize("engine,groups_per_row,n_shapes", [
+    ("hybrid", True, 5),        # one per row bucket 1, 2, 4, 8, 16
+    ("grouped", False, 15),     # every (rows, groups) bucket pair, as before
+])
+def test_group_launch_shapes_at_batch_16(engine, groups_per_row, n_shapes):
+    """The launch shapes a batch of up to 16 reads in 1 to 16 predicate
+    groups reaches. A hybrid launch pads its groups to the row bucket, so
+    at 2 fusion modes x 5 query-term buckets its warm-up compiles 50
+    programs (150 with a shape per groups bucket); dense shapes stay."""
+    shapes = set()
+    for n in range(1, 17):
+        for g in range(1, n + 1):
+            q, gids, preds, n_valid = executor_mod._pad_group_launch(
+                np.zeros((n, 8), np.float32),
+                np.arange(n, dtype=np.int32) % g,
+                [Predicate(tenant=t) for t in range(g)], 8, engine,
+                stats=None, shapes=CompiledShapes(),
+                groups_per_row=groups_per_row)
+            assert n_valid == n and len(gids) == q.shape[0]
+            shapes.add((q.shape[0], len(preds)))
+    assert len(shapes) == n_shapes
+
+
+def _products_over(jaxpr, width: int) -> list:
+    """Precision of every dot_general in ``jaxpr`` (sub-jaxprs included:
+    the jitted wrapper, the Pallas kernel body, loop bodies) that contracts
+    an axis of ``width``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (ca, _), _ = eqn.params["dimension_numbers"]
+            if any(eqn.invars[0].aval.shape[a] == width for a in ca):
+                out.append(eqn.params["precision"])
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _products_over(sub, width)
+    return out
+
+
+@pytest.mark.parametrize("family", ["grouped", "wsum", "rrf"])
+@pytest.mark.parametrize("use_kernel", [True, False], ids=["kernel", "scan"])
+@pytest.mark.parametrize("page", [None, 2048], ids=["resident", "paged"])
+def test_similarity_product_is_exact_f32(family, use_kernel, page):
+    """Every product over the embedding width, in the kernel the chip runs
+    and in the jnp scan, dense and hybrid (wsum and rrf), resident and
+    paged, is pinned to `Precision.HIGHEST`: the configurations state
+    exact f32 similarity, and on a TPU any lower precision (HIGH: three
+    bf16 passes; DEFAULT: one) changes scores by more than f32 rounding.
+    On the CPU every precision gives the same bits, so the bit-equality
+    grids above cannot see a lower one; this reads the traced programs."""
+    import jax
+    N, D, B, G, QT = 4096, 768, 8, 4, 16
+    f32, i32 = jnp.float32, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    arena = (sds((B, D), f32), sds((N, D), f32), sds((N,), i32),
+             sds((N,), i32), sds((N,), i32), sds((N,), jnp.uint32))
+    tail = (sds((B,), i32), sds((G, 4), i32))
+    kw = dict(use_kernel=use_kernel, interpret=False, page_rows=page)
+    if family == "grouped":
+        def scan(*a):
+            return grouped_topk(*a, 10, **kw)
+        args = arena + tail
+    else:
+        def scan(*a):
+            return hybrid_score(*a, 10, mode=family, **kw)
+        args = arena + (sds((N, T_MSMARCO), i32), sds((N, T_MSMARCO), f32),
+                        sds((V_MSMARCO,), f32)) + tail + (sds((B, QT), i32),)
+    precisions = _products_over(jax.make_jaxpr(scan)(*args).jaxpr, D)
+    assert precisions, "no similarity product found"
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    assert all(p == highest for p in precisions), precisions
 
 
 # ---------------------------------------------------------------------------

@@ -347,6 +347,37 @@ def test_launch_passes_follow_block_rows(family, n, bucket, block_rows):
     assert ann["passes"] == -(-bucket // block_rows)
 
 
+def test_launch_span_carries_lexical_loop():
+    """A hybrid unit's `rag.launch` carries its fusion ``mode``, its
+    query-term bucket ``qt``, the real terms of its rows (``qterms``), the
+    postings ``lanes``, and the scheduler ``batch`` it was launched in,
+    which the units of one batch share; a dense launch carries its batch
+    too."""
+    db, ccfg = _lexical_db()
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+    rng = np.random.default_rng(9)
+    sess = db.admin_session()
+    reads = [("wsum", [1]), ("wsum", [2]), ("wsum", [3, 4, 5]),
+             ("rrf", [6, 7, 8, 9, 10]), ("rrf", [11, 12, 13, 14, 15])]
+    plans = [sess.search(rng.standard_normal(ccfg.dim).astype(np.float32),
+                         normalize=False).match(t).fuse(m).limit(6).plan()
+             for m, t in reads]
+    plans += [sess.search(rng.standard_normal(ccfg.dim).astype(np.float32),
+                          normalize=False).limit(6).plan()]
+    _serve(db, plans, max_batch=5)
+    launches = {s.ann["unit"]: s.ann for t in rec.traces() for s in t.spans
+                if s.name == "launch"}
+    hybrid = sorted(((a["mode"], a["qt"], a["rows"], a["qterms"],
+                      a["lanes"], a["batch"])
+                     for a in launches.values() if a["family"] == "hybrid"))
+    assert hybrid == [("rrf", 8, 2, 10, ccfg.doc_terms, 1),
+                      ("wsum", 1, 2, 2, ccfg.doc_terms, 1),
+                      ("wsum", 4, 1, 3, ccfg.doc_terms, 1)]
+    (dense,) = [a for a in launches.values() if a["family"] != "hybrid"]
+    assert dense["batch"] == 2 and "qterms" not in dense
+
+
 def test_results_bit_identical_under_profiler(tmp_path):
     db, ccfg = _db()
     plans = _plans(db, ccfg, 5)

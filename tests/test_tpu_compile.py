@@ -120,16 +120,33 @@ def test_ivf_slot_lane_compiles(one_chip):
         sds((16,), jnp.int32), sds((4,), jnp.int32))
 
 
-@pytest.mark.parametrize("mode", ["wsum", "rrf"])     # fused / both lists
-def test_hybrid_compiles(one_chip, mode):
+#: MS MARCO widths: 64 postings lanes of BERT-base's 30,522 WordPiece
+#: ids.
+T_MSMARCO, V_MSMARCO = 64, 30522
+
+
+@pytest.mark.parametrize("mode,lanes,vocab,page", [
+    pytest.param("wsum", T, V, None, id="wsum"),          # fused list
+    pytest.param("rrf", T, V, None, id="rrf"),            # both lists
+    pytest.param("wsum", T_MSMARCO, V_MSMARCO, None, id="wsum-T64"),
+    pytest.param("rrf", T_MSMARCO, V_MSMARCO, None, id="rrf-T64"),
+    pytest.param("wsum", T_MSMARCO, V_MSMARCO, 2048, id="wsum-T64-paged"),
+    pytest.param("rrf", T_MSMARCO, V_MSMARCO, 2048, id="rrf-T64-paged"),
+])
+def test_hybrid_compiles(one_chip, mode, lanes, vocab, page):
+    """The hybrid kernel at QT = 16 query terms, at the program's default
+    16 lanes and at MS MARCO's 64 lanes, resident (512-row
+    tiles) and at the planner's 2048-row page: the unrolled T x QT lexical
+    loop fits the scoped VMEM."""
+    assert page in (None, PlannerConfig().page_rows)
     sds, a = _arena(one_chip)
     _assert_kernel(
         lambda q, e, t, ts, c, acl, tm, ln, idf, g, p, qt: hybrid_score(
             q, e, t, ts, c, acl, tm, ln, idf, g, p, qt, K, mode=mode,
-            use_kernel=True, interpret=False),
+            use_kernel=True, interpret=False, page_rows=page),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
-        sds((N, T), jnp.int32), sds((N, T), jnp.float32),
-        sds((V,), jnp.float32), sds((B,), jnp.int32),
+        sds((N, lanes), jnp.int32), sds((N, lanes), jnp.float32),
+        sds((vocab,), jnp.float32), sds((B,), jnp.int32),
         sds((4, 4), jnp.int32), sds((B, QT), jnp.int32))
 
 
