@@ -88,6 +88,9 @@ class ExecStats:
     terms_scanned: int = 0        # postings lanes streamed by hybrid scans
                                   # (N * doc_terms per one-pass scan) — the
                                   # lexical bandwidth audit trail
+    lex_bucket_joins: int = 0     # hybrid launches that joined groups of two
+                                  # or more query-term buckets, their terms
+                                  # padded to the largest (planner.fuse_batch)
     paged_scans: int = 0          # hot-tier programs launched in the paged
                                   # arena-scan regime (plan.page_rows set):
                                   # the memory-traffic audit — bits are
@@ -127,9 +130,9 @@ class CompiledShapes:
     A shape is ``(engine, bucket_rows, k)`` — fused grouped scans append
     their pow2-padded group count (the (G, 4) predicate block is part of
     the program shape), and hybrid scans additionally their score-mix
-    identity (fusion mode + query-term-count bucket + weights, which bake
-    into the compiled program). Paged launches key on their page size too:
-    paged and resident regimes compile different programs (different grid
+    identity (fusion mode + the launch's query-term bucket + weights, which
+    bake into the compiled program). Paged launches key on their page size
+    too: paged and resident regimes compile different programs (different grid
     + DMA schedule), and sharded launches on their mesh shard count (the
     merge gathers S*k candidates — an S-dependent shape). Bucketed batching guarantees that any group whose
     shape is in this set reuses the already-compiled program (XLA caches by
@@ -384,7 +387,9 @@ def _pad_group_launch(q: np.ndarray, gids: np.ndarray,
     pads the groups to the row bucket itself, which always has room for
     the blocker (a group holds at least one real row): one program per row
     bucket instead of one per (rows, groups) bucket pair. The group select
-    is an exact 0/1 one-hot, so blocker lanes change no bits.
+    is an exact 0/1 one-hot, so blocker lanes change no bits. ``lex`` is a
+    hybrid launch's (mode, launched query-term bucket, weights), the shape
+    key of the program that runs.
 
     Returns (q, gids, preds, n_valid) with every array launch-ready."""
     n_valid = q.shape[0]
@@ -439,10 +444,13 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
     """Launch ONE fused hybrid dense+BM25 scan answering every predicate
     group in ``preds`` — the hybrid engine's only dispatch shape (a single
     group is G=1). ``lex_snap`` is `LexicalArena.snapshot()`; ``qterms``
-    is (B, QT) int32 per-row query terms, already bucketed to the plan's
-    query-term-count bucket. ``lists=True`` (rrf + tiered route) keeps the
-    two per-signal lists unfused: dense rides `_Hot.s/.sl`, bm25 rides
-    `_Hot.extra`, and the finish phase rank-fuses after the tier merges."""
+    is (B, QT) int32 per-row query terms, padded to the launch's QT: the
+    largest query-term bucket of its member groups (``lex_key`` names it).
+    A padding term adds exactly +0.0 to a row's BM25, so every row scores
+    the bits it would at its own bucket. ``lists=True`` (rrf + tiered
+    route) keeps the two per-signal lists unfused: dense rides
+    `_Hot.s/.sl`, bm25 rides `_Hot.extra`, and the finish phase rank-fuses
+    after the tier merges."""
     from repro.kernels.hybrid_score.ops import hybrid_score
     q, gids, preds, n_valid = _pad_group_launch(
         q, gids, preds, k, "hybrid", stats=stats, shapes=shapes, lex=lex_key,
@@ -670,8 +678,9 @@ def query_tiered(hot_store: Store, warm, q: jax.Array, pred: Predicate,
 
 def _qterms_rows(row_plans, idxs, qt_bucket: int) -> np.ndarray:
     """Per-row query-term matrix for a hybrid dispatch: row i's plan
-    supplies its lowered match() ids, padded with -1 to the unit's
-    query-term-count bucket (part of the fuse key, so every member fits)."""
+    supplies its lowered match() ids, padded with -1 to ``qt_bucket`` — a
+    launch's largest member bucket, or a warm probe's own bucket — so every
+    row fits."""
     qt = np.full((len(idxs), qt_bucket), -1, np.int32)
     for r, i in enumerate(idxs):
         t = row_plans[i].logical.match_terms or ()
@@ -802,7 +811,8 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
         groups.setdefault(p.group_key, []).append(i)
     reps = {key: row_plans[idxs[0]] for key, idxs in groups.items()}
     units = fuse_batch(list(reps.values()),
-                       cfg=planner_cfg or PlannerConfig())
+                       cfg=planner_cfg or PlannerConfig(),
+                       rows=[len(groups[key]) for key in reps])
 
     # -- phase 1: launch every hot program (no device_get yet) -----------
     # each entry: (unit, member row-index lists, real row count, _Hot)
@@ -830,18 +840,23 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
             gids = np.concatenate(
                 [np.full(len(m), g, np.int32)
                  for g, m in enumerate(member_idxs)])
-            mode, qt_bucket, w_d, w_l = rep.lex
-            qterms = _qterms_rows(row_plans, idxs, qt_bucket)
+            # a joined launch runs at its largest member bucket
+            mode, _, w_d, w_l = rep.lex
+            qts = {p.lex[1] for p in unit.plans}
+            qt = max(qts)
+            qterms = _qterms_rows(row_plans, idxs, qt)
             hot = _launch_hybrid(
                 hot_store, lex.snapshot(), q_all[np.asarray(idxs)], gids,
                 [p.pred for p in unit.plans], qterms, k, mode=mode,
                 w_dense=w_d, w_lex=w_l, rrf_c=lex.cfg.rrf_c,
                 lists=(mode == "rrf" and rep.route == "hot+warm"),
-                stats=stats, shapes=shapes, lex_key=rep.lex,
+                stats=stats, shapes=shapes, lex_key=(mode, qt, w_d, w_l),
                 page_rows=rep.page_rows)
-            if stats is not None and unit.fused:
-                stats.fused_groups += len(unit.plans)
-                stats.fused_scans += 1
+            if stats is not None:
+                stats.lex_bucket_joins += len(qts) > 1
+                if unit.fused:
+                    stats.fused_groups += len(unit.plans)
+                    stats.fused_scans += 1
         elif unit.fused:
             idxs = [i for m in member_idxs for i in m]
             gids = np.concatenate(
@@ -888,9 +903,10 @@ def launch_plans(hot_store: Store, warm, plans: list[PhysicalPlan], *,
                 shape["block_rows"] = blk_b
                 shape["passes"] = -(-bucket // blk_b)
             if family == "hybrid":
-                # the lexical stage's loop: ``lanes`` x ``qt`` compares a
-                # row, of which ``qterms`` (summed over the rows) are real
-                shape.update(mode=rep.lex[0], qt=rep.lex[1],
+                # the lexical stage's loop: ``lanes`` x ``qt`` (the launched
+                # QT) compares a row, of which ``qterms`` (summed over the
+                # rows) are real; ``qt_joined`` query-term buckets joined
+                shape.update(mode=rep.lex[0], qt=qt, qt_joined=len(qts),
                              qterms=int((qterms >= 0).sum()),
                              lanes=int(lex.cfg.doc_terms))
             fan.end(**shape)

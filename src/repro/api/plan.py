@@ -149,9 +149,10 @@ class PhysicalPlan:
         differently, and grouping them would apply one plan's tiers to the
         other's results. ``nprobe`` rides along so probe depths never mix
         inside one ivf group, and ``lex`` (fusion mode + query-term-count
-        bucket + weights) so hybrid groups only ever stack rows whose
-        compiled shape AND score semantics agree — the actual term ids are
-        per-row data, exactly like the query embedding. ``page_rows`` is
+        bucket + weights) so a hybrid group holds one score mix and one
+        bucket — the actual term ids are per-row data, exactly like the
+        query embedding, and the warm tier's probe runs per group at its
+        own bucket. ``page_rows`` is
         part of the key because paged and resident launches compile
         different programs (different grid + DMA schedule), even though
         they return the same bits. ``shards``/``placement`` likewise: the
@@ -176,11 +177,14 @@ class PhysicalPlan:
     def fuse_key(self) -> tuple:
         """Distinct predicate groups sharing this key are candidates for ONE
         fused grouped scan (planner.fuse_batch): same LIMIT k, same engine,
-        same tier route, same score mix (``lex`` — None for dense engines,
-        so dense and hybrid groups never fuse together), same paged/
-        resident regime, same mesh shape — the predicates themselves are
-        what the grouped kernel keeps apart."""
-        return (self.logical.k, self.engine, self.route, self.lex,
+        same tier route, same score mix (fusion mode + weights — None for
+        dense engines, so dense and hybrid groups never fuse together),
+        same paged/resident regime, same mesh shape — the predicates
+        themselves are what the grouped kernel keeps apart. The query-term
+        bucket is not part of it: a joined hybrid launch pads every row's
+        terms to its largest member bucket, which changes no bits."""
+        mix = None if self.lex is None else (self.lex[0],) + self.lex[2:]
+        return (self.logical.k, self.engine, self.route, mix,
                 self.page_rows, self.shards, self.placement)
 
     def explain(self) -> str:
@@ -240,7 +244,8 @@ class PhysicalPlan:
             lines.append(
                 f"  fusion:    score mix {mix} over "
                 f"{len(lp.match_terms or ())} term(s) -> bucket {qt_bucket}; "
-                f"groups sharing fuse key scan once")
+                f"groups of this mix, any bucket, scan once at their "
+                f"largest bucket where that saves a pass")
         elif self.fusable:
             lines.append(
                 f"  fusion:    eligible — groups sharing fuse key "

@@ -55,6 +55,8 @@ import numpy as np
 
 from repro.api.plan import (ALL_BITS, ANY_TENANT, LogicalPlan, PhysicalPlan,
                             bucket_rows)
+from repro.kernels.arena_scan.ops import default_blk_b
+from repro.kernels.hybrid_score.hybrid_score import hybrid_spec
 
 #: default location bench_latency writes its measurements to (cwd-relative,
 #: i.e. resolved from the repo root where benchmarks are run).
@@ -226,17 +228,27 @@ class FusedGroup:
     reason: str
 
 
-def fuse_batch(plans, *, cfg: PlannerConfig = PlannerConfig()) -> list[FusedGroup]:
+def fuse_batch(plans, *, cfg: PlannerConfig = PlannerConfig(),
+               rows=None) -> list[FusedGroup]:
     """Batch-level fusion rule: collapse exact-engine predicate groups that
     share a `fuse_key` (same k, engine, tier route) into one grouped scan.
 
     ``plans`` is one representative `PhysicalPlan` per DISTINCT predicate
-    group in the batch (executor.execute_plans dedups by group_key first).
-    Groups whose engine scans per-group candidate sets (ivf) or owns a
-    collective (sharded) stay on their engines; exact groups fuse when at
-    least ``cfg.fuse_min_groups`` of them share a fuse key — the arena then
-    streams once for all of them instead of once per group
-    (`rows_scanned` G*N -> N, G compiled programs -> 1).
+    group in the batch (executor.execute_plans dedups by group_key first);
+    ``rows`` the query rows of each group, aligned to ``plans`` (None =
+    each plan's own rows). Groups whose engine scans per-group candidate
+    sets (ivf) or owns a collective (sharded) stay on their engines; exact
+    groups fuse when at least ``cfg.fuse_min_groups`` of them share a fuse
+    key — the arena then streams once for all of them instead of once per
+    group (`rows_scanned` G*N -> N, G compiled programs -> 1).
+
+    Hybrid groups of one score mix share a fuse key whatever their
+    query-term bucket. The groups of one bucket fuse as one launch, and
+    launches of different buckets join while the joined launch makes
+    fewer passes (`_lex_passes`) than the launches it replaces: a joined
+    pass runs at its largest bucket, so it is cheaper than two passes,
+    never than one. A launch left with fewer than ``fuse_min_groups``
+    groups runs each on its own.
 
     With a cost model loaded the decision is priced from the engine's
     measured curve: a fused scan costs ~one scan at ``n_rows`` where the
@@ -253,17 +265,20 @@ def fuse_batch(plans, *, cfg: PlannerConfig = PlannerConfig()) -> list[FusedGrou
     >>> [u.fused for u in fuse_batch([mk(0)])]
     [False]
     """
+    if rows is None:
+        rows = [1 if p.logical.q is None else len(np.atleast_2d(p.logical.q))
+                for p in plans]
     order: list[tuple] = []                    # first-occurrence unit order
-    buckets: dict[tuple, list] = {}
-    for p in plans:
+    buckets: dict[tuple, list[int]] = {}
+    for i, p in enumerate(plans):
         key = ("fuse", p.fuse_key) if p.fusable else ("solo", id(p))
         if key not in buckets:
             buckets[key] = []
             order.append(key)
-        buckets[key].append(p)
+        buckets[key].append(i)
     units: list[FusedGroup] = []
     for key in order:
-        group = buckets[key]
+        group = [plans[i] for i in buckets[key]]
         gsz = len(group)
         if key[0] == "solo":
             (p,) = group
@@ -277,18 +292,67 @@ def fuse_batch(plans, *, cfg: PlannerConfig = PlannerConfig()) -> list[FusedGrou
                     f"{gsz} group(s) share fuse key {p.fuse_key!r} "
                     f"< fuse_min_groups={cfg.fuse_min_groups}"))
             continue
-        k, engine, route, _lex, _page, _shards, _placement = group[0].fuse_key
-        n_rows = group[0].n_rows
-        est = (cfg.cost_model.estimate_ms(engine, n_rows)
-               if cfg.cost_model is not None else None)
-        if est is not None:
-            reason = (f"cost model: one fused scan ~{est:.2f}ms replaces "
-                      f"{gsz} looped scans ~{gsz * est:.2f}ms at {n_rows} rows")
-        else:
-            reason = (f"{gsz} exact groups share (k={k}, engine={engine!r}, "
-                      f"route={route!r}): one scan replaces {gsz}")
-        units.append(FusedGroup(tuple(group), True, reason))
+        for members in _join_buckets(plans, rows, buckets[key]):
+            launch = [plans[i] for i in members]
+            n = len(launch)
+            if n < cfg.fuse_min_groups:
+                for p in launch:
+                    units.append(FusedGroup(
+                        (p,), False,
+                        f"{n} group(s) in its launch < fuse_min_groups="
+                        f"{cfg.fuse_min_groups}: joining another query-term "
+                        f"bucket saves no pass"))
+                continue
+            k, engine, route, _mix, _page, _shards, _plc = launch[0].fuse_key
+            n_rows = launch[0].n_rows
+            est = (cfg.cost_model.estimate_ms(engine, n_rows)
+                   if cfg.cost_model is not None else None)
+            if est is not None:
+                reason = (f"cost model: one fused scan ~{est:.2f}ms replaces "
+                          f"{n} looped scans ~{n * est:.2f}ms at {n_rows} "
+                          f"rows")
+            else:
+                reason = (f"{n} exact groups share (k={k}, engine={engine!r}, "
+                          f"route={route!r}): one scan replaces {n}")
+            qts = sorted({p.lex[1] for p in launch if p.lex is not None})
+            if len(qts) > 1:
+                reason += f"; joins query-term buckets {qts} at qt {qts[-1]}"
+            units.append(FusedGroup(tuple(launch), True, reason))
     return units
+
+
+def _lex_passes(rows: int, mode: str) -> int:
+    """Arena streams a hybrid launch of ``rows`` query rows makes: its pow2
+    row bucket over the lexical scan's query-row block."""
+    bucket = bucket_rows(rows)
+    return -(-bucket // default_blk_b(bucket, hybrid_spec(mode)))
+
+
+def _join_buckets(plans, rows, idxs) -> list[list[int]]:
+    """Split one fuse key's groups (``idxs`` into ``plans``) into launches,
+    each a list of member indices in batch order: the groups of one
+    query-term bucket stay together, and each bucket joins the first
+    launch it saves a pass against. Dense groups carry no bucket: one
+    launch."""
+    parts: dict = {}
+    for i in idxs:
+        lex = plans[i].lex
+        parts.setdefault(None if lex is None else lex[1], []).append(i)
+    if len(parts) == 1:
+        return [list(idxs)]
+    mode = plans[idxs[0]].lex[0]
+    launches: list[list] = []                  # [members, rows]
+    for members in parts.values():
+        r = sum(rows[i] for i in members)
+        for launch in launches:
+            joined = _lex_passes(launch[1] + r, mode)
+            if joined < _lex_passes(launch[1], mode) + _lex_passes(r, mode):
+                launch[0] += members
+                launch[1] += r
+                break
+        else:
+            launches.append([list(members), r])
+    return [sorted(members) for members, _ in launches]
 
 
 def _candidate_engines(has_mesh: bool, has_index: bool = False) -> list[str]:

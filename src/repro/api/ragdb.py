@@ -854,7 +854,9 @@ class RagDB:
             f"{st.terms_scanned} term lanes scanned",
             f"  grouped scan: fused {st.fused_groups} groups -> "
             f"{st.fused_scans} scans "
-            f"({max(st.fused_groups - st.fused_scans, 0)} arena scans saved)",
+            f"({max(st.fused_groups - st.fused_scans, 0)} arena scans saved), "
+            f"{st.lex_bucket_joins} hybrid launches joined query-term "
+            f"buckets",
             f"  serving:      {st.degraded_plans} degraded plans, "
             f"{st.stale_serves} stale serves (within declared bound), "
             f"{st.warm_failovers} warm failovers (hot-only), "

@@ -30,6 +30,8 @@ test_hybrid / test_ivf_engine) stay as deep per-family coverage; this
 matrix is the single cross-family gate CI runs on every push.
 """
 import dataclasses
+import functools
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import numpy as np
@@ -404,6 +406,56 @@ def test_hybrid_group_padding_bits(mode, rng):
                            "padded": tuple(a[:B] for a in pad),
                            "launch": (s_l[:B], i_l[:B])})
         _assert_no_leak(store, preds, gids, ref[1])
+
+
+@pytest.mark.parametrize("mode", ["wsum", "rrf"])
+@pytest.mark.parametrize("page", [None, 256], ids=["resident", "paged"])
+def test_hybrid_bucket_join_bits(mode, page, rng, monkeypatch):
+    """Hybrid reads of query-term buckets 1, 4 and 16 from three tenants
+    join into ONE launch at QT 16 (`planner.fuse_batch`). A padding term
+    adds exactly +0.0 to a row's BM25, so each read gets the bits it gets
+    served alone, in the scan and the kernel body, resident and paged; and
+    no row of another tenant comes back."""
+    N, D, k = 640, 32, 8
+    store = _arena(rng, N, D)
+    snap = {"terms": store["terms"], "lexnorm": store["lexnorm"],
+            "idf": store["idf"]}
+    lex = SimpleNamespace(snapshot=lambda: snap,
+                          cfg=SimpleNamespace(rrf_c=60.0, doc_terms=T_LANES))
+    cfg = (PlannerConfig() if page is None
+           else PlannerConfig(paged_min_rows=1, page_rows=page))
+    reads = [(0, 1), (1, 4), (2, 16), (0, 3), (1, 11)]    # (tenant, terms)
+    q = rng.standard_normal((len(reads), D)).astype(np.float32)
+    plans = [compile_plan(
+        LogicalPlan(tenant=t, min_ts=100, k=k, q=q[i:i + 1],
+                    match_terms=tuple(int(x) for x in
+                                      rng.choice(V, n, replace=False)),
+                    fusion=mode, w_dense=W_DENSE if mode == "wsum" else 1.0,
+                    w_lex=W_LEX if mode == "wsum" else 1.0),
+        n_rows=N, hot_window_s=100, now_ts=1000, warm_rows=0, cfg=cfg,
+        lex=lex) for i, (t, n) in enumerate(reads)]
+    assert {p.lex[1] for p in plans} == {1, 4, 16}
+    assert all(p.page_rows == page for p in plans)
+
+    def serve(ps, stats=None):
+        return executor_mod.execute_plans(
+            dict(store), None, ps, stats=stats, shapes=CompiledShapes(),
+            planner_cfg=cfg, lex=lex)[:2]
+
+    kernel = functools.partial(hybrid_score, use_kernel=True, interpret=True)
+    tenant = np.asarray(store["tenant"])
+    for name, fn in (("scan", hybrid_score), ("kernel", kernel)):
+        monkeypatch.setattr("repro.kernels.hybrid_score.ops.hybrid_score", fn)
+        stats = ExecStats()
+        s_j, i_j = serve(plans, stats)
+        assert (stats.device_calls, stats.lex_bucket_joins) == (1, 1), name
+        for r, p in enumerate(plans):
+            s_a, i_a = serve([p])
+            _assert_all_equal({f"{name}-alone": (s_a[0], i_a[0]),
+                               f"{name}-joined": (s_j[r], i_j[r])})
+            real = i_j[r][i_j[r] >= 0]
+            assert len(real) and (tenant[real] == reads[r][0]).all(), name
+            assert _oracle_mask(store, p.pred)[real].all(), name
 
 
 @pytest.mark.parametrize("engine,groups_per_row,n_shapes", [

@@ -358,6 +358,51 @@ def test_hybrid_never_fuses_with_dense_groups(rng):
     assert db.stats.device_calls - calls0 == 2        # one scan each
 
 
+@pytest.mark.parametrize("mix,launches,joins", [
+    # 1 + 3 + 1 rows of buckets 1, 4, 16: one launch at qt 16
+    ([("wsum", 1, 1), ("wsum", 4, 3), ("wsum", 16, 1)],
+     [("wsum", 16, 5, 3)], 1),
+    # wsum and rrf never join, whatever their buckets
+    ([("wsum", 1, 1), ("rrf", 16, 1)],
+     [("rrf", 16, 1, 1), ("wsum", 1, 1, 1)], 0),
+    # rrf joins like wsum
+    ([("rrf", 2, 1), ("rrf", 8, 2)], [("rrf", 8, 3, 2)], 1),
+    # 8 + 8 rows: a joined launch makes two passes, as the two apart do
+    ([("wsum", 1, 8), ("wsum", 16, 8)],
+     [("wsum", 1, 8, 1), ("wsum", 16, 8, 1)], 0),
+    # 9 + 1 rows: two passes replace three
+    ([("wsum", 1, 9), ("wsum", 16, 1)], [("wsum", 16, 10, 2)], 1),
+    # 2 + 2 rows of one bucket: one launch, as before the join
+    ([("wsum", 4, 2), ("wsum", 4, 2)], [("wsum", 4, 4, 1)], 0),
+])
+def test_hybrid_bucket_join_rule(mix, launches, joins):
+    """Hybrid groups of one score mix join across query-term buckets while
+    the joined launch makes fewer 8-row passes than the launches it
+    replaces; each launch runs at its largest member bucket (``qt``) and
+    says how many buckets it joined (``qt_joined``). Entry i of ``mix`` is
+    (mode, bucket, rows), its rows one predicate group of tenant i."""
+    from repro.obs import FlightRecorder, Tracer
+    db, ccfg, corpus = _keyword_db(17, n_docs=600)
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+    rng = np.random.default_rng(3)
+    plans = []
+    for t, (mode, bucket, rows) in enumerate(mix):
+        sess = db.session(Principal(tenant_id=t, group_bits=0xFFFFFFFF))
+        for _ in range(rows):
+            b = sess.search(rng.standard_normal(ccfg.dim).astype(np.float32))
+            plans.append(b.match(list(range(bucket))).fuse(mode).limit(5)
+                         .plan())
+    joins0 = db.stats.lex_bucket_joins
+    db.execute(plans, use_cache=False)
+    units = {s.ann["unit"]: s.ann for t in rec.traces() for s in t.spans
+             if s.name == "launch"}
+    got = sorted((a["mode"], a["qt"], a["rows"], a["qt_joined"])
+                 for a in units.values())
+    assert got == launches
+    assert db.stats.lex_bucket_joins - joins0 == joins
+
+
 # ---------------------------------------------------------------------------
 # warm-tier lexical pushdown
 # ---------------------------------------------------------------------------

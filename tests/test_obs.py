@@ -348,11 +348,12 @@ def test_launch_passes_follow_block_rows(family, n, bucket, block_rows):
 
 
 def test_launch_span_carries_lexical_loop():
-    """A hybrid unit's `rag.launch` carries its fusion ``mode``, its
-    query-term bucket ``qt``, the real terms of its rows (``qterms``), the
-    postings ``lanes``, and the scheduler ``batch`` it was launched in,
-    which the units of one batch share; a dense launch carries its batch
-    too."""
+    """A hybrid unit's `rag.launch` carries its fusion ``mode``, the
+    query-term bucket ``qt`` it ran at, the buckets it joined
+    (``qt_joined``), the real terms of its rows (``qterms``), the postings
+    ``lanes``, and the scheduler ``batch`` it was launched in, which the
+    units of one batch share; a dense launch carries its batch too. The
+    wsum reads of buckets 1 and 4 join into one launch at qt 4."""
     db, ccfg = _lexical_db()
     rec = FlightRecorder()
     db.attach_tracer(Tracer(enabled=True, recorder=rec))
@@ -369,11 +370,10 @@ def test_launch_span_carries_lexical_loop():
     launches = {s.ann["unit"]: s.ann for t in rec.traces() for s in t.spans
                 if s.name == "launch"}
     hybrid = sorted(((a["mode"], a["qt"], a["rows"], a["qterms"],
-                      a["lanes"], a["batch"])
+                      a["lanes"], a["batch"], a["qt_joined"])
                      for a in launches.values() if a["family"] == "hybrid"))
-    assert hybrid == [("rrf", 8, 2, 10, ccfg.doc_terms, 1),
-                      ("wsum", 1, 2, 2, ccfg.doc_terms, 1),
-                      ("wsum", 4, 1, 3, ccfg.doc_terms, 1)]
+    assert hybrid == [("rrf", 8, 2, 10, ccfg.doc_terms, 1, 1),
+                      ("wsum", 4, 3, 5, ccfg.doc_terms, 1, 2)]
     (dense,) = [a for a in launches.values() if a["family"] != "hybrid"]
     assert dense["batch"] == 2 and "qterms" not in dense
 
