@@ -30,22 +30,23 @@ engines, so the planner prices the pruned scan from measurements too.
 
 The `group_sweep` section (PR 4) measures grouped-scan fusion: a B=64 batch
 with G distinct predicate groups, per-group loop (G arena streams) vs ONE
-fused grouped_topk scan, at G in {1, 2, 4, 8, 16} on the 50k-doc arena —
+fused grouped arena scan, at G in {1, 2, 4, 8, 16} on the 50k-doc arena —
 with `rows_scanned` recorded both ways, so the G*N -> N claim is auditable
 by count. `tools/check_bench_regression.py` gates CI on the G=8 point.
 Run with ``--gsweep-only --out PATH`` for a fresh comparison file.
 
 The `hybrid` section (PR 5) measures the lexical workload: fused one-pass
-dense+BM25 (`kernels.hybrid_score`) vs the split two-scan+host-merge
-baseline (`index.lexical.twoscan`) at N in {5k, 20k, 50k} — an "open" row
-(no predicate, generous pushdown baseline: isolates the pure fusion win)
-and a "composed" row (tenant+recency predicate, faithful Stack-A baseline
-with app-layer post-filter and the over-fetch retry ladder: the paper's
-crossover, reproduced for lexical+vector fusion) — plus keyword-anchored
-recall@10 hybrid vs dense-only through the full session path, and the
-planner's own engine choice for a match() query. The open fused curve
-joins the `cost_model` engines. `tools/check_bench_regression.py
---hybrid-only` gates CI on the composed 50k point and the recall ordering.
+dense+BM25 (`kernels.arena_scan` at a hybrid spec) vs the split
+two-scan+host-merge baseline (`index.lexical.twoscan`) at N in {5k, 20k,
+50k} — an "open" row (no predicate, generous pushdown baseline: isolates
+the pure fusion win) and a "composed" row (tenant+recency predicate,
+faithful Stack-A baseline with app-layer post-filter and the over-fetch
+retry ladder: the paper's crossover, reproduced for lexical+vector fusion)
+— plus keyword-anchored recall@10 hybrid vs dense-only through the full
+session path, and the planner's own engine choice for a match() query. The
+open fused curve joins the `cost_model` engines.
+`tools/check_bench_regression.py --hybrid-only` gates CI on the composed
+50k point and the recall ordering.
 Run with ``--hybrid-only --out PATH`` for a fresh comparison file.
 
 The `paged_scan` section (PR 7) measures the paged arena-scan regime: the
@@ -96,7 +97,8 @@ from repro.data.corpus import (DAY_S, CorpusConfig, make_corpus,
                                make_keyword_queries, make_queries)
 from repro.index.lexical import LexicalConfig
 from repro.index.lexical.twoscan import two_scan_hybrid
-from repro.kernels.hybrid_score.ops import hybrid_score
+from repro.kernels.arena_scan.ops import arena_scan
+from repro.kernels.arena_scan.stages import hybrid_spec
 
 
 def run(iters: int = 200, engine: str = "ref", n_docs: int = 50_000) -> dict:
@@ -211,10 +213,11 @@ def run_hybrid_section(*, iters: int, k: int = 10, batch: int = 8,
             preds = stack_predicates([pred])
 
             def fused():
-                s, _ = hybrid_score(
+                s, _ = arena_scan(
                     Q, snap["emb"], snap["tenant"], snap["updated_at"],
-                    snap["category"], snap["acl"], lex["terms"],
-                    lex["lexnorm"], lex["idf"], gids, preds, QT, k)
+                    snap["category"], snap["acl"], gids, preds, k,
+                    spec=hybrid_spec("wsum"), terms=lex["terms"],
+                    lexnorm=lex["lexnorm"], idf=lex["idf"], qterms=QT)
                 jax.block_until_ready(s)
 
             def twoscan():
@@ -271,7 +274,7 @@ def run_group_sweep(*, iters: int, engine: str = "ref", batch: int = 64,
     """Grouped-scan fusion, measured: a B-row batch carrying G distinct
     predicate groups (one per tenant — the paper's query composition
     explosion), answered by the per-group loop (G device programs, each
-    streaming the arena: rows_scanned = G*N) vs ONE fused grouped_topk
+    streaming the arena: rows_scanned = G*N) vs ONE fused grouped arena
     program (rows_scanned = N). The G=8 row is the PR's acceptance bar
     (fused >= 3x lower p50) and the point
     `tools/check_bench_regression.py` gates CI on.

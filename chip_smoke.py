@@ -62,8 +62,8 @@ from repro.core.tenancy import Principal, category_mask  # noqa: E402
 from repro.data.corpus import (DAY_S, chunk_columns,  # noqa: E402
                                make_keyword_queries, make_queries)
 from repro.index.lexical import LexicalConfig  # noqa: E402
-from repro.kernels.filtered_topk.ops import filtered_topk  # noqa: E402
-from repro.kernels.hybrid_score.ops import hybrid_score  # noqa: E402
+from repro.kernels.arena_scan.ops import arena_scan  # noqa: E402
+from repro.kernels.arena_scan.stages import hybrid_spec  # noqa: E402
 from repro.serving.scheduler import (Scheduler, SchedulerConfig,  # noqa: E402
                                      ServeRequest)
 
@@ -673,13 +673,14 @@ def hot_program_text(db, cfg) -> dict:
     q = jax.ShapeDtypeStruct((Q_ROWS, cfg.dim), jnp.float32)
     qt = jax.ShapeDtypeStruct((Q_ROWS, 1), jnp.int32)
     preds = stack_predicates([Predicate(tenant=0)])
-    pallas = jax.jit(lambda st, q, p: filtered_topk(
+    gids = jnp.zeros((Q_ROWS,), jnp.int32)
+    pallas = jax.jit(lambda st, q, p: arena_scan(
         q, st["emb"], st["tenant"], st["updated_at"], st["category"],
-        st["acl"], p[0], K))
-    hybrid = jax.jit(lambda st, lx, q, qt, p: hybrid_score(
+        st["acl"], gids, p, K, use_kernel=True))
+    hybrid = jax.jit(lambda st, lx, q, qt, p: arena_scan(
         q, st["emb"], st["tenant"], st["updated_at"], st["category"],
-        st["acl"], lx["terms"], lx["lexnorm"], lx["idf"],
-        jnp.zeros((q.shape[0],), jnp.int32), p, qt, K))
+        st["acl"], gids, p, K, spec=hybrid_spec("wsum"), terms=lx["terms"],
+        lexnorm=lx["lexnorm"], idf=lx["idf"], qterms=qt))
     return {"pallas": pallas.lower(snap, q, preds).compile().as_text(),
             "hybrid": hybrid.lower(snap, lex, q, qt, preds).compile().as_text()}
 

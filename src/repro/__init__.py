@@ -3,7 +3,7 @@
 
 Layers:
   repro.core        the paper's unified data layer (store/query/transactions/tenancy)
-  repro.kernels     Pallas TPU kernels (filtered_topk, decode_attention)
+  repro.kernels     Pallas TPU kernels (arena_scan, decode_attention)
   repro.models      model zoo (LM dense/MoE, GNN, recsys)
   repro.training    optimizers, train loop, checkpointing, fault tolerance
   repro.serving     batched RAG serving engine

@@ -8,7 +8,7 @@ enforceable and testable:
     runs as ONE device program over the stacked query rows — B requests with
     G unique predicate groups cost G device calls, not B;
   * grouped-scan fusion: exact-engine groups sharing a `fuse_key` (same k,
-    engine, route) collapse further into ONE `grouped_topk` program that
+    engine, route) collapse further into ONE grouped arena scan that
     streams the arena once for ALL of them — `rows_scanned` drops from G*N
     to N and G compiled programs become 1 (planner.fuse_batch decides,
     `ExecStats.fused_groups / fused_scans` audit);
@@ -42,9 +42,9 @@ from repro.obs.tracer import FanSpan
 from repro.core.query import (BLOCK_ALL, Predicate, stack_predicates,
                               unified_query, unified_query_grouped)
 from repro.core.store import Store
-from repro.kernels.arena_scan.ops import default_blk_b
-from repro.kernels.arena_scan.stages import ScanSpec
-from repro.kernels.hybrid_score.hybrid_score import hybrid_spec
+from repro.core.ivf import ivf_probe
+from repro.kernels.arena_scan.ops import arena_scan, default_blk_b
+from repro.kernels.arena_scan.stages import ScanSpec, hybrid_spec
 
 #: tier tags in the returned `tiers` array
 TIER_HOT = 0
@@ -268,7 +268,6 @@ def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
         if ivf is None:
             raise ValueError("engine='ivf' requires a built index — "
                              "call RagDB.build_index() first")
-        from repro.kernels.ivf_probe.ops import ivf_probe
         nv = q.shape[0] if n_valid is None else n_valid
         exact = "pallas" if jax.default_backend() == "tpu" else "ref"
         if (pred, k) in ivf.starved:
@@ -280,9 +279,7 @@ def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
         clusters, _, rows = ivf.probe(np.asarray(q[:nv]),
                                       nprobe or ivf.cfg.nprobe)
         dev = ivf.device_arrays()
-        s, sl = ivf_probe(q, store["emb"], store["tenant"],
-                          store["updated_at"], store["category"],
-                          store["acl"], dev["members"], dev["overflow"],
+        s, sl = ivf_probe(q, store, dev["members"], dev["overflow"],
                           clusters, pred.as_array(), k)
         rescan = None if skip_rescan else (store, q, pred, k, exact, nv, ivf)
         return _Hot(s, sl, rows, rescan=rescan)
@@ -451,7 +448,6 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
     route) keeps the two per-signal lists unfused: dense rides
     `_Hot.s/.sl`, bm25 rides `_Hot.extra`, and the finish phase rank-fuses
     after the tier merges."""
-    from repro.kernels.hybrid_score.ops import hybrid_score
     q, gids, preds, n_valid = _pad_group_launch(
         q, gids, preds, k, "hybrid", stats=stats, shapes=shapes, lex=lex_key,
         page_rows=page_rows, groups_per_row=True)
@@ -459,13 +455,14 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
         qterms = np.concatenate(
             [qterms, np.full((q.shape[0] - qterms.shape[0], qterms.shape[1]),
                              -1, np.int32)])
-    out = hybrid_score(jnp.asarray(q), store["emb"], store["tenant"],
-                       store["updated_at"], store["category"], store["acl"],
-                       lex_snap["terms"], lex_snap["lexnorm"],
-                       lex_snap["idf"], jnp.asarray(gids),
-                       stack_predicates(preds), jnp.asarray(qterms), k,
-                       mode=mode, w_dense=w_dense, w_lex=w_lex, rrf_c=rrf_c,
-                       lists=lists, page_rows=page_rows)
+    out = arena_scan(jnp.asarray(q), store["emb"], store["tenant"],
+                     store["updated_at"], store["category"], store["acl"],
+                     jnp.asarray(gids), stack_predicates(preds), k,
+                     spec=hybrid_spec(mode), terms=lex_snap["terms"],
+                     lexnorm=lex_snap["lexnorm"], idf=lex_snap["idf"],
+                     qterms=jnp.asarray(qterms), w_dense=w_dense,
+                     w_lex=w_lex, rrf_c=rrf_c, lists=lists,
+                     page_rows=page_rows)
     n_arena = store["emb"].shape[0]
     terms = n_arena * int(lex_snap["terms"].shape[1])
     if stats is not None:
@@ -530,7 +527,7 @@ def run_grouped_fused(store: Store, q: np.ndarray, preds: list[Predicate],
                       page_rows: int | None = None):
     """Scan-once counterpart of `run_grouped` for the exact engines: the G
     unique predicates stack into one (G, 4) block and ONE fused
-    `grouped_topk` program answers every row — `rows_scanned` is the arena
+    grouped arena scan answers every row — `rows_scanned` is the arena
     N, not G*N. Same contract and return shape as `run_grouped`
     (n_device_calls is always 1)."""
     B = q.shape[0]
@@ -598,7 +595,7 @@ def merge_tiers(hs, hi, ws, wi, k: int):
 
 def _rrf_merge_np(ds, di, dt, ls, li, lt, k: int, c: float):
     """Host-side reciprocal-rank fusion of two TIER-MERGED per-signal
-    k-lists (numpy twin of kernels.hybrid_score.ref.rrf_fuse, with tier
+    k-lists (numpy twin of kernels.arena_scan.ref.rrf_fuse, with tier
     tags carried through): candidates are identified by (slot, tier) — the
     hot and warm tiers are separate arenas, so a bare slot number is
     ambiguous across the merge. A candidate in both lists is represented

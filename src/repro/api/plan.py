@@ -167,7 +167,7 @@ class PhysicalPlan:
         """Whether this plan's scan can join a fused grouped scan. The
         exact full-arena engines qualify — including "hybrid", whose kernel
         takes the same (G, 4) stacked predicates + per-row group ids as
-        grouped_topk — because they stream the same rows under different
+        the grouped scan — because they stream the same rows under different
         predicates, so G of them collapse into one program. ivf scans
         per-group candidate sets and sharded owns its own collective —
         both stay on their engines."""

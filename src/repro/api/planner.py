@@ -22,7 +22,7 @@ and the planner refuses rather than drop a clause:
       3. "sharded"  if the RagDB was built with a device mesh and the hot
          arena is at least `shard_min_rows`;
       4. "pallas"   on a TPU backend once the arena crosses `pallas_min_rows`
-         (the fused filtered_topk kernel amortizes its launch there);
+         (the fused arena-scan kernel amortizes its launch there);
       5. "ref"      otherwise (pure-jnp reference; the only engine on CPU).
 
   Selectivity guard: a pruned scan scores at most nprobe clusters' rows, so
@@ -56,7 +56,7 @@ import numpy as np
 from repro.api.plan import (ALL_BITS, ANY_TENANT, LogicalPlan, PhysicalPlan,
                             bucket_rows)
 from repro.kernels.arena_scan.ops import default_blk_b
-from repro.kernels.hybrid_score.hybrid_score import hybrid_spec
+from repro.kernels.arena_scan.stages import hybrid_spec
 
 #: default location bench_latency writes its measurements to (cwd-relative,
 #: i.e. resolved from the repo root where benchmarks are run).

@@ -2,7 +2,7 @@
 
 Benchmark scale (Section 6.1): 50k docs x 128-dim, 20 tenants, 5 categories.
 Production scale (Section 7.3 hot tier): 64Mi docs x 768-dim sharded over the
-pod; queries are the fused filtered_topk over the row-sharded corpus."""
+pod; queries are the fused arena scan over the row-sharded corpus."""
 from repro.core.store import StoreConfig
 from repro.data.corpus import CorpusConfig
 

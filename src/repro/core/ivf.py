@@ -8,8 +8,9 @@ clusters, and the fused filtered scan runs only over those clusters' rows.
 Layout: a padded cluster-major MEMBER table (C, cap) of arena slot ids. The
 probe takes the deduplicated union of the predicate group's probed clusters
 and gathers those members' embeddings + metadata from the ARENA once per
-group (kernels/ivf_probe) — slot-indirect, so the arena stays the single
-source of truth and the index never carries a second copy of any column.
+group (`ivf_probe`: the arena scan's slot-lane preset over that candidate
+set) — slot-indirect, so the arena stays the single source of truth and the
+index never carries a second copy of any column.
 
 Rows that don't fit their cluster's cap land in an explicit ``overflow``
 tail that every probe scans exactly — overfull clusters cost a little
@@ -37,6 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.store import Store
+from repro.kernels.arena_scan.ops import arena_scan
+from repro.kernels.arena_scan.stages import ScanSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,6 +314,41 @@ def build_ivf(store: Store, cfg: IVFConfig, *, epoch: int = 0) -> IVFIndex:
                     n_at_build=len(rows), epoch=epoch)
 
 
+def candidate_slots(members, overflow, clusters):
+    """The arena slots one predicate group's probe scores: the probed
+    clusters' member rows (cluster-list padding -1 scores nothing) plus
+    the overflow tail. Returns (P,) int32, -1 padding. The B stacked query
+    rows of the group share this ONE candidate stream — no per-row
+    (B, P, D) gather."""
+    m = members[jnp.maximum(clusters, 0)]                  # (U, cap)
+    m = jnp.where((clusters >= 0)[:, None], m, -1)         # cluster-list pad
+    return jnp.concatenate([m.reshape(-1), overflow])      # (P,)
+
+
+def ivf_probe(q, store: Store, members, overflow, clusters, pred, k: int, *,
+              use_kernel: bool | None = None, interpret: bool | None = None):
+    """Fused probe over one predicate group's candidate set: the arena
+    scan's slot-lane preset, candidate assembly and scan in ONE jitted
+    program.
+
+    q: (B, D) stacked query rows; store: the ARENA columns (source of
+    truth); members: (C, cap) i32 member table; overflow: (O,) i32
+    exact-scan tail; clusters: (U,) i32 probed cluster ids, -1-padded to a
+    bucketed length; pred: (4,) int32. Returns (scores (B, k) f32, ARENA
+    slots (B, k) i32, -1 past the fill). The predicate mask runs on
+    metadata gathered from the arena, so a stale or poisoned member table
+    can only waste score work."""
+    return arena_scan(q, store["emb"], store["tenant"], store["updated_at"],
+                      store["category"], store["acl"],
+                      jnp.zeros((q.shape[0],), jnp.int32),
+                      jnp.asarray(pred, jnp.int32)[None, :], k,
+                      spec=ScanSpec(slot_lane=True),
+                      candidates=(candidate_slots,
+                                  (members, overflow,
+                                   jnp.asarray(clusters, jnp.int32))),
+                      use_kernel=use_kernel, interpret=interpret)
+
+
 def ivf_query(store: Store, index: IVFIndex, q: jax.Array, pred, k: int,
               nprobe: int | None = None, *, use_kernel: bool | None = None):
     """Single-call convenience over probe + fused scan (the executor drives
@@ -319,10 +357,8 @@ def ivf_query(store: Store, index: IVFIndex, q: jax.Array, pred, k: int,
     ``pred`` is a Predicate or its packed (4,) int32 array. Returns
     (scores (B, k), ARENA slots (B, k))."""
     from repro.core.query import Predicate
-    from repro.kernels.ivf_probe.ops import ivf_probe
     pa = pred.as_array() if isinstance(pred, Predicate) else jnp.asarray(pred)
     clusters, _, _ = index.probe(np.asarray(q), nprobe or index.cfg.nprobe)
     dev = index.device_arrays()
-    return ivf_probe(q, store["emb"], store["tenant"], store["updated_at"],
-                     store["category"], store["acl"], dev["members"],
-                     dev["overflow"], clusters, pa, k, use_kernel=use_kernel)
+    return ivf_probe(q, store, dev["members"], dev["overflow"], clusters, pa,
+                     k, use_kernel=use_kernel)

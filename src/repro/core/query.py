@@ -16,8 +16,9 @@ property-tested in tests/test_core_query.py.
 
 Two execution engines share this contract:
   * `unified_query_ref`    — pure-jnp reference (this file)
-  * `repro.kernels.filtered_topk.ops.filtered_topk` — Pallas TPU kernel
-`unified_query` dispatches on `engine=`.
+  * `repro.kernels.arena_scan.ops.arena_scan` — the arena scan (Pallas
+    kernel on a TPU, its jnp streaming scan elsewhere)
+`unified_query` and `unified_query_grouped` dispatch on `engine=`.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.store import Store
+from repro.kernels.arena_scan.ops import arena_scan
 
 NEG_INF = jnp.float32(jnp.finfo(jnp.float32).min)
 
@@ -145,7 +147,9 @@ def make_sharded_query(mesh, axes, n_rows: int, k: int,
 
 def unified_query(store: Store, q: jax.Array, pred: Predicate, k: int,
                   engine: str = "ref", page_rows: int | None = None):
-    """Front door used by the serving engine / benchmarks.
+    """Front door used by the serving engine / benchmarks. The resident
+    ``ref`` engine is the store-level reference `unified_query_ref`; every
+    other launch is the G = 1 grouped scan.
 
     ``page_rows`` selects the paged arena-scan regime (HBM-resident arena
     streamed in page tiles — `repro.kernels.arena_scan`): the pallas engine
@@ -153,18 +157,11 @@ def unified_query(store: Store, q: jax.Array, pred: Predicate, k: int,
     streaming jnp scan tiled at the page size. Results are bit-identical to
     the resident regime (the arena-scan conformance contract)."""
     pa = pred.as_array()
-    if engine == "ref":
-        if page_rows is None:
-            return unified_query_ref(store, q, pa, k)
-        gids = jnp.zeros((q.shape[0],), jnp.int32)
-        return unified_query_grouped(store, q, gids, pa[None, :], k,
-                                     engine="ref", page_rows=page_rows)
-    if engine == "pallas":
-        from repro.kernels.filtered_topk.ops import filtered_topk
-        return filtered_topk(q, store["emb"], store["tenant"], store["updated_at"],
-                             store["category"], store["acl"], pa, k,
-                             page_rows=page_rows)
-    raise ValueError(f"unknown engine {engine!r}")
+    if engine == "ref" and page_rows is None:
+        return unified_query_ref(store, q, pa, k)
+    gids = jnp.zeros((q.shape[0],), jnp.int32)
+    return unified_query_grouped(store, q, gids, pa[None, :], k,
+                                 engine=engine, page_rows=page_rows)
 
 
 #: Blocker predicate for padding a stacked (G, 4) predicate list to a pow2
@@ -198,15 +195,10 @@ def unified_query_grouped(store: Store, q: jax.Array, gids, preds, k: int,
     what any row may see. ``page_rows`` selects the paged arena-scan regime
     (bit-identical; see `unified_query`). Returns (scores (B, k),
     slots (B, k))."""
-    from repro.kernels.grouped_topk.ops import grouped_topk
     pa = (stack_predicates(preds) if isinstance(preds, (list, tuple))
           else jnp.asarray(preds, jnp.int32))
-    if engine == "ref":
-        use_kernel = False
-    elif engine == "pallas":
-        use_kernel = True
-    else:
-        raise ValueError(f"unknown grouped engine {engine!r}")
-    return grouped_topk(q, store["emb"], store["tenant"], store["updated_at"],
-                        store["category"], store["acl"], gids, pa, k,
-                        use_kernel=use_kernel, page_rows=page_rows)
+    if engine not in ("ref", "pallas"):
+        raise ValueError(f"unknown engine {engine!r}")
+    return arena_scan(q, store["emb"], store["tenant"], store["updated_at"],
+                      store["category"], store["acl"], gids, pa, k,
+                      use_kernel=engine == "pallas", page_rows=page_rows)

@@ -96,7 +96,7 @@ def vector_topk_hybrid(emb: jax.Array, valid: jax.Array,
     round trip, no app-layer filter in the loop), extended with the second
     signal. idf/avgdl come from the CORPUS-GLOBAL `LexicalStats`, so warm
     BM25 scores are comparable with hot ones across the tier merge."""
-    from repro.kernels.hybrid_score.ref import bm25_block, qidf_of, rrf_fuse
+    from repro.kernels.arena_scan.ref import bm25_block, qidf_of, rrf_fuse
     keep = _warm_keep(valid, meta, pred)
     qidf = qidf_of(idf, qterms)
     if mode == "wsum":

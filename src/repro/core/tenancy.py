@@ -2,7 +2,7 @@
 
 The unified engine's isolation guarantee has two halves:
   1. the predicate is evaluated inside the retrieval kernel (query.py /
-     kernels/filtered_topk) — no app code can skip it;
+     kernels/arena_scan) — no app code can skip it;
   2. the predicate itself is built HERE from the authenticated principal, not
      from request parameters — a client cannot ask for another tenant.
 

@@ -26,7 +26,7 @@ Two fidelity levels, selected by ``pushdown``:
     paper's workload) blow the split stack up.
 
 `benchmarks/bench_latency.py` measures both against the one-pass fused
-scan (`kernels.hybrid_score`); `tools/check_bench_regression.py
+scan (`kernels.arena_scan`, hybrid spec); `tools/check_bench_regression.py
 --hybrid-only` gates CI on the fused path staying >= 1.5x faster than the
 faithful baseline on the composed query at the 50k-doc point.
 """
@@ -40,7 +40,7 @@ import numpy as np
 
 from repro.core.query import NEG_INF, Predicate, predicate_mask, unified_query
 from repro.core.store import Store
-from repro.kernels.hybrid_score.ref import bm25_block, qidf_of, rrf_fuse
+from repro.kernels.arena_scan.ref import bm25_block, qidf_of, rrf_fuse
 
 
 @partial(jax.jit, static_argnames=("k",))

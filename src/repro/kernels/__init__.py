@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels. `arena_scan/` is the one retrieval kernel: every
+# scan family (filtered, grouped, hybrid, ivf) is a `ScanSpec` preset of
+# its entry `arena_scan.ops.arena_scan`. `hybrid_score/ops.py` only
+# re-exports that entry's `_run` under its former path.

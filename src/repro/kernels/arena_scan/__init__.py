@@ -13,12 +13,17 @@ running top-k in VMEM scratch. This package owns that program once:
     arena, explicit double-buffered DMA so the next page's copy overlaps
     the current page's compute);
   * `ref`     — the dense oracle and the streaming jnp scan, generic over
-    the same `ScanSpec`;
-  * `ops`     — shared padding / metadata packing / dispatch helpers.
+    the same `ScanSpec` (the kernel-level oracles of every spec), and the
+    lexical helpers every scorer shares (`qidf_of`, `bm25_block`,
+    `rrf_fuse`);
+  * `ops`     — `arena_scan`, the one public entry: padding, metadata
+    packing, engine choice, tiles, weight folding and rank fusion, once;
+  * `sharded` — the shard-mapped scan of the `sharded` engine.
 
-The four kernel families (`filtered_topk`, `ivf_probe`, `grouped_topk`,
-`hybrid_score`) are thin configurations of this framework; their public
-contracts are unchanged.
+The scan families are `ScanSpec` presets of the entry, not packages:
+filtered (dense, G = 1), grouped (dense, G >= 1), hybrid
+(`stages.hybrid_spec`) and ivf (`ScanSpec(slot_lane=True)` over a
+candidate set).
 """
 from repro.kernels.arena_scan.stages import NEG_INF, ScanSpec, merge_topk
 

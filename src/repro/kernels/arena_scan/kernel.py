@@ -189,7 +189,7 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
 
     B % blk_b == 0, D % 128 == 0, and N % blk_n == 0 (resident) or
     N % page_rows == 0 (paged), with blk_n / page_rows multiples of 128 —
-    the family ops wrappers pad. Returns
+    `ops.arena_scan` pads. Returns
     `spec.n_lists` (scores (B, k) f32, indices (B, k) i32) pairs,
     flattened. ``page_rows`` selects the paged regime; its merge schedule
     (and thus its bits) equals resident mode at blk_n = page_rows."""

@@ -1,4 +1,5 @@
-"""The unified arena scan's jnp engines: dense oracle + streaming scan.
+"""The unified arena scan's oracles: the dense oracle, the streaming scan,
+and the query-side and post-scan lexical helpers every scorer shares.
 
 Both are generic over the same `ScanSpec` as the Pallas kernel and run the
 same `stages` functions per tile, which is what makes the three engines
@@ -9,6 +10,13 @@ blk_n rows, local top-k per tile, one final merge — and is the production
 engine on the CPU rig (kernels run interpret-mode there, far too slow to
 serve). The scan's blk_n IS the page size: the paged Pallas kernel at
 page_rows = P merges in exactly this schedule at blk_n = P.
+
+Both are the kernel-level oracles of every `ScanSpec`: a family's oracle
+is one of them called with the family's spec. For the lexical specs the
+caller gathers ``qidf`` (`qidf_of`) and, for wsum, folds the fusion
+weights into q and qidf (pinning rule 1); rrf lists fuse after the scan
+(`rrf_fuse`). `bm25_block` is the lexical stage on the row-major lanes
+the split-system baselines hold.
 """
 from __future__ import annotations
 
@@ -16,7 +24,61 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.arena_scan.stages import (B_LANES, NEG_INF, ScanSpec,
-                                             tile_mask, tile_signals)
+                                             bm25_scores, tile_mask,
+                                             tile_signals)
+
+
+def qidf_of(idf: jax.Array, qterms: jax.Array) -> jax.Array:
+    """Query-side idf gather: (B, QT) term ids against the snapshot's (V,)
+    idf table. Padding terms (-1) gather weight 0 — the invariant that
+    makes padded term lanes inert in every scorer (kernel, refs, warm
+    pushdown, split baseline), so it lives in exactly one place."""
+    return jnp.where(qterms >= 0,
+                     idf[jnp.clip(qterms, 0, idf.shape[0] - 1)], 0.0
+                     ).astype(jnp.float32)
+
+
+def bm25_block(terms: jax.Array, lexnorm: jax.Array, qterms: jax.Array,
+               qidf: jax.Array) -> jax.Array:
+    """Masked-gather BM25 over one block of (N, T) postings lanes — the
+    lexical score stage on the row-major layout the split-stack scorers
+    hold (see `stages.bm25_scores` for the fixed accumulation order and
+    the select-guarded lane product that pin its bits across fusion
+    contexts). Returns (B, N) f32."""
+    return bm25_scores(terms.T, lexnorm.T, qterms, qidf)
+
+
+def rrf_fuse(ds: jax.Array, di: jax.Array, ls: jax.Array, li: jax.Array,
+             k: int, c: float):
+    """Reciprocal-rank fusion of two per-signal k-lists (the standard
+    retrieved-lists form): candidate score = sum over lists containing it of
+    1/(c + rank). A candidate in both lists is represented by its dense-list
+    copy (the lex copy is masked out), so the union is deduplicated exactly.
+    Returns (scores (B, k) f32, slots (B, k) i32, -1 past the fill).
+
+    Ties (e.g. rank r in dense only vs rank r in lex only) break toward the
+    dense list, then toward the better rank — `lax.top_k` lower-index-first
+    over the [dense | lex] concatenation, deterministically.
+    """
+    kd, kl = di.shape[1], li.shape[1]
+    rd = 1.0 / (c + jnp.arange(1, kd + 1, dtype=jnp.float32))
+    rl = 1.0 / (c + jnp.arange(1, kl + 1, dtype=jnp.float32))
+    d_valid = di >= 0
+    l_valid = li >= 0
+    cross = ((di[:, :, None] == li[:, None, :])
+             & d_valid[:, :, None] & l_valid[:, None, :])        # (B, kd, kl)
+    d_score = (jnp.where(d_valid, rd[None, :], NEG_INF)
+               + jnp.sum(jnp.where(cross, rl[None, None, :], 0.0), axis=2))
+    # a lex candidate also in the dense list already carries both ranks on
+    # its dense copy — mask the lex copy out so the union stays deduplicated
+    in_dense = cross.any(axis=1)                                 # (B, kl)
+    l_score = jnp.where(l_valid & ~in_dense, rl[None, :], NEG_INF)
+    all_s = jnp.concatenate([d_score, l_score], axis=1)
+    all_i = jnp.concatenate([di, li], axis=1)
+    k_eff = min(k, all_s.shape[1])
+    top_s, sel = jax.lax.top_k(all_s, k_eff)
+    top_i = jnp.take_along_axis(all_i, sel, axis=1)
+    return _finish(top_s, top_i, k, k_eff)
 
 
 def _finish(top_s, top_i, k: int, k_eff: int):
@@ -77,7 +139,7 @@ def arena_scan_scan_ref(q, emb, meta, gids, preds, k: int, blk_n: int, *,
                         lex: tuple | None = None):
     """Streaming scan: `lax.scan` over (blk_n,)-row tiles, LOCAL top-k per
     running list, one final merge over the (tiles*k)-wide candidates.
-    Never materializes (B, N). N % blk_n == 0 (family ops pad). ``meta``
+    Never materializes (B, N). N % blk_n == 0 (`ops.arena_scan` pads). ``meta``
     and the lexical lanes are lane-major (W, N), as in every engine.
 
     Bit-identity with the oracle is by construction: same stage functions,

@@ -63,8 +63,8 @@ class ScanSpec:
     """What one arena-scan program computes.
 
     score:
-      * ``"dense"`` — similarity only (filtered_topk / grouped_topk /
-        ivf_probe): ONE running k-list on the masked dot product;
+      * ``"dense"`` — similarity only (the filtered, grouped and ivf
+        presets): ONE running k-list on the masked dot product;
       * ``"fused"`` — hybrid wsum: ONE running k-list on ``dense + bm25``
         (fusion weights pre-folded into q / qidf by the caller);
       * ``"both"``  — hybrid rrf: TWO running k-lists (dense, bm25) — rank
@@ -87,6 +87,14 @@ class ScanSpec:
     @property
     def meta_width(self) -> int:
         return 5 if self.slot_lane else 4
+
+
+def hybrid_spec(mode: str) -> ScanSpec:
+    """The scan a fusion mode runs: ``wsum`` one fused list, ``rrf`` the
+    two per-signal lists."""
+    if mode not in ("wsum", "rrf"):
+        raise ValueError(f"unknown fusion mode {mode!r}")
+    return ScanSpec(score="fused" if mode == "wsum" else "both")
 
 
 def merge_topk(best_s, best_i, scores, idx, k: int):
@@ -150,7 +158,9 @@ def bm25_scores(terms, lexnorm, qterms, qidf):
     a column slice of an (n, T) tile would be a relayout per lane.
     Padding safety: a padding query term (-1) can only "match" an empty
     doc lane (-1), and its gathered idf is 0, so it contributes exactly
-    0.0."""
+    0.0. The T x QT loop stays unrolled: a `fori_loop` over lanes read
+    from the kernel's VMEM refs compiles faster but took twice the device
+    time of a pass on a v5e."""
     bm25 = jnp.zeros((qterms.shape[0], terms.shape[1]), jnp.float32)
     for t in range(terms.shape[0]):
         lane = terms[t:t + 1, :]                                   # (1, n)

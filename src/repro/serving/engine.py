@@ -69,7 +69,7 @@ class RAGEngine:
     request mix reuses a small set of compiled program shapes (front-door
     path: the RagDB's `shapes` cache; raw-store path: the engine's own).
     Through the front door the exact-engine groups fuse further: groups
-    sharing (k, engine, route) run as ONE grouped_topk scan, so a
+    sharing (k, engine, route) run as ONE grouped arena scan, so a
     multi-tenant batch streams the arena once, not once per tenant.
     `last_retrieval_device_calls` reports the call count per batch (1 when
     the whole batch fused).

@@ -1,8 +1,8 @@
 """Arena-scan conformance matrix — ONE grid proving every scan family.
 
-All four kernel families (filtered_topk, grouped_topk, ivf_probe,
-hybrid_score) are thin wrappers over `repro.kernels.arena_scan`; this file
-is the framework's acceptance contract (ISSUE 7):
+All four scan families (filtered, grouped, ivf, hybrid) are `ScanSpec`
+presets of `repro.kernels.arena_scan`'s one entry; this file is the
+framework's acceptance contract (ISSUE 7):
 
   * ENGINE CONFORMANCE: for every (family x shape bucket x page size x
     group count) cell, the dense jnp oracle, the streaming jnp scan, the
@@ -33,6 +33,7 @@ import dataclasses
 import functools
 from types import SimpleNamespace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -44,20 +45,40 @@ from repro.api.plan import LogicalPlan
 from repro.api.planner import PlannerConfig, compile_plan
 from repro.core.query import (Predicate, stack_predicates, unified_query,
                               unified_query_ref)
-from repro.kernels.arena_scan.ops import _pad_axis0, default_blk_b, pad_d128
-from repro.kernels.arena_scan.stages import ScanSpec
-from repro.kernels.filtered_topk.ops import filtered_topk
-from repro.kernels.grouped_topk.ops import _packed_meta, grouped_topk
-from repro.kernels.grouped_topk.ref import grouped_topk_ref
-from repro.kernels.hybrid_score.ops import hybrid_score
-from repro.kernels.hybrid_score.ref import hybrid_score_ref
-from repro.kernels.ivf_probe.ivf_probe import ivf_probe_pallas
-from repro.kernels.ivf_probe.ref import ivf_probe_ref, ivf_probe_scan_ref
+from repro.kernels.arena_scan import ops as scan_ops
+from repro.kernels.arena_scan.kernel import arena_scan_pallas
+from repro.kernels.arena_scan.ops import (_packed_meta, _pad_axis0,
+                                          arena_scan, default_blk_b, pad_d128)
+from repro.kernels.arena_scan.ref import (arena_scan_ref, arena_scan_scan_ref,
+                                          rrf_fuse)
+from repro.kernels.arena_scan.stages import ScanSpec, hybrid_spec
 
 pytestmark = [pytest.mark.kernels, pytest.mark.slow]
 
 W_DENSE, W_LEX = 0.8, 1.7    # the historical FMA-divergence weights
 V, T_LANES = 64, 6
+
+IVF = ScanSpec(slot_lane=True)
+
+#: The kernel-level oracles as one program each, as a family ran them.
+oracle = jax.jit(arena_scan_ref, static_argnames=("k", "spec"))
+scan_oracle = jax.jit(arena_scan_scan_ref, static_argnames=("k", "blk_n",
+                                                            "spec"))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "mode", "w_dense",
+                                             "w_lex", "rrf_c"))
+def hybrid_oracle(q, emb, meta, terms, lexnorm, gids, preds, qterms, qidf,
+                  k: int, mode: str = "wsum", w_dense: float = 1.0,
+                  w_lex: float = 1.0, rrf_c: float = 60.0):
+    """The dense oracle at a fusion mode's spec: wsum folds its weights into
+    q and qidf (pinning rule 1), rrf fuses the two lists after the scan.
+    ``meta``, ``terms`` and ``lexnorm`` are lane-major."""
+    if mode == "wsum":
+        q, qidf = q * jnp.float32(w_dense), qidf * jnp.float32(w_lex)
+    out = arena_scan_ref(q, emb, meta, gids, preds, k, spec=hybrid_spec(mode),
+                         lex=(terms, lexnorm, qterms, qidf))
+    return out if mode == "wsum" else rrf_fuse(*out, k, rrf_c)
 
 
 # ---------------------------------------------------------------------------
@@ -135,9 +156,9 @@ def _lanes_filtered(rng, store, B, N, D, k, G, qt, page):
     meta = _packed_meta(store["tenant"], store["updated_at"],
                         store["category"], store["acl"])
     outs = {
-        "oracle": grouped_topk_ref(jnp.asarray(q), store["emb"], meta,
-                                   jnp.zeros(B, jnp.int32),
-                                   pred.as_array()[None, :], k),
+        "oracle": oracle(jnp.asarray(q), store["emb"], meta,
+                         jnp.zeros(B, jnp.int32), pred.as_array()[None, :],
+                         k),
         "scan": unified_query(store, jnp.asarray(q), pred, k, engine="ref",
                               page_rows=N),   # one tile = classic scan
         "kernel": unified_query(store, jnp.asarray(q), pred, k,
@@ -165,13 +186,13 @@ def _lanes_grouped(rng, store, B, N, D, k, G, qt, page):
                         store["category"], store["acl"])
 
     def call(**kw):
-        return grouped_topk(q, store["emb"], store["tenant"],
-                            store["updated_at"], store["category"],
-                            store["acl"], gids, pa, k, **kw)
+        return arena_scan(q, store["emb"], store["tenant"],
+                          store["updated_at"], store["category"],
+                          store["acl"], gids, pa, k, **kw)
 
     outs = {
-        "oracle": grouped_topk_ref(jnp.asarray(q), store["emb"], meta,
-                                   jnp.asarray(gids), pa, k),
+        "oracle": oracle(jnp.asarray(q), store["emb"], meta,
+                         jnp.asarray(gids), pa, k),
         "scan": call(use_kernel=False),
         "kernel": call(use_kernel=True, interpret=True),
     }
@@ -200,26 +221,29 @@ def _lanes_ivf(rng, store, B, N, D, k, G, qt, page):
     cand_emb = np.asarray(store["emb"]).copy()
     cand_emb[dead] = 0.0
     cand_emb, meta = jnp.asarray(cand_emb), jnp.asarray(meta)
-    pa = pred.as_array()
+    pa = pred.as_array()[None, :]
+    gids = jnp.zeros(B, jnp.int32)
 
     qp, embp = pad_d128(jnp.asarray(q), cand_emb)
     qp = _pad_axis0(qp, 8, 0)
 
     def kernel(**kw):
-        s, i = ivf_probe_pallas(qp, embp, meta, pa, k, blk_b=8,
-                                interpret=True, **kw)
+        s, i = arena_scan_pallas(qp, embp, meta, jnp.zeros((8, 1), jnp.int32),
+                                 pa, k, spec=IVF, blk_b=8, interpret=True,
+                                 **kw)
         return s[:B], i[:B]
 
     outs = {
-        "oracle": ivf_probe_ref(jnp.asarray(q), cand_emb, meta, pa, k),
-        "scan": ivf_probe_scan_ref(jnp.asarray(q), cand_emb, meta, pa, k,
-                                   blk_p=N),
-        "kernel": kernel(blk_p=256),
+        "oracle": oracle(jnp.asarray(q), cand_emb, meta, gids, pa, k,
+                         spec=IVF),
+        "scan": scan_oracle(jnp.asarray(q), cand_emb, meta, gids, pa, k,
+                            blk_n=N, spec=IVF),
+        "kernel": kernel(blk_n=256),
     }
     if page is not None:
-        outs["scan-paged"] = ivf_probe_scan_ref(jnp.asarray(q), cand_emb,
-                                                meta, pa, k, blk_p=page)
-        outs["kernel-paged"] = kernel(blk_p=256, page_rows=page)
+        outs["scan-paged"] = scan_oracle(jnp.asarray(q), cand_emb, meta,
+                                         gids, pa, k, blk_n=page, spec=IVF)
+        outs["kernel-paged"] = kernel(blk_n=256, page_rows=page)
 
     # slot-lane leakage: returned ARENA slots must come from live candidates
     # that pass the predicate
@@ -246,14 +270,15 @@ def _lanes_hybrid(mode, pool=None):
         gids = rng.integers(0, G, B).astype(np.int32)
         preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
         pa = stack_predicates(preds)
-        kw = dict(mode=mode, w_dense=W_DENSE, w_lex=W_LEX)
+        kw = dict(w_dense=W_DENSE, w_lex=W_LEX)
 
         def call(**extra):
-            return hybrid_score(q, store["emb"], store["tenant"],
-                                store["updated_at"], store["category"],
-                                store["acl"], store["terms"],
-                                store["lexnorm"], store["idf"], gids, pa,
-                                qterms, k, **kw, **extra)
+            return arena_scan(q, store["emb"], store["tenant"],
+                              store["updated_at"], store["category"],
+                              store["acl"], gids, pa, k,
+                              spec=hybrid_spec(mode), terms=store["terms"],
+                              lexnorm=store["lexnorm"], idf=store["idf"],
+                              qterms=qterms, **kw, **extra)
 
         meta = _packed_meta(store["tenant"], store["updated_at"],
                             store["category"], store["acl"])
@@ -261,11 +286,11 @@ def _lanes_hybrid(mode, pool=None):
                         np.asarray(store["idf"])[np.clip(qterms, 0, None)],
                         0.0).astype(np.float32)
         outs = {
-            "oracle": hybrid_score_ref(jnp.asarray(q), store["emb"], meta,
-                                       store["terms"].T, store["lexnorm"].T,
-                                       jnp.asarray(gids), pa,
-                                       jnp.asarray(qterms),
-                                       jnp.asarray(qidf), k, **kw),
+            "oracle": hybrid_oracle(jnp.asarray(q), store["emb"], meta,
+                                    store["terms"].T, store["lexnorm"].T,
+                                    jnp.asarray(gids), pa,
+                                    jnp.asarray(qterms), jnp.asarray(qidf),
+                                    k, mode=mode, **kw),
             "scan": call(use_kernel=False),
             "kernel": call(use_kernel=True, interpret=True),
         }
@@ -382,11 +407,11 @@ def test_hybrid_group_padding_bits(mode, rng):
     qterms = rng.integers(0, V, (B, qt)).astype(np.int32)
     gids = np.asarray([i % G for i in range(B)], np.int32)
     preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
-    kw = dict(mode=mode, w_dense=W_DENSE, w_lex=W_LEX, rrf_c=60.0)
+    kw = dict(w_dense=W_DENSE, w_lex=W_LEX, rrf_c=60.0)
 
     stats, shapes = ExecStats(), CompiledShapes()
     s_l, i_l = _finish_hot(_launch_hybrid(dict(store), lex, q, gids, preds,
-                                          qterms, k, stats=stats,
+                                          qterms, k, mode=mode, stats=stats,
                                           shapes=shapes, **kw))
     assert stats.padded_rows == 8 - B
     assert stats.padded_groups == 8 - G          # groups to the row bucket
@@ -395,13 +420,16 @@ def test_hybrid_group_padding_bits(mode, rng):
         groups_per_row=True)
     qtp = np.concatenate([qterms, np.full((8 - B, qt), -1, np.int32)])
     cols = (store["emb"], store["tenant"], store["updated_at"],
-            store["category"], store["acl"], store["terms"],
-            store["lexnorm"], store["idf"])
+            store["category"], store["acl"])
+    kw.update(spec=hybrid_spec(mode), terms=store["terms"],
+              lexnorm=store["lexnorm"], idf=store["idf"])
     for use_kernel in (False, True):
-        ref = hybrid_score(q, *cols, gids, stack_predicates(preds), qterms,
-                           k, use_kernel=use_kernel, interpret=True, **kw)
-        pad = hybrid_score(qp, *cols, gp, stack_predicates(pp), qtp, k,
-                           use_kernel=use_kernel, interpret=True, **kw)
+        ref = arena_scan(q, *cols, gids, stack_predicates(preds), k,
+                         qterms=qterms, use_kernel=use_kernel,
+                         interpret=True, **kw)
+        pad = arena_scan(qp, *cols, gp, stack_predicates(pp), k,
+                         qterms=qtp, use_kernel=use_kernel, interpret=True,
+                         **kw)
         _assert_all_equal({"unpadded": ref,
                            "padded": tuple(a[:B] for a in pad),
                            "launch": (s_l[:B], i_l[:B])})
@@ -442,10 +470,11 @@ def test_hybrid_bucket_join_bits(mode, page, rng, monkeypatch):
             dict(store), None, ps, stats=stats, shapes=CompiledShapes(),
             planner_cfg=cfg, lex=lex)[:2]
 
-    kernel = functools.partial(hybrid_score, use_kernel=True, interpret=True)
     tenant = np.asarray(store["tenant"])
-    for name, fn in (("scan", hybrid_score), ("kernel", kernel)):
-        monkeypatch.setattr("repro.kernels.hybrid_score.ops.hybrid_score", fn)
+    for name, use_kernel in (("scan", False), ("kernel", True)):
+        monkeypatch.setattr(
+            scan_ops, "default_use_kernel",
+            lambda u, use_kernel=use_kernel: use_kernel if u is None else u)
         stats = ExecStats()
         s_j, i_j = serve(plans, stats)
         assert (stats.device_calls, stats.lex_bucket_joins) == (1, 1), name
@@ -520,11 +549,14 @@ def test_similarity_product_is_exact_f32(family, use_kernel, page):
     kw = dict(use_kernel=use_kernel, interpret=False, page_rows=page)
     if family == "grouped":
         def scan(*a):
-            return grouped_topk(*a, 10, **kw)
+            return arena_scan(*a, 10, **kw)
         args = arena + tail
     else:
         def scan(*a):
-            return hybrid_score(*a, 10, mode=family, **kw)
+            *cols, terms, lexnorm, idf, gids, preds, qterms = a
+            return arena_scan(*cols, gids, preds, 10,
+                              spec=hybrid_spec(family), terms=terms,
+                              lexnorm=lexnorm, idf=idf, qterms=qterms, **kw)
         args = arena + (sds((N, T_MSMARCO), i32), sds((N, T_MSMARCO), f32),
                         sds((V_MSMARCO,), f32)) + tail + (sds((B, QT), i32),)
     precisions = _products_over(jax.make_jaxpr(scan)(*args).jaxpr, D)
@@ -553,23 +585,69 @@ def test_query_block_bits(family, B, page, rng):
             store["category"], store["acl"])
     if family == "filtered":
         preds, gids = [Predicate(tenant=1, min_ts=100)], np.zeros(B, np.int32)
-
-        def call(**kw):
-            return filtered_topk(q, *cols, preds[0].as_array(), k,
-                                 page_rows=page, interpret=True, **kw)
     else:
         preds = [Predicate(tenant=i % 3, min_ts=100) for i in range(G)]
         gids = rng.integers(0, G, B).astype(np.int32)
 
-        def call(**kw):
-            return grouped_topk(q, *cols, gids, stack_predicates(preds), k,
-                                use_kernel=True, interpret=True,
-                                page_rows=page, **kw)
+    def call(**kw):
+        return arena_scan(q, *cols, gids, stack_predicates(preds), k,
+                          use_kernel=True, interpret=True, page_rows=page,
+                          **kw)
 
     outs = {"blk_b=8": call(blk_b=8), f"blk_b={blk_b}": call()}
     _assert_all_equal(outs)
     for _, slots in outs.values():
         _assert_no_leak(store, preds, gids, slots)
+
+
+# ---------------------------------------------------------------------------
+# the one entry: every family launches the same jitted program
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("family,B,blk_b,blk_n", [
+    ("dense-G1", 5, 8, 512),       # filtered: the padded batch
+    ("dense-G4", 200, 128, 512),   # grouped: the batch, capped at 128 rows
+    ("wsum", 20, 8, 512),          # lexical: 8-row blocks
+    ("rrf", 20, 8, 512),
+    ("ivf", 20, 8, 256),           # candidate set: 8 rows, 256-row tiles
+])
+def test_entry_lowers_as_jit_run(family, B, blk_b, blk_n, rng, monkeypatch):
+    """Every family's launch is one program of the entry's `_run`, which
+    lowers as the module ``jit__run`` (the name the benchmark's device
+    readers find the scans by), at the tiles the family always took."""
+    import inspect
+    from repro.core.ivf import ivf_probe
+    N, D, k = 1024, 16, 4
+    store = _arena(rng, N, D)
+    q = rng.standard_normal((B, D)).astype(np.float32)
+    cols = (store["emb"], store["tenant"], store["updated_at"],
+            store["category"], store["acl"])
+    run, seen = scan_ops._run, []
+
+    def spy(*args):
+        seen.append((inspect.signature(run).bind(*args).arguments,
+                     run.lower(*args).as_text()))
+        return run.eval_shape(*args)
+
+    monkeypatch.setattr(scan_ops, "_run", spy)
+    G = 4 if family == "dense-G4" else 1
+    gids = np.arange(B, dtype=np.int32) % G
+    preds = stack_predicates([Predicate(tenant=g) for g in range(G)])
+    if family.startswith("dense"):
+        arena_scan(q, *cols, gids, preds, k, use_kernel=True)
+    elif family == "ivf":
+        members = jnp.asarray(rng.integers(-1, N, (8, 64)).astype(np.int32))
+        ivf_probe(q, store, members, jnp.arange(128, dtype=jnp.int32),
+                  np.arange(4, dtype=np.int32), preds[0], k, use_kernel=True)
+    else:
+        qterms = rng.integers(-1, V, (B, 4)).astype(np.int32)
+        arena_scan(q, *cols, gids, preds, k, spec=hybrid_spec(family),
+                   terms=store["terms"], lexnorm=store["lexnorm"],
+                   idf=store["idf"], qterms=qterms, use_kernel=True)
+    (bound, text), = seen
+    assert text.splitlines()[0].startswith("module @jit__run"), text[:80]
+    assert (bound["blk_b"], bound["blk_n"]) == (blk_b, blk_n)
+    assert bound["blk_b"] == default_blk_b(B, bound["spec"])
 
 
 # ---------------------------------------------------------------------------

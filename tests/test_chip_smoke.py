@@ -3,9 +3,8 @@
 The script itself refuses to run without a TPU. Here its body runs at
 4,096 rows x D=128 with every Pallas kernel in interpret mode: the test
 steers the TPU-only choices — the planner's backend probe and its row
-threshold (so plans pick the `pallas` engine) and the hybrid wrapper's
-kernel default — and
-the body's own checks do the rest (zero isolation violations, zero untied
+threshold (so plans pick the `pallas` engine) and the arena scan's
+kernel default — and the body's own checks do the rest (zero isolation violations, zero untied
 oracle mismatches, every scheduled request fresh or cached, every write
 read back).
 """
@@ -19,7 +18,7 @@ import pytest
 
 from repro.api import planner
 from repro.api.planner import PlannerConfig
-from repro.kernels.hybrid_score import ops as hybrid_ops
+from repro.kernels.arena_scan import ops as scan_ops
 
 pytestmark = pytest.mark.kernels
 
@@ -39,7 +38,7 @@ def test_chip_smoke_body_tiny(monkeypatch):
     smoke = _load_smoke()
     monkeypatch.setattr(planner, "jax",
                         types.SimpleNamespace(default_backend=lambda: "tpu"))
-    monkeypatch.setattr(hybrid_ops, "default_use_kernel",
+    monkeypatch.setattr(scan_ops, "default_use_kernel",
                         lambda use_kernel: True if use_kernel is None
                         else use_kernel)
     # the arena is far below the planner's pallas threshold at this size

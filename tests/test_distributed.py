@@ -57,8 +57,8 @@ def test_fit_spec_logic_pure():
 def test_sharded_kernels_and_vp_loss_subprocess():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.kernels.filtered_topk.ops import filtered_topk_sharded
-        from repro.kernels.filtered_topk.ref import filtered_topk_ref
+        from repro.kernels.arena_scan.ref import arena_scan_ref
+        from repro.kernels.arena_scan.sharded import make_sharded_arena_scan
         from repro.kernels.decode_attention.ops import decode_attention_sharded
         from repro.kernels.decode_attention.ref import decode_attention_ref
         from repro.launch.mesh import make_mesh
@@ -67,7 +67,7 @@ def test_sharded_kernels_and_vp_loss_subprocess():
         rng = np.random.default_rng(0)
         mesh = make_mesh((2, 2), ("data", "model"))
 
-        # sharded filtered_topk == global oracle
+        # sharded arena scan == global oracle
         N, D, kk = 2048, 64, 7
         q = jnp.asarray(rng.standard_normal((3, D), dtype=np.float32))
         emb = jnp.asarray(rng.standard_normal((N, D), dtype=np.float32))
@@ -76,8 +76,15 @@ def test_sharded_kernels_and_vp_loss_subprocess():
                           jnp.asarray(rng.integers(0, 4, N, dtype=np.int32)),
                           jnp.asarray(rng.integers(1, 8, N, dtype=np.int32))], 0)
         pred = jnp.array([1, 20, 0b1010, 0b11], jnp.int32)
-        s1, i1 = filtered_topk_sharded(mesh, ("data", "model"), q, emb, meta, pred, kk)
-        s2, i2 = filtered_topk_ref(q, emb, meta, pred, kk)
+        store = {"emb": emb, "tenant": meta[0], "updated_at": meta[1],
+                 "category": meta[2], "acl": meta[3].astype(jnp.uint32),
+                 "doc_id": jnp.arange(N, dtype=jnp.int32),
+                 "version": jnp.zeros(N, jnp.int32),
+                 "commit_ts": jnp.int32(0), "n_live": jnp.int32(N)}
+        sharded = make_sharded_arena_scan(mesh, ("data", "model"), N, kk)
+        s1, i1, _ = sharded(store, q, pred)
+        s2, i2 = arena_scan_ref(q, emb, meta, jnp.zeros(3, jnp.int32),
+                                pred[None, :], kk)
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-5, atol=1e-5)
 
         # sharded flash-decode == oracle across shard-crossing lengths

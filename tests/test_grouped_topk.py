@@ -1,7 +1,7 @@
 """The fused multi-predicate grouped scan: scan once, answer every group.
 
 Acceptance contracts (ISSUE 4):
-  * the grouped_topk Pallas kernel (interpret mode) is BIT-identical to the
+  * the grouped arena-scan kernel (interpret mode) is BIT-identical to the
     jnp ref, which is BIT-identical to the per-group loop it replaces —
     across bucket boundaries and G in {1, 2, 7, 16};
   * the fused executor path (`db.execute` with planner fusion) returns
@@ -28,7 +28,7 @@ from repro.core import (Predicate, Principal, StoreConfig,
                         unified_query_grouped, unified_query_ref)
 from repro.core.query import BLOCK_ALL, stack_predicates
 from repro.data.corpus import DAY_S, CorpusConfig, make_corpus
-from repro.kernels.grouped_topk.ops import grouped_topk
+from repro.kernels.arena_scan.ops import arena_scan
 
 pytestmark = [pytest.mark.kernels]
 
@@ -74,9 +74,9 @@ def test_kernel_bit_identical_to_ref(B, N, D, k, blk_n, G, rng):
     gids = rng.integers(0, G, B).astype(np.int32)
     args = (q, store["emb"], store["tenant"], store["updated_at"],
             store["category"], store["acl"], gids, preds, k)
-    s_r, i_r = grouped_topk(*args, use_kernel=False)
-    s_k, i_k = grouped_topk(*args, use_kernel=True, interpret=True,
-                            blk_n=blk_n)
+    s_r, i_r = arena_scan(*args, use_kernel=False)
+    s_k, i_k = arena_scan(*args, use_kernel=True, interpret=True,
+                          blk_n=blk_n)
     assert (np.asarray(s_r) == np.asarray(s_k)).all()
     assert (np.asarray(i_r) == np.asarray(i_k)).all()
 
@@ -236,11 +236,11 @@ def test_cross_group_leakage_impossible(seed, use_kernel):
              for g in range(G)]
     gids = rng.integers(0, G, B).astype(np.int32)
     q = rng.standard_normal((B, 16)).astype(np.float32)
-    s, slots = grouped_topk(q, store["emb"], store["tenant"],
-                            store["updated_at"], store["category"],
-                            store["acl"], gids, stack_predicates(preds), k,
-                            use_kernel=use_kernel,
-                            interpret=use_kernel or None, blk_n=64)
+    s, slots = arena_scan(q, store["emb"], store["tenant"],
+                          store["updated_at"], store["category"],
+                          store["acl"], gids, stack_predicates(preds), k,
+                          use_kernel=use_kernel,
+                          interpret=use_kernel or None, blk_n=64)
     slots = np.asarray(slots)
     masks = [_oracle_mask(store, p) for p in preds]
     for b in range(B):

@@ -1,7 +1,7 @@
 """The hybrid dense+BM25 engine: one arena pass, both signals, same arena.
 
 Acceptance contracts (ISSUE 5):
-  * the hybrid_score Pallas kernel (interpret mode) is BIT-identical to the
+  * the hybrid arena-scan kernel (interpret mode) is BIT-identical to the
     jnp dense oracle AND the jnp streaming scan — across query-term counts
     {1, 4, T_max} and both fusion modes;
   * LEXICAL-PATH LEAKAGE IMPOSSIBILITY: a row outside the predicate group
@@ -32,9 +32,9 @@ from repro.data.corpus import (DAY_S, CorpusConfig, make_corpus,
                                make_keyword_queries)
 from repro.index.lexical import LexicalArena, LexicalConfig
 from repro.index.lexical.twoscan import two_scan_hybrid
-from repro.kernels.hybrid_score.ops import hybrid_score
-from repro.kernels.hybrid_score.ref import hybrid_score_ref
-from repro.kernels.grouped_topk.ops import _packed_meta
+from repro.kernels.arena_scan.ops import _packed_meta, arena_scan
+from repro.kernels.arena_scan.stages import hybrid_spec
+from tests.test_arena_scan_conformance import hybrid_oracle
 
 pytestmark = [pytest.mark.kernels, pytest.mark.slow]
 
@@ -60,10 +60,11 @@ def _arena(rng, n, d=16, v=64, t_lanes=6, n_tenants=5):
 
 
 def _call(store, q, gids, preds, qterms, k, mode, **kw):
-    return hybrid_score(q, store["emb"], store["tenant"],
-                        store["updated_at"], store["category"], store["acl"],
-                        store["terms"], store["lexnorm"], store["idf"],
-                        gids, preds, qterms, k, mode=mode, **kw)
+    return arena_scan(q, store["emb"], store["tenant"], store["updated_at"],
+                      store["category"], store["acl"], gids, preds, k,
+                      spec=hybrid_spec(mode), terms=store["terms"],
+                      lexnorm=store["lexnorm"], idf=store["idf"],
+                      qterms=qterms, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -102,11 +103,10 @@ def test_kernel_bit_identical_to_refs(mode, qt, B, N, D, k, blk_n, rng):
     qidf = np.where(qterms >= 0,
                     np.asarray(store["idf"])[np.clip(qterms, 0, None)],
                     0.0).astype(np.float32)
-    s_o, i_o = hybrid_score_ref(jnp.asarray(q), store["emb"], meta,
-                                store["terms"].T, store["lexnorm"].T,
-                                jnp.asarray(gids), preds,
-                                jnp.asarray(qterms), jnp.asarray(qidf), k,
-                                mode=mode, **kw)
+    s_o, i_o = hybrid_oracle(jnp.asarray(q), store["emb"], meta,
+                             store["terms"].T, store["lexnorm"].T,
+                             jnp.asarray(gids), preds, jnp.asarray(qterms),
+                             jnp.asarray(qidf), k, mode=mode, **kw)
     assert (np.asarray(s_r) == np.asarray(s_o)).all()
     assert (np.asarray(i_r) == np.asarray(i_o)).all()
 

@@ -22,9 +22,8 @@ import pytest
 from repro.api import LogicalPlan, RagDB
 from repro.api.planner import choose_engine
 from repro.core import Predicate, Principal, StoreConfig
-from repro.core.ivf import IVFConfig, build_ivf
+from repro.core.ivf import IVFConfig, build_ivf, ivf_probe
 from repro.data.corpus import CorpusConfig, make_corpus, make_queries
-from repro.kernels.ivf_probe.ops import ivf_probe
 
 pytestmark = [pytest.mark.kernels, pytest.mark.slow]
 
@@ -85,9 +84,8 @@ def test_probe_kernel_bit_identical_to_ref(n, dim, k, cap_override, B, rng):
     clusters, _, _ = index.probe(q, nprobe=6)
     dev = index.device_arrays()
     pred = Predicate(min_ts=3, cat_mask=0b0111).as_array()
-    args = (jnp.asarray(q), snap["emb"], snap["tenant"], snap["updated_at"],
-            snap["category"], snap["acl"], dev["members"], dev["overflow"],
-            clusters, pred, k)
+    args = (jnp.asarray(q), snap, dev["members"], dev["overflow"], clusters,
+            pred, k)
     s_ref, i_ref = ivf_probe(*args, use_kernel=False)
     s_ker, i_ker = ivf_probe(*args, use_kernel=True, interpret=True)
     assert (np.asarray(s_ref) == np.asarray(s_ker)).all()

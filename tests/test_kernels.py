@@ -7,8 +7,8 @@ import pytest
 
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.filtered_topk.ops import filtered_topk
-from repro.kernels.filtered_topk.ref import filtered_topk_ref
+from repro.kernels.arena_scan.ops import arena_scan
+from repro.kernels.arena_scan.ref import arena_scan_ref
 
 pytestmark = [pytest.mark.kernels]
 
@@ -29,9 +29,12 @@ def test_filtered_topk_sweep(B, N, D, k, blk_n, dtype, rng):
     cat = jnp.asarray(rng.integers(0, 6, N, dtype=np.int32))
     acl = jnp.asarray(rng.integers(1, 16, N, dtype=np.int64).astype(np.uint32))
     pred = jnp.array([2, 300, 0b10110, 0b0101], jnp.int32)
-    s_p, i_p = filtered_topk(q, emb, tenant, ts, cat, acl, pred, k, blk_n=blk_n)
+    gids = jnp.zeros((B,), jnp.int32)
+    s_p, i_p = arena_scan(q, emb, tenant, ts, cat, acl, gids, pred[None, :], k,
+                          use_kernel=True, blk_n=blk_n)
     meta = jnp.stack([tenant, ts, cat, acl.astype(jnp.int32)], 0)
-    s_r, i_r = filtered_topk_ref(q, emb, meta, pred, k)
+    s_r, i_r = jax.jit(arena_scan_ref, static_argnames="k")(
+        q, emb, meta, gids, pred[None, :], k)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_r), rtol=tol, atol=tol)
     # predicate safety on the kernel path

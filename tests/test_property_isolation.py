@@ -5,7 +5,8 @@
    row-level-security claim, attacked adversarially).
 2. TOP-K SOUNDNESS: returned scores are the true top-k of the masked score
    vector, in non-increasing order.
-3. The filtered_topk Pallas kernel satisfies the same contract as the ref.
+3. The arena-scan Pallas kernel (filtered preset: one predicate group)
+   satisfies the same contract as the ref.
 4. FRONT DOOR: the same two properties hold through `RagDB`/`Session` — a
    builder chain is bit-identical to the direct reference call, and no
    Session can surface another tenant's rows (the API cannot even express
@@ -22,7 +23,7 @@ from repro.api import RagDB
 from repro.core import Principal, StoreConfig
 from repro.core.query import Predicate, unified_query_ref
 from repro.core.store import DocBatch
-from repro.kernels.filtered_topk.ops import filtered_topk
+from repro.kernels.arena_scan.ops import arena_scan
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -104,10 +105,12 @@ def _check_pallas_same_contract(args):
     emb, tenant, ts, cat, acl, pred, q, k = _corpus(args)
     store = _store_from(emb, tenant, ts, cat, acl)
     s_ref, _ = unified_query_ref(store, jnp.asarray(q), pred.as_array(), k)
-    s_pal, i_pal = filtered_topk(jnp.asarray(q), jnp.asarray(emb),
-                                 jnp.asarray(tenant), jnp.asarray(ts),
-                                 jnp.asarray(cat), jnp.asarray(acl, jnp.uint32),
-                                 pred.as_array(), k, blk_n=64)
+    s_pal, i_pal = arena_scan(jnp.asarray(q), jnp.asarray(emb),
+                              jnp.asarray(tenant), jnp.asarray(ts),
+                              jnp.asarray(cat), jnp.asarray(acl, jnp.uint32),
+                              np.zeros(len(q), np.int32),
+                              pred.as_array()[None, :], k, use_kernel=True,
+                              blk_n=64)
     np.testing.assert_allclose(np.asarray(s_pal), np.asarray(s_ref),
                                rtol=1e-4, atol=1e-5)
 

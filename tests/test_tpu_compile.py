@@ -3,7 +3,7 @@
 The TPU compiler is installed on CPU-only machines: a topology can be
 described and programs compiled for it (nothing runs). These tests compile
 the arena-scan programs at the production width (D=768, k=10) through the
-wrappers the executor calls, and check that each compiled program holds
+entry the executor calls, and check that each compiled program holds
 its Pallas kernel (`tpu_custom_call`) — so a kernel Mosaic refuses (an
 unlowerable primitive, an unaligned DMA slice, a VMEM overflow) fails here
 and not on the chip. The topology is described inside a module-scoped
@@ -21,12 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api.planner import PlannerConfig
 from repro.core.store import ShardPlacement, StoreConfig, _empty_lanes
-from repro.kernels.arena_scan.ops import default_blk_b
-from repro.kernels.arena_scan.stages import ScanSpec
+from repro.core.ivf import ivf_probe
+from repro.kernels.arena_scan.ops import arena_scan, default_blk_b
+from repro.kernels.arena_scan.stages import ScanSpec, hybrid_spec
 from repro.kernels.arena_scan.sharded import make_sharded_arena_scan
-from repro.kernels.grouped_topk.ops import grouped_topk
-from repro.kernels.hybrid_score.ops import hybrid_score
-from repro.kernels.ivf_probe.ops import ivf_probe
 
 pytestmark = pytest.mark.kernels
 
@@ -75,7 +73,7 @@ def _assert_kernel(fn, *args):
 def test_dense_resident_compiles(one_chip, groups):
     sds, a = _arena(one_chip)
     _assert_kernel(
-        lambda q, e, t, ts, c, acl, g, p: grouped_topk(
+        lambda q, e, t, ts, c, acl, g, p: arena_scan(
             q, e, t, ts, c, acl, g, p, K, use_kernel=True, interpret=False),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
         sds((B,), jnp.int32), sds((groups, 4), jnp.int32))
@@ -85,7 +83,7 @@ def test_dense_paged_compiles_at_planner_page(one_chip):
     sds, a = _arena(one_chip)
     page = PlannerConfig().page_rows
     _assert_kernel(
-        lambda q, e, t, ts, c, acl, g, p: grouped_topk(
+        lambda q, e, t, ts, c, acl, g, p: arena_scan(
             q, e, t, ts, c, acl, g, p, K, use_kernel=True, interpret=False,
             page_rows=page),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
@@ -102,7 +100,7 @@ def test_dense_query_block_compiles(one_chip, rows, paged):
     sds, a = _arena(one_chip)
     page = PlannerConfig().page_rows if paged else None
     _assert_kernel(
-        lambda q, e, t, ts, c, acl, g, p: grouped_topk(
+        lambda q, e, t, ts, c, acl, g, p: arena_scan(
             q, e, t, ts, c, acl, g, p, K, use_kernel=True, interpret=False,
             page_rows=page),
         sds((rows, D), jnp.float32), a["emb"], a["tenant"], a["ts"],
@@ -113,7 +111,8 @@ def test_ivf_slot_lane_compiles(one_chip):
     sds, a = _arena(one_chip)
     _assert_kernel(
         lambda q, e, t, ts, c, acl, m, o, cl, p: ivf_probe(
-            q, e, t, ts, c, acl, m, o, cl, p, K, use_kernel=True,
+            q, {"emb": e, "tenant": t, "updated_at": ts, "category": c,
+                "acl": acl}, m, o, cl, p, K, use_kernel=True,
             interpret=False),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
         sds((256, 64), jnp.int32), sds((512,), jnp.int32),
@@ -141,9 +140,10 @@ def test_hybrid_compiles(one_chip, mode, lanes, vocab, page):
     assert page in (None, PlannerConfig().page_rows)
     sds, a = _arena(one_chip)
     _assert_kernel(
-        lambda q, e, t, ts, c, acl, tm, ln, idf, g, p, qt: hybrid_score(
-            q, e, t, ts, c, acl, tm, ln, idf, g, p, qt, K, mode=mode,
-            use_kernel=True, interpret=False, page_rows=page),
+        lambda q, e, t, ts, c, acl, tm, ln, idf, g, p, qt: arena_scan(
+            q, e, t, ts, c, acl, g, p, K, spec=hybrid_spec(mode), terms=tm,
+            lexnorm=ln, idf=idf, qterms=qt, use_kernel=True,
+            interpret=False, page_rows=page),
         a["q"], a["emb"], a["tenant"], a["ts"], a["cat"], a["acl"],
         sds((N, lanes), jnp.int32), sds((N, lanes), jnp.float32),
         sds((vocab,), jnp.float32), sds((B,), jnp.int32),

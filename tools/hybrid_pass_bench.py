@@ -2,7 +2,8 @@
 
     python tools/hybrid_pass_bench.py --rows 1048576 --terms 64
 
-Times `hybrid_score` (the compiled Pallas kernel) on a seeded arena of
+Times `arena_scan` at a hybrid spec (the compiled Pallas kernel) on a
+seeded arena of
 ``--rows`` x 768 f32 rows with ``--terms`` postings lanes, at 8 query rows
 (one pass) for each fusion mode and query-term bucket, against the dense
 grouped scan of the same arena and rows. Prints one JSON line per shape:
@@ -34,8 +35,8 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.grouped_topk.ops import grouped_topk
-    from repro.kernels.hybrid_score.ops import hybrid_score
+    from repro.kernels.arena_scan.ops import arena_scan
+    from repro.kernels.arena_scan.stages import hybrid_spec
 
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
@@ -72,7 +73,7 @@ def main(argv=None) -> int:
 
     gids = jnp.arange(b, dtype=jnp.int32) % 2
     for g in (int(x) for x in args.groups.split(",")):
-        ms, first = timed(lambda: grouped_topk(
+        ms, first = timed(lambda: arena_scan(
             q, emb, tenant, ts, cat, acl, gids, preds_of(g), k))
         print(json.dumps({"scan": "dense", "groups": g, "ms": ms,
                           "first_s": first}), flush=True)
@@ -80,9 +81,10 @@ def main(argv=None) -> int:
             for qt in (int(x) for x in args.qts.split(",")):
                 qterms = jax.random.randint(jax.random.key(qt), (b, qt), 0,
                                             v, jnp.int32)
-                ms, first = timed(lambda: hybrid_score(
-                    q, emb, tenant, ts, cat, acl, terms, lexnorm, idf, gids,
-                    preds_of(g), qterms, k, mode=mode))
+                ms, first = timed(lambda: arena_scan(
+                    q, emb, tenant, ts, cat, acl, gids, preds_of(g), k,
+                    spec=hybrid_spec(mode), terms=terms, lexnorm=lexnorm,
+                    idf=idf, qterms=qterms))
                 print(json.dumps({"scan": "hybrid", "mode": mode, "qt": qt,
                                   "groups": g, "lanes": t, "ms": ms,
                                   "first_s": first}), flush=True)
