@@ -214,7 +214,10 @@ class _Hot:
     launch (synced into ``extra_np`` at finish). ``shard_rows`` is the
     sharded engine's per-shard rows-scanned audit vector (a device future
     until finish, a numpy (S,) after), with ``shard_meta`` carrying the
-    (n_shards, collective_bytes) facts of the launching handle."""
+    (n_shards, collective_bytes) facts of the launching handle. ``tally``
+    is the arena-scan kernel's merge tally (`arena_scan`): (merged, tiles)
+    with ``merged`` a device future until finish and the int sum of it
+    after; None where no kernel ran."""
     s: jax.Array
     sl: jax.Array
     rows: int                     # arena rows this program scored
@@ -231,6 +234,7 @@ class _Hot:
                                   # per-unit twin of stats.terms_scanned
     unit: int | None = None       # dispatch unit's sequence number (tracing
                                   # only: `Tracer.next_unit`)
+    tally: tuple | None = None    # (tiles merged, tiles) of the kernel
 
 
 def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
@@ -273,9 +277,9 @@ def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
         if (pred, k) in ivf.starved:
             # learned: the WHOLE arena can't fill k for this predicate —
             # probing first would be pure waste (memo clears on any write)
-            s, sl = unified_query(store, q, pred, k, engine=exact,
-                                  page_rows=page_rows)
-            return _Hot(s, sl, n_arena)
+            s, sl, tally = unified_query(store, q, pred, k, engine=exact,
+                                         page_rows=page_rows, tally=True)
+            return _Hot(s, sl, n_arena, tally=tally)
         clusters, _, rows = ivf.probe(np.asarray(q[:nv]),
                                       nprobe or ivf.cfg.nprobe)
         dev = ivf.device_arrays()
@@ -283,9 +287,9 @@ def _launch_hot(store: Store, q: jax.Array, pred: Predicate, k: int,
                           clusters, pred.as_array(), k)
         rescan = None if skip_rescan else (store, q, pred, k, exact, nv, ivf)
         return _Hot(s, sl, rows, rescan=rescan)
-    s, sl = unified_query(store, q, pred, k, engine=engine,
-                          page_rows=page_rows)
-    return _Hot(s, sl, n_arena)
+    s, sl, tally = unified_query(store, q, pred, k, engine=engine,
+                                 page_rows=page_rows, tally=True)
+    return _Hot(s, sl, n_arena, tally=tally)
 
 
 def _finish_hot(hot: _Hot, trace_fan=None) -> tuple[np.ndarray, np.ndarray]:
@@ -299,7 +303,10 @@ def _finish_hot(hot: _Hot, trace_fan=None) -> tuple[np.ndarray, np.ndarray]:
     ``trace_fan`` (member request traces, tracer-enabled path only) nests
     a ``rescan`` span under the caller's open ``device_sync`` span exactly
     when the completeness net fires."""
-    s, sl = jax.device_get((hot.s, hot.sl))
+    merged, tiles = hot.tally or (None, 0)
+    s, sl, merged = jax.device_get((hot.s, hot.sl, merged))
+    if merged is not None:
+        hot.tally = (int(merged.sum()), tiles)
     if hot.shard_rows is not None:
         # sharded: the per-shard audit vector replaces the whole-arena row
         # count — under the tenant-affine gate only the owning shard scans,
@@ -424,10 +431,10 @@ def _launch_grouped(store: Store, q: np.ndarray, gids: np.ndarray,
     q, gids, preds, n_valid = _pad_group_launch(
         q, gids, preds, k, engine, stats=stats, shapes=shapes,
         page_rows=page_rows)
-    s, sl = unified_query_grouped(store, jnp.asarray(q), jnp.asarray(gids),
-                                  stack_predicates(preds), k, engine=engine,
-                                  page_rows=page_rows)
-    return _Hot(s, sl, store["emb"].shape[0], pad_check=n_valid)
+    s, sl, tally = unified_query_grouped(
+        store, jnp.asarray(q), jnp.asarray(gids), stack_predicates(preds), k,
+        engine=engine, page_rows=page_rows, tally=True)
+    return _Hot(s, sl, store["emb"].shape[0], pad_check=n_valid, tally=tally)
 
 
 def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
@@ -462,7 +469,8 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
                      lexnorm=lex_snap["lexnorm"], idf=lex_snap["idf"],
                      qterms=jnp.asarray(qterms), w_dense=w_dense,
                      w_lex=w_lex, rrf_c=rrf_c, lists=lists,
-                     page_rows=page_rows)
+                     page_rows=page_rows, tally=True)
+    *out, tally = out
     n_arena = store["emb"].shape[0]
     terms = n_arena * int(lex_snap["terms"].shape[1])
     if stats is not None:
@@ -470,9 +478,9 @@ def _launch_hybrid(store: Store, lex_snap: dict, q: np.ndarray,
     if lists:
         d_s, d_i, l_s, l_i = out
         return _Hot(d_s, d_i, n_arena, pad_check=n_valid,
-                    extra=(l_s, l_i), terms=terms)
+                    extra=(l_s, l_i), terms=terms, tally=tally)
     s, sl = out
-    return _Hot(s, sl, n_arena, pad_check=n_valid, terms=terms)
+    return _Hot(s, sl, n_arena, pad_check=n_valid, terms=terms, tally=tally)
 
 
 def run_grouped(store: Store, q: np.ndarray, preds: list[Predicate], k: int,
@@ -989,10 +997,12 @@ def finish_plans(pending: InFlightPlans):
     into row order. Returns (scores, slots, tiers).
 
     Observability rides the same loop: each unit's sync is a
-    ``device_sync`` span (rescans nest inside it) and the per-group merge
-    a ``merge`` span in every member request's trace, and each unit lands
-    one predicted-vs-measured row in `pending.calib` (the cost-model
-    calibration audit — always-on, tracing or not)."""
+    ``device_sync`` span (rescans nest inside it; an arena-scan kernel's
+    unit annotates it with its merge tally, ``tiles_merged`` of ``tiles``)
+    and the per-group merge a ``merge`` span in every member request's
+    trace, and each unit lands one predicted-vs-measured row in
+    `pending.calib` (the cost-model calibration audit — always-on,
+    tracing or not)."""
     B, k, stats, lex = pending.B, pending.k, pending.stats, pending.lex
     row_traces, calib = pending.row_traces, pending.calib
     scores = np.full((B, k), np.float32(np.finfo(np.float32).min), np.float32)
@@ -1013,6 +1023,9 @@ def finish_plans(pending: InFlightPlans):
             if hot.shard_meta is not None:
                 sync_fan.annotate("shards", hot.shard_meta[0])
                 sync_fan.annotate("collective_bytes", hot.shard_meta[1])
+            if hot.tally is not None:
+                sync_fan.annotate("tiles_merged", hot.tally[0])
+                sync_fan.annotate("tiles", hot.tally[1])
             sync_fan.end(rows_scanned=hot.rows)
         if calib is not None:
             rep = unit.plans[0]

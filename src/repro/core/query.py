@@ -146,7 +146,8 @@ def make_sharded_query(mesh, axes, n_rows: int, k: int,
 
 
 def unified_query(store: Store, q: jax.Array, pred: Predicate, k: int,
-                  engine: str = "ref", page_rows: int | None = None):
+                  engine: str = "ref", page_rows: int | None = None,
+                  tally: bool = False):
     """Front door used by the serving engine / benchmarks. The resident
     ``ref`` engine is the store-level reference `unified_query_ref`; every
     other launch is the G = 1 grouped scan.
@@ -155,13 +156,17 @@ def unified_query(store: Store, q: jax.Array, pred: Predicate, k: int,
     streamed in page tiles — `repro.kernels.arena_scan`): the pallas engine
     switches to explicit double-buffered DMA, the ref engine to the
     streaming jnp scan tiled at the page size. Results are bit-identical to
-    the resident regime (the arena-scan conformance contract)."""
+    the resident regime (the arena-scan conformance contract).
+    ``tally=True`` appends the kernel's merge tally (`arena_scan`; None
+    where no kernel ran)."""
     pa = pred.as_array()
     if engine == "ref" and page_rows is None:
-        return unified_query_ref(store, q, pa, k)
+        out = unified_query_ref(store, q, pa, k)
+        return out + (None,) if tally else out
     gids = jnp.zeros((q.shape[0],), jnp.int32)
     return unified_query_grouped(store, q, gids, pa[None, :], k,
-                                 engine=engine, page_rows=page_rows)
+                                 engine=engine, page_rows=page_rows,
+                                 tally=tally)
 
 
 #: Blocker predicate for padding a stacked (G, 4) predicate list to a pow2
@@ -184,7 +189,8 @@ def stack_predicates(preds) -> jax.Array:
 
 
 def unified_query_grouped(store: Store, q: jax.Array, gids, preds, k: int,
-                          engine: str = "ref", page_rows: int | None = None):
+                          engine: str = "ref", page_rows: int | None = None,
+                          tally: bool = False):
     """Grouped front door: ONE arena scan answers every predicate group.
 
     q: (B, D) stacked query rows across ALL groups; gids: (B,) int32 group
@@ -194,11 +200,12 @@ def unified_query_grouped(store: Store, q: jax.Array, gids, preds, k: int,
     changes how many times the arena streams (once, not G times), never
     what any row may see. ``page_rows`` selects the paged arena-scan regime
     (bit-identical; see `unified_query`). Returns (scores (B, k),
-    slots (B, k))."""
+    slots (B, k)), and with ``tally=True`` the merge tally after them."""
     pa = (stack_predicates(preds) if isinstance(preds, (list, tuple))
           else jnp.asarray(preds, jnp.int32))
     if engine not in ("ref", "pallas"):
         raise ValueError(f"unknown engine {engine!r}")
     return arena_scan(q, store["emb"], store["tenant"], store["updated_at"],
                       store["category"], store["acl"], gids, pa, k,
-                      use_kernel=engine == "pallas", page_rows=page_rows)
+                      use_kernel=engine == "pallas", page_rows=page_rows,
+                      tally=tally)

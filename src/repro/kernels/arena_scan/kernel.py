@@ -11,6 +11,8 @@
     stages:      score (MXU dot [+ VPU BM25]) + mask (predicate groups via
                  one-hot matmul [+ slot-lane membership])
     scratch:     running top-k per signal list (ORDER BY .. LIMIT k)
+    gate:        a list merges the tile only where it holds a row above
+                 some query row's k-th entry (see below)
 
   Pallas pipelines the tile copies against compute automatically — the
   right regime while the working set of in-flight tiles fits VMEM.
@@ -36,6 +38,19 @@
   hidden, and the page size is a planner knob (`PhysicalPlan.page_rows`),
   not a compile-time constant.
 
+**The merge gate.** Past the first tiles a tile rarely holds a row that
+beats a query row's k-th best, and `stages.merge_topk` (k selection
+rounds over the list and the tile) is most of a pass's compute. So each
+running list merges a tile only where some row's best tile score is
+STRICTLY above that row's k-th entry, ``s[:, k-1]`` (the list is kept in
+descending order). Skipping is exact: a tile score equal to the k-th
+entry loses its tie to the list, which comes first in the merge; masked
+rows are ``NEG_INF`` and beat nothing, while a list not yet full holds
+``NEG_INF`` entries that any kept row beats; padding query rows only
+make a merge more likely. rrf's two lists gate on their own signals.
+Every merge counts into one SMEM int32 per (query block, list), the
+kernel's last output: the share of tiles merged is what the gate saves.
+
 Bit-identity across regimes is structural: both run the same
 `stages.tile_mask` + `stages.tile_signals` + `stages.merge_topk` per tile,
 and paged mode's merge schedule at page size P equals resident mode's (and
@@ -60,27 +75,43 @@ from repro.kernels.arena_scan.stages import (B_LANES, NEG_INF, ScanSpec,
                                              tile_signals)
 
 
-def _tile_step(spec: ScanSpec, k: int, scratch, q, e, meta, gids, preds,
-               base, lex):
+#: Scoped VMEM of the paged regime (a v5e core has 128 MiB of VMEM).
+PAGED_VMEM_BYTES = 32 * 2**20
+
+
+def _tile_step(spec: ScanSpec, k: int, scratch, merged, q, e, meta, gids,
+               preds, base, lex):
     """One tile through the shared stages: mask -> score -> merge into the
-    running lists. ``base`` is the tile's arena offset (index source for
-    positional engines; slot-lane engines index from meta[4])."""
+    running lists, each list only where the tile can enter it (the merge
+    gate, module docstring). ``merged`` is the query block's (SMEM ref,
+    offset) pair: one int32 per list counts its merges. ``base`` is the
+    tile's arena offset (index source for positional engines; slot-lane
+    engines index from meta[4])."""
     row_keep = tile_mask(spec, meta, preds, gids, onehot=True)
     signals = tile_signals(spec, q, e, row_keep, lex)
     if spec.slot_lane:
         idx = jnp.broadcast_to(meta[4:5, :], signals[0].shape)
     else:
         idx = base + jax.lax.broadcasted_iota(jnp.int32, signals[0].shape, 1)
-    for (s_ref, i_ref), sig in zip(scratch, signals):
-        new_s, new_i = merge_topk(s_ref[...], i_ref[...], sig, idx, k)
-        s_ref[...] = new_s
-        i_ref[...] = new_i
+    cnt_ref, at = merged
+    for j, ((s_ref, i_ref), sig) in enumerate(zip(scratch, signals)):
+        best = jnp.max(sig, axis=1, keepdims=True)                 # (B, 1)
+        enters = jnp.max(jnp.where(best > s_ref[:, k - 1:k], 1, 0))
+
+        @pl.when(enters > 0)
+        def _merge():
+            new_s, new_i = merge_topk(s_ref[...], i_ref[...], sig, idx, k)
+            s_ref[...] = new_s
+            i_ref[...] = new_i
+            cnt_ref[at + j] += 1
 
 
-def _init_lists(scratch):
-    for s_ref, i_ref in scratch:
+def _init_lists(scratch, merged):
+    cnt_ref, at = merged
+    for j, (s_ref, i_ref) in enumerate(scratch):
         s_ref[...] = jnp.full(s_ref.shape, NEG_INF, jnp.float32)
         i_ref[...] = jnp.full(i_ref.shape, -1, jnp.int32)
+        cnt_ref[at + j] = 0
 
 
 def _flush_lists(outs, scratch):
@@ -90,12 +121,15 @@ def _flush_lists(outs, scratch):
 
 
 def _split_refs(spec: ScanSpec, refs):
-    """Outputs then scratch lists, (s, i) pairs each."""
+    """Outputs ((s, i) pairs, then the merge counts), then the scratch
+    lists, (s, i) pairs. The counts come as (ref, this query block's
+    offset): `pl.program_id` is read here, at the kernel's top level."""
     n = spec.n_lists
     outs = tuple((refs[2 * j], refs[2 * j + 1]) for j in range(n))
-    scratch = tuple((refs[2 * n + 2 * j], refs[2 * n + 2 * j + 1])
+    merged = (refs[2 * n], pl.program_id(0) * n)
+    scratch = tuple((refs[2 * n + 1 + 2 * j], refs[2 * n + 2 + 2 * j])
                     for j in range(n))
-    return outs, scratch, refs[4 * n:]
+    return outs, merged, scratch, refs[4 * n + 1:]
 
 
 def _resident_kernel(gid_ref, pred_ref, q_ref, emb_ref, meta_ref, *refs,
@@ -105,17 +139,17 @@ def _resident_kernel(gid_ref, pred_ref, q_ref, emb_ref, meta_ref, *refs,
         lex = (terms_ref[...], ln_ref[...], qterms_ref[...], qidf_ref[...])
     else:
         lex = None
-    outs, scratch, rest = _split_refs(spec, refs)
+    outs, merged, scratch, rest = _split_refs(spec, refs)
     assert not rest
     bn = pl.program_id(1)
     n_blocks = pl.num_programs(1)
 
     @pl.when(bn == 0)
     def _init():
-        _init_lists(scratch)
+        _init_lists(scratch, merged)
 
-    _tile_step(spec, k, scratch, q_ref[...], emb_ref[...], meta_ref[...],
-               gid_ref[...], pred_ref[...], bn * blk_n, lex)
+    _tile_step(spec, k, scratch, merged, q_ref[...], emb_ref[...],
+               meta_ref[...], gid_ref[...], pred_ref[...], bn * blk_n, lex)
 
     @pl.when(bn == n_blocks - 1)
     def _finish():
@@ -134,7 +168,7 @@ def _paged_kernel(gid_ref, pred_ref, q_ref, *refs, spec: ScanSpec, k: int,
         qlex = (qterms_ref[...], qidf_ref[...])
     n_streams = 4 if spec.has_lex else 2
     hbm = refs[:n_streams]
-    outs, scratch, rest = _split_refs(spec, refs[n_streams:])
+    outs, merged, scratch, rest = _split_refs(spec, refs[n_streams:])
     bufs = rest[:n_streams]
     sems = rest[n_streams:]
     assert len(sems) == n_streams
@@ -145,7 +179,7 @@ def _paged_kernel(gid_ref, pred_ref, q_ref, *refs, spec: ScanSpec, k: int,
         return [pltpu.make_async_copy(src, b.at[slot], s.at[slot])
                 for src, b, s in zip(srcs, bufs, sems)]
 
-    _init_lists(scratch)
+    _init_lists(scratch, merged)
     q = q_ref[...]
     gids = gid_ref[...]
     preds = pred_ref[...]
@@ -167,7 +201,8 @@ def _paged_kernel(gid_ref, pred_ref, q_ref, *refs, spec: ScanSpec, k: int,
         meta = bufs[1][slot]
         lex = ((bufs[2][slot], bufs[3][slot]) + qlex if spec.has_lex
                else None)
-        _tile_step(spec, k, scratch, q, e, meta, gids, preds, p * page, lex)
+        _tile_step(spec, k, scratch, merged, q, e, meta, gids, preds,
+                   p * page, lex)
         return 0
 
     jax.lax.fori_loop(0, n_pages, body, 0)
@@ -191,7 +226,8 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
     N % page_rows == 0 (paged), with blk_n / page_rows multiples of 128 —
     `ops.arena_scan` pads. Returns
     `spec.n_lists` (scores (B, k) f32, indices (B, k) i32) pairs,
-    flattened. ``page_rows`` selects the paged regime; its merge schedule
+    flattened, then the merge counts: (B // blk_b * n_lists,) int32, the
+    tiles each (query block, list) merged (module docstring). ``page_rows`` selects the paged regime; its merge schedule
     (and thus its bits) equals resident mode at blk_n = page_rows."""
     B, D = q.shape
     N = emb.shape[0]
@@ -201,8 +237,10 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
     assert meta.shape[0] == M, (meta.shape, M)
     assert gids.shape == (B, 1), gids.shape
     n_lists = spec.n_lists
-    out_shape = (jax.ShapeDtypeStruct((B, k), jnp.float32),
-                 jax.ShapeDtypeStruct((B, k), jnp.int32)) * n_lists
+    out_shape = ((jax.ShapeDtypeStruct((B, k), jnp.float32),
+                  jax.ShapeDtypeStruct((B, k), jnp.int32)) * n_lists
+                 + (jax.ShapeDtypeStruct((B // blk_b * n_lists,), jnp.int32),))
+    merged_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     list_scratch = (pltpu.VMEM((blk_b, k), jnp.float32),
                     pltpu.VMEM((blk_b, k), jnp.int32)) * n_lists
 
@@ -229,8 +267,10 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
             inputs += [terms, lexnorm, qterms, qidf]
         kernel = functools.partial(_resident_kernel, spec=spec, k=k,
                                    blk_n=blk_n)
-        out_spec = (pl.BlockSpec((blk_b, k), lambda b, n: (b, 0)),) * 2 * n_lists
+        out_spec = ((pl.BlockSpec((blk_b, k), lambda b, n: (b, 0)),) * 2
+                    * n_lists + (merged_spec,))
         scratch = list(list_scratch)
+        params = None
     else:
         page = page_rows
         assert N % page == 0, (N, page)
@@ -257,14 +297,19 @@ def arena_scan_pallas(q: jax.Array, emb: jax.Array, meta: jax.Array,
                    else [emb, meta])
         kernel = functools.partial(_paged_kernel, spec=spec, k=k, page=page,
                                    n_pages=N // page)
-        out_spec = (pl.BlockSpec((blk_b, k), lambda b: (b, 0)),) * 2 * n_lists
+        out_spec = ((pl.BlockSpec((blk_b, k), lambda b: (b, 0)),) * 2
+                    * n_lists + (merged_spec,))
         scratch = list(list_scratch)
         scratch += [pltpu.VMEM((2,) + shape, dt) for shape, dt in stream_shapes]
         scratch += [pltpu.SemaphoreType.DMA((2,))] * len(stream_shapes)
+        # the emb double buffer alone is 12.6 MB at the planner's 2048-row
+        # page of 768 f32, and the merge gate's reduction and branch take
+        # Mosaic 2-5 MB of stack more: over the default 16 MiB scope
+        params = pltpu.CompilerParams(vmem_limit_bytes=PAGED_VMEM_BYTES)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0, grid=grid, in_specs=in_specs,
         out_specs=list(out_spec), scratch_shapes=scratch)
     fn = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                        interpret=interpret)
+                        compiler_params=params, interpret=interpret)
     return fn(*inputs)

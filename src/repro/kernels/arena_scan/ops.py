@@ -30,7 +30,10 @@ The entry owns each of these once, for every family:
     perturb real rows, and they are sliced off before returning), with
     the tiles from `default_blk_b` / `default_blk_n`;
   * the wsum weight fold (pinning rule 1, stages.py), the query-side idf
-    gather, and the rrf rank fusion after the scan.
+    gather, and the rrf rank fusion after the scan;
+  * the merge tally (``tally=True``): the kernel's count of the tiles its
+    merge gate let into each running list (`kernel` module docstring),
+    an output of the same program whether or not the caller asks for it.
 
 Its jitted program is `_run`: every launch compiles as ``jit__run``.
 """
@@ -221,7 +224,8 @@ def _run(q, emb, meta, gids, preds, lex, cand, k, spec, w_dense, w_lex,
          rrf_c, lists, use_kernel, blk_b, blk_n, page_rows, interpret,
          slots_of):
     """Every launch's one device program. Readers of a device trace find
-    the scans by its module name, ``jit__run``: keep the name."""
+    the scans by its module name, ``jit__run``: keep the name. Returns the
+    scan's lists and the kernel's merge counts (None for the jnp scan)."""
     if slots_of is not None:
         emb, meta = _gather_candidates(emb, meta, slots_of(*cand))
     tile = page_rows or blk_n
@@ -237,6 +241,7 @@ def _run(q, emb, meta, gids, preds, lex, cand, k, spec, w_dense, w_lex,
         lex = (terms, lexnorm, qterms, qidf)
     else:
         emb, meta = pad_dead_rows(emb, meta, tile)
+    merged = None
     if use_kernel:
         b = q.shape[0]
         q, emb = pad_d128(q, emb)
@@ -249,13 +254,14 @@ def _run(q, emb, meta, gids, preds, lex, cand, k, spec, w_dense, w_lex,
         out = arena_scan_pallas(q, emb, meta, gids, preds, k, spec=spec,
                                 lex=lex, blk_b=blk_b, blk_n=blk_n,
                                 page_rows=page_rows, interpret=interpret)
+        *out, merged = out
         out = tuple(a[:b] for a in out)
     else:
         out = arena_scan_scan_ref(q, emb, meta, gids, preds, k, tile,
                                   spec=spec, lex=lex)
     if spec.score == "both" and not lists:
-        return rrf_fuse(*out, k, rrf_c)
-    return out
+        out = rrf_fuse(*out, k, rrf_c)
+    return out, merged
 
 
 def arena_scan(q, emb, tenant, updated_at, category, acl, gids, preds,
@@ -265,7 +271,7 @@ def arena_scan(q, emb, tenant, updated_at, category, acl, gids, preds,
                candidates: tuple | None = None,
                use_kernel: bool | None = None, blk_b: int | None = None,
                blk_n: int | None = None, page_rows: int | None = None,
-               interpret: bool | None = None):
+               interpret: bool | None = None, tally: bool = False):
     """ONE arena scan answering every predicate group of a launch.
 
     q: (B, D) stacked query rows for every group; emb/tenant/updated_at/
@@ -295,6 +301,13 @@ def arena_scan(q, emb, tenant, updated_at, category, acl, gids, preds,
     selects the paged regime: the kernel switches to HBM-resident streams
     with double-buffered DMA, the jnp scan tiles at the page size — bits
     are unchanged either way (arena_scan contract).
+
+    ``tally=True`` appends the merge tally to the return: ``(merged,
+    tiles)``, with ``merged`` the kernel's (blocks * lists,) int32 device
+    array of the tiles each query block merged into each running list and
+    ``tiles`` the int tiles x lists x blocks the scan streamed; None where
+    no kernel ran (the jnp scan, an empty arena). The launch is the same
+    program either way.
     """
     if lists and spec.score != "both":
         raise ValueError("lists=True is only meaningful for the rrf scan")
@@ -310,19 +323,25 @@ def arena_scan(q, emb, tenant, updated_at, category, acl, gids, preds,
     if n == 0:                      # nothing to scan: nothing qualifies
         empty = (jnp.full((q.shape[0], k), NEG_INF, jnp.float32),
                  jnp.full((q.shape[0], k), -1, jnp.int32))
-        return empty * (2 if lists else 1)
+        return empty * (2 if lists else 1) + ((None,) if tally else ())
     meta = _packed_meta(tenant, updated_at, category, acl)
     lex = None
     if spec.has_lex:
         lex = _packed_lanes(terms, lexnorm) + (
             jnp.asarray(idf, jnp.float32), jnp.asarray(qterms, jnp.int32))
     k_eff = min(k, n)
-    out = _run(jnp.asarray(q), emb, meta, jnp.asarray(gids, jnp.int32),
-               jnp.asarray(preds, jnp.int32), lex, cand, k_eff, spec,
-               float(w_dense), float(w_lex), float(rrf_c), lists, use_kernel,
-               blk_b, blk_n, page_rows, interpret, slots_of)
+    out, merged = _run(jnp.asarray(q), emb, meta,
+                       jnp.asarray(gids, jnp.int32),
+                       jnp.asarray(preds, jnp.int32), lex, cand, k_eff, spec,
+                       float(w_dense), float(w_lex), float(rrf_c), lists,
+                       use_kernel, blk_b, blk_n, page_rows, interpret,
+                       slots_of)
     if k_eff < k:   # LIMIT larger than the rows scanned: SQL semantics
         pad = ((0, 0), (0, k - k_eff))
         out = tuple(jnp.pad(a, pad, constant_values=NEG_INF if j % 2 == 0
                             else -1) for j, a in enumerate(out))
-    return out
+    if not tally:
+        return out
+    tiles = -(-n // (page_rows or blk_n))
+    return out + (None if merged is None
+                  else (merged, merged.shape[0] * tiles),)

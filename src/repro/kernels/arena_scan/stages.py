@@ -107,7 +107,13 @@ def merge_topk(best_s, best_i, scores, idx, k: int):
     Mosaic has no `top_k` lowering, so the selection is k rounds of: row
     max, lowest position holding it, mask that position. Every step is an
     exact compare/select (no arithmetic on scores), so the result is the
-    same (value, index) sequence `lax.top_k` returns."""
+    same (value, index) sequence `lax.top_k` returns, in descending order.
+
+    The kernel calls it only for a tile that can change the lists (its
+    merge gate, `kernel` module docstring): where no tile score is above
+    its row's k-th entry ``best_s[:, k-1]``, every round picks the list's
+    own entry, which comes first on a tie, so the merge returns
+    ``(best_s, best_i)`` unchanged and skipping it is exact."""
     all_s = jnp.concatenate([best_s, scores], axis=1)
     all_i = jnp.concatenate([best_i, idx], axis=1)
     b, m = all_s.shape

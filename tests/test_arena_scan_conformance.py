@@ -228,9 +228,9 @@ def _lanes_ivf(rng, store, B, N, D, k, G, qt, page):
     qp = _pad_axis0(qp, 8, 0)
 
     def kernel(**kw):
-        s, i = arena_scan_pallas(qp, embp, meta, jnp.zeros((8, 1), jnp.int32),
-                                 pa, k, spec=IVF, blk_b=8, interpret=True,
-                                 **kw)
+        s, i, _ = arena_scan_pallas(qp, embp, meta,
+                                    jnp.zeros((8, 1), jnp.int32), pa, k,
+                                    spec=IVF, blk_b=8, interpret=True, **kw)
         return s[:B], i[:B]
 
     outs = {
@@ -355,6 +355,109 @@ def test_conformance_matrix(family, B, N, D, k, G, qt, page, rng):
     if preds is not None:   # ivf asserts its slot-lane leakage inline
         for name, (_, slots) in outs.items():
             _assert_no_leak(store, preds, gids, slots)
+
+
+# ---------------------------------------------------------------------------
+# the merge gate: a running list merges only the tiles that can enter it
+# ---------------------------------------------------------------------------
+
+GATE_N, GATE_D, GATE_K, GATE_T = 2048, 128, 8, 512   # four 512-row tiles
+
+
+def _gate_case(case, rng):
+    """One merge-gate case: (store, q, gids, preds, qterms, the tiles each
+    list must merge or None where the count is not known in advance)."""
+    store = _arena(rng, GATE_N, GATE_D)
+    q = rng.standard_normal((8, GATE_D)).astype(np.float32)
+    gids = np.zeros(8, np.int32)
+    preds = [Predicate()]
+    qterms, merges = None, None
+    if case == "ties":
+        # every tile repeats the same 64 live rows: after the first tile
+        # the list holds 8 copies of each row's best, and a later copy only
+        # ties the k-th entry, which loses to the list (strict >)
+        emb = np.tile(np.asarray(store["emb"])[:64], (GATE_N // 64, 1))
+        store.update(emb=jnp.asarray(emb),
+                     tenant=jnp.asarray(rng.integers(0, 5, GATE_N,
+                                                     dtype=np.int32)))
+        merges = [1]
+    elif case == "never-fills":
+        # a group two rows satisfy, in the second and the fourth tile: its
+        # list never fills, the other tiles are all NEG_INF, and the
+        # fourth tile's row (half the second's score) still enters
+        ts = np.asarray(store["updated_at"]).copy()
+        ts[ts >= 999] = 998
+        ts[[700, 1900]] = 999
+        emb = np.asarray(store["emb"]).copy()
+        emb[1900] = 0.5 * emb[700]
+        store.update(updated_at=jnp.asarray(ts), emb=jnp.asarray(emb),
+                     tenant=store["tenant"].at[np.asarray([700, 1900])].set(2))
+        q = emb[700] * np.arange(1, 9, dtype=np.float32)[:, None]
+        preds = [Predicate(tenant=2, min_ts=999)]
+        merges = [2]
+    elif case == "padded-rows":
+        # 5 query rows pad to the 8-row block with zero rows, which tie
+        # every arena row at 0.0
+        q, gids = q[:5], np.asarray([0, 1, 2, 0, 1], np.int32)
+        preds = [Predicate(tenant=g, min_ts=100) for g in range(3)]
+    else:
+        # rrf: the query term sits in the third tile alone. The bm25 list
+        # fills with zeros in the first tile and merges the third; the
+        # dense list merges every tile (each is a record for some row)
+        terms = np.full((GATE_N, T_LANES), -1, np.int32)
+        terms[1100:1110, 0] = 5
+        store.update(terms=jnp.asarray(terms),
+                     lexnorm=jnp.asarray(np.where(terms >= 0, 1.0, 0.0)
+                                         .astype(np.float32)),
+                     tenant=jnp.asarray(rng.integers(0, 5, GATE_N,
+                                                     dtype=np.int32)))
+        qterms = np.full((8, 2), -1, np.int32)
+        qterms[:, 0] = 5
+        merges = [GATE_N // GATE_T, 2]
+    return store, q, gids, preds, qterms, merges
+
+
+@pytest.mark.parametrize("case", ["ties", "never-fills", "padded-rows",
+                                  "rrf-one-list-merges"])
+@pytest.mark.parametrize("page", [None, GATE_T], ids=["resident", "paged"])
+def test_merge_gate_bits(case, page, rng):
+    """The gated kernel body (interpret mode, resident and paged) returns
+    the dense oracle's bits where a skipped tile ties the k-th entry, where
+    a group's list never fills, where padding rows join the query block,
+    and where one of rrf's lists merges a tile the other skips; and its
+    merge tally counts the tiles the case lets in."""
+    store, q, gids, preds, qterms, merges = _gate_case(case, rng)
+    pa = stack_predicates(preds)
+    meta = _packed_meta(store["tenant"], store["updated_at"],
+                        store["category"], store["acl"])
+    cols = (store["emb"], store["tenant"], store["updated_at"],
+            store["category"], store["acl"])
+    kw = {}
+    lex = None
+    if qterms is not None:
+        kw = dict(spec=hybrid_spec("rrf"), terms=store["terms"],
+                  lexnorm=store["lexnorm"], idf=store["idf"], qterms=qterms,
+                  lists=True)
+        qidf = np.where(qterms >= 0, np.asarray(store["idf"])[
+            np.clip(qterms, 0, None)], 0.0).astype(np.float32)
+        lex = (store["terms"].T, store["lexnorm"].T, jnp.asarray(qterms),
+               jnp.asarray(qidf))
+    want = oracle(jnp.asarray(q), store["emb"], meta, jnp.asarray(gids), pa,
+                  GATE_K, spec=kw.get("spec", ScanSpec()), lex=lex)
+    *got, (merged, tiles) = arena_scan(
+        q, *cols, gids, pa, GATE_K, use_kernel=True, interpret=True,
+        page_rows=page, tally=True, **kw)
+    assert len(got) == len(want)
+    for j in range(0, len(want), 2):
+        _assert_all_equal({"oracle": want[j:j + 2], "kernel": got[j:j + 2]})
+        _assert_no_leak(store, preds, gids, got[j + 1])
+    merged = np.asarray(merged)
+    n_lists = len(want) // 2
+    assert tiles == n_lists * GATE_N // GATE_T          # one 8-row block
+    assert merged.shape == (n_lists,)
+    assert ((merged >= 1) & (merged <= GATE_N // GATE_T)).all()
+    if merges is not None:
+        assert merged.tolist() == merges
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,7 @@ import json
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -301,6 +302,45 @@ def test_launch_span_carries_launched_shape():
     db.execute(_plans(db, ccfg, 3), use_cache=False)
     ann = next(s.ann for s in rec.traces()[-1].spans if s.name == "launch")
     assert ann["family"] == "ref" and "passes" not in ann
+
+
+@pytest.mark.parametrize("order,merged", [("descending", 1),
+                                           ("ascending", 4)])
+def test_device_sync_span_carries_merge_tally(order, merged):
+    """A kernel unit's `device_sync` span carries the merge gate's tally:
+    ``tiles_merged`` of ``tiles`` (tiles x lists x query blocks). Over an
+    arena whose scores fall row by row for every query row, only the first
+    of its four 512-row tiles holds a row of the top 10; where they rise,
+    every tile does."""
+    from repro.core.store import DocBatch
+    n, dim = 2048, 8
+    score = np.arange(n, 0, -1) if order == "descending" else np.arange(1, n + 1)
+    emb = np.zeros((n, dim), np.float32)
+    emb[:, 0] = score
+    db = RagDB(StoreConfig(capacity=n, dim=dim, metric="dot"), now_ts=100)
+    db.ingest(DocBatch(emb=jnp.asarray(emb),
+                       tenant=jnp.zeros(n, jnp.int32),
+                       category=jnp.zeros(n, jnp.int32),
+                       updated_at=jnp.full(n, 100, jnp.int32),
+                       acl=jnp.full(n, ALL_BITS, jnp.uint32),
+                       doc_id=jnp.arange(n, dtype=jnp.int32)))
+    rec = FlightRecorder()
+    db.attach_tracer(Tracer(enabled=True, recorder=rec))
+    sess = db.admin_session()
+    q = np.eye(dim, dtype=np.float32)[0]
+    plans = [sess.search(q * (i + 1), normalize=False).using("pallas")
+             .limit(10).plan() for i in range(3)]
+    db.execute(plans, use_cache=False)
+    (ann,) = {tuple(sorted(s.ann.items())) for t in rec.traces()[-3:]
+              for s in t.spans if s.name == "device_sync"}
+    ann = dict(ann)
+    assert (ann["tiles_merged"], ann["tiles"]) == (merged, 4)
+    # the ref engine runs no kernel: no tally
+    db.execute([sess.search(q, normalize=False).limit(10).plan()],
+               use_cache=False)
+    ann = next(s.ann for s in rec.traces()[-1].spans
+               if s.name == "device_sync")
+    assert "tiles" not in ann
 
 
 def _lexical_db(n_docs=300, dim=16):

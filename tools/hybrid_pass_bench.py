@@ -1,6 +1,6 @@
 """Device time of one resident hybrid arena scan, split into its parts.
 
-    python tools/hybrid_pass_bench.py --rows 1048576 --terms 64
+    python tools/hybrid_pass_bench.py --rows 1048576 --terms 64 [--k 1]
 
 Times `arena_scan` at a hybrid spec (the compiled Pallas kernel) on a
 seeded arena of
@@ -8,7 +8,10 @@ seeded arena of
 (one pass) for each fusion mode and query-term bucket, against the dense
 grouped scan of the same arena and rows. Prints one JSON line per shape:
 ms per call (mean of ``--iters`` back-to-back calls after a warm call) and
-the compile-and-first-call time. Needs a TPU.
+the compile-and-first-call time. ``--k`` is the LIMIT (10 by default):
+at ``--k 1`` the kernel's running-list merge is one selection round in
+place of ten, so the two runs bound what the merge costs a pass. Needs a
+TPU.
 """
 from __future__ import annotations
 
@@ -30,6 +33,7 @@ def main(argv=None) -> int:
     ap.add_argument("--qts", default="1,4,8,16")
     ap.add_argument("--groups", default="1,16")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--k", type=int, default=10)
     args = ap.parse_args(argv)
     sys.path[:0] = [os.path.join(ROOT, "src")]
     import jax
@@ -41,7 +45,8 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
         return 1
-    n, d, t, v, b, k = args.rows, args.dim, args.terms, args.vocab, 8, 10
+    n, d, t, v, b, k = (args.rows, args.dim, args.terms, args.vocab, 8,
+                        args.k)
     key = jax.random.key(7)
     ks = jax.random.split(key, 8)
     emb = jax.random.normal(ks[0], (n, d), jnp.float32)
@@ -75,7 +80,7 @@ def main(argv=None) -> int:
     for g in (int(x) for x in args.groups.split(",")):
         ms, first = timed(lambda: arena_scan(
             q, emb, tenant, ts, cat, acl, gids, preds_of(g), k))
-        print(json.dumps({"scan": "dense", "groups": g, "ms": ms,
+        print(json.dumps({"scan": "dense", "groups": g, "k": k, "ms": ms,
                           "first_s": first}), flush=True)
         for mode in ("wsum", "rrf"):
             for qt in (int(x) for x in args.qts.split(",")):
@@ -86,7 +91,7 @@ def main(argv=None) -> int:
                     spec=hybrid_spec(mode), terms=terms, lexnorm=lexnorm,
                     idf=idf, qterms=qterms))
                 print(json.dumps({"scan": "hybrid", "mode": mode, "qt": qt,
-                                  "groups": g, "lanes": t, "ms": ms,
+                                  "groups": g, "lanes": t, "k": k, "ms": ms,
                                   "first_s": first}), flush=True)
     return 0
 
